@@ -3,8 +3,8 @@
 ``throughput = niters * batch * world / (end - start)``).
 
 This is the interactive form of the harness; the repo-root ``bench.py``
-wraps the same measurement with probing/fallback orchestration for the
-scored one-line JSON.
+runs the same measurement beside its other legs, on a TPU only, and
+prints one JSON line.
 
 Usage: python examples/benchmark.py [--bs 32] [--iters 100]
            [--warmup 8] [--depth 50] [--size 224] [-p float32|bfloat16]
@@ -39,8 +39,8 @@ def main():
                     choices=["conv7", "space_to_depth"])
     args = ap.parse_args()
 
+    import jax
     if args.cpu:
-        import jax
         jax.config.update("jax_platforms", "cpu")
 
     import jax.numpy as jnp
@@ -75,14 +75,10 @@ def main():
 
     m.compile([tx], is_train=True, use_graph=True)
 
-    # completion barrier that holds on proxied backends too — the one
-    # canonical recipe, shipped in the package (block_until_ready can
-    # resolve on enqueue-ACK through a network tunnel; see
-    # docs/performance.md)
-    from singa_tpu.utils import force_completion
-
     def sync(t):
-        return force_completion(t.data)
+        # dispatch is asynchronous: the clock may only stop once the
+        # last step's output exists
+        jax.block_until_ready(t.data)
 
     # always at least one untimed step: it includes trace+compile, which
     # must not land inside the timed region

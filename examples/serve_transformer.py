@@ -372,8 +372,10 @@ def main():
                     help="cold-start elimination (singa_tpu.aot): "
                          "deserialize matching prefill/decode "
                          "executables from DIR instead of tracing "
-                         "(persistent compile cache under "
-                         "DIR/xla-cache); programs compiled fresh are "
+                         "(the persistent compile cache goes where "
+                         "JAX_COMPILATION_CACHE_DIR says, else "
+                         "<checkout>/.jax_compile_cache); programs "
+                         "compiled fresh are "
                          "exported back so the NEXT spin-up is warm")
     ap.add_argument("--autoscale", type=int, default=0, metavar="MIN",
                     help="fleet mode: MIN in-process replicas behind "
@@ -468,10 +470,8 @@ def main():
 
     serve_kw = {}
     if args.aot_dir:
-        from singa_tpu.aot import cache as aot_cache
         serve_kw["aot_store"] = args.aot_dir
-        serve_kw["compile_cache"] = aot_cache.cache_dir_for(
-            args.aot_dir)
+        serve_kw["compile_cache"] = True
     if args.kv_layout != "ring":
         serve_kw.update(kv_layout=args.kv_layout,
                         kv_block_size=args.kv_block_size,

@@ -14,7 +14,7 @@ for the accepted locations/formats. ``synthetic`` needs no files.
 Usage: python examples/train_cnn.py [cnn|alexnet|resnet|xceptionnet|mlp]
            [cifar10|cifar100|mnist|synthetic] [--data-dir DIR]
            [--bs 64] [--epochs 10] [--lr 0.05]
-           [-p float32|bfloat16|bf16_mixed] [--layout auto|NCHW|NHWC]
+           [-p float32|bfloat16|bf16_mixed] [--layout NCHW|NHWC]
            [--dist] [--dist-option plain|half|partialUpdate|
             sparseTopK|sparseThreshold] [--spars 0.05] [--cpu]
            [--mesh DxM] [--fsdp]
@@ -26,9 +26,7 @@ Usage: python examples/train_cnn.py [cnn|alexnet|resnet|xceptionnet|mlp]
 ``-p bf16_mixed`` trains under the mixed-precision compile policy
 (``Model.compile(policy="bf16_mixed")``): fp32 master weights (what
 checkpoints store) with bf16 conv/matmul compute and dynamic loss
-scaling — the TPU production setting. ``--layout auto`` (resnet) uses
-the banked ``resnet_layout_ab`` hardware A/B winner so the example runs
-the measured-fastest conv layout, falling back to NCHW when unmeasured.
+scaling — the TPU production setting.
 
 ``--resilient`` runs the fault-tolerant driver instead of the bare
 epoch loop: NaN/divergence guards (singa_tpu/resilience/guards.py)
@@ -45,20 +43,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, ".")
-
-
-def _measured_layout():
-    """Conv-trunk layout for --layout auto: the banked
-    ``resnet_layout_ab`` hardware A/B winner via bench._conv_layout
-    (env pin > fresh banked measurement > NCHW default), so the example
-    — not just the benchmark — runs the measured-fastest form. Falls
-    back to NCHW when bench.py or its observations are unreachable
-    (e.g. the example is run outside the repo root)."""
-    try:
-        import bench
-        return bench._conv_layout()
-    except Exception as e:  # noqa: BLE001 — the example must still run
-        return "NCHW", f"unmeasured-fallback ({type(e).__name__})"
 
 
 def build_parser():
@@ -100,17 +84,14 @@ def build_parser():
                          "just-in-time inside the step (~Nx per-chip "
                          "optimizer-state headroom). Implies a default "
                          "data mesh when --mesh is not given")
-    ap.add_argument("--bucket-mb", default="0",
+    ap.add_argument("--bucket-mb", type=float, default=0.0,
                     help="with --dist: gradient-psum bucket size target "
                          "in MiB (DistOpt bucket_mb) — gradients "
                          "coalesce into size-targeted buckets, one "
                          "collective each, issued as backward produces "
                          "them so XLA hides them under remaining "
                          "backward compute; 0 = per-gradient streaming "
-                         "psums (default); 'auto' resolves the banked "
-                         "grad_bucket_ab winner via "
-                         "bench._grad_bucket_mb (BENCH_BUCKET_MB pin "
-                         "> measured winner > 0). Read the win off "
+                         "psums (default). Read the win off "
                          "timeline_exposed_collective_seconds")
     ap.add_argument("--no-overlap", action="store_true",
                     help="with --dist: pin every gradient collective "
@@ -124,14 +105,11 @@ def build_parser():
     ap.add_argument("--cpu", action="store_true")
     ap.add_argument("--no-augment", action="store_true")
     ap.add_argument("--verbosity", "-v", type=int, default=0)
-    ap.add_argument("--layout", default="auto",
-                    choices=["auto", "NCHW", "NHWC"],
+    ap.add_argument("--layout", default="NCHW",
+                    choices=["NCHW", "NHWC"],
                     help="conv-trunk activation layout (resnet only; "
                          "NHWC is the TPU lane-friendly form, applied "
-                         "via ops.layout.use_layout inside the model). "
-                         "'auto' runs the banked resnet_layout_ab "
-                         "hardware A/B winner (bench._conv_layout) and "
-                         "falls back to NCHW when unmeasured")
+                         "via ops.layout.use_layout inside the model)")
     ap.add_argument("--stem", default="conv7",
                     choices=["conv7", "space_to_depth"],
                     help="resnet stem: plain 7x7/s2 conv or its exact "
@@ -254,33 +232,15 @@ def main():
     else:
         kw = {}
         if args.model == "resnet":
-            layout = args.layout
-            if layout == "auto":
-                layout, layout_src = _measured_layout()
-                print(f"conv layout: {layout} ({layout_src})", flush=True)
-            kw = {"layout": layout, "stem": args.stem}
+            kw = {"layout": args.layout, "stem": args.stem}
         model = factory.create_model(num_channels=chans,
                                      num_classes=num_classes, **kw)
-    if args.bucket_mb == "auto":
-        # same mechanism as --layout auto: the banked hardware A/B
-        # winner through bench's measured-choice plumbing
-        try:
-            import bench
-            bucket_mb, bucket_src = bench._grad_bucket_mb()
-        except Exception as e:  # noqa: BLE001 — the example must run
-            bucket_mb, bucket_src = 0.0, \
-                f"unmeasured-fallback ({type(e).__name__})"
-        if args.dist:
-            print(f"grad bucket: {bucket_mb} MiB ({bucket_src})",
-                  flush=True)
-    else:
-        bucket_mb = float(args.bucket_mb)
     sgd = opt.SGD(lr=args.lr, momentum=0.9, weight_decay=1e-5,
                   fused=args.fused_optim)
-    opt_obj = opt.DistOpt(sgd, bucket_mb=bucket_mb,
+    opt_obj = opt.DistOpt(sgd, bucket_mb=args.bucket_mb,
                           overlap=not args.no_overlap) \
         if args.dist else sgd
-    if not args.dist and (bucket_mb or args.no_overlap):
+    if not args.dist and (args.bucket_mb or args.no_overlap):
         print("note: --bucket-mb/--no-overlap shape the gradient "
               "collectives and need --dist; ignored on a single "
               "replica", flush=True)

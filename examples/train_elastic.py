@@ -30,6 +30,11 @@ Two hosts, then lose one and restart smaller::
 
 ``tools/chaos_smoke.py`` drives these scenarios end-to-end under a
 wall-clock budget.
+
+The ``--world N`` launcher starts every rank on THIS host, so it is for
+``--cpu`` runs: on an accelerator host each rank would claim every chip
+(a chip belongs to one process) and the launcher refuses. Across real
+hosts, start one rank per host with ``--rank`` and ``--coordinator``.
 """
 
 import argparse
@@ -284,6 +289,13 @@ def main():
 
     # launcher mode: one subprocess per rank; exit code is rank 0's
     # (the supervisor contract — 75 means "restart me, maybe smaller")
+    if not args.cpu:
+        raise SystemExit(
+            f"train_elastic: the launcher starts all {args.world} ranks "
+            "on this one host; without --cpu each of them would claim "
+            "every accelerator chip (a chip belongs to one process). "
+            "Pass --cpu, or start one rank per host with "
+            "--rank/--coordinator.")
     procs = []
     for r in range(args.world):
         cmd = [sys.executable, os.path.abspath(__file__), "--rank",

@@ -26,13 +26,14 @@ def main():
                     help="pin the CPU backend (hermetic runs)")
     args = ap.parse_args()
 
-    if args.cpu or os.environ.get("JAX_PLATFORMS") == "cpu":
+    if args.cpu:
         import jax
         jax.config.update("jax_platforms", "cpu")
 
     from singa_tpu import device, layer, metric, net, opt, tensor
 
-    dev = device.create_tpu_device()
+    dev = device.create_cpu_device() if args.cpu \
+        else device.create_tpu_device()
     dev.SetRandSeed(7)
 
     # synthetic separable data: class = argmax of a fixed projection

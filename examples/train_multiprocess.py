@@ -19,6 +19,12 @@ or launch one rank per host, SPMD-style:
 On machines without accelerators each process simulates a host with
 ``--devices-per-proc`` CPU devices, so the full multi-host code path —
 coordination service, global mesh, cross-process psum — runs anywhere.
+
+The standalone launcher is CPU-only: it starts every rank on THIS host,
+and on a TPU host each rank would claim every chip — a chip belongs to
+one process, so all ranks but the first fail or hang. One process drives
+all the chips of a host (``opt.DistOpt`` / ``compile(mesh=...)``); with
+``--platform tpu`` start one rank per host yourself with ``--rank``.
 """
 
 import argparse
@@ -161,6 +167,16 @@ def main():
             args.coordinator = "127.0.0.1:29512"
         run_rank(args)
         return
+
+    if args.platform != "cpu":
+        raise SystemExit(
+            "train_multiprocess: the standalone launcher starts all "
+            f"{args.procs} ranks on this one host, and with --platform "
+            f"{args.platform} each of them would claim every chip (a "
+            "chip belongs to one process). Start one rank per host "
+            "with --rank/--coordinator, or drive all of one host's "
+            "chips from a single process (opt.DistOpt, "
+            "compile(mesh=...)).")
 
     if args.coordinator is None:
         # ephemeral free port so concurrent runs / stale workers on the

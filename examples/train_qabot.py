@@ -46,14 +46,15 @@ def main():
                     help="pin the CPU backend (hermetic runs)")
     args = ap.parse_args()
 
-    if args.cpu or os.environ.get("JAX_PLATFORMS") == "cpu":
+    if args.cpu:
         import jax
         jax.config.update("jax_platforms", "cpu")
 
     from singa_tpu import device, opt, tensor
     from singa_tpu.models import qabot
 
-    dev = device.create_tpu_device()
+    dev = device.create_cpu_device() if args.cpu \
+        else device.create_tpu_device()
     dev.SetRandSeed(7)
     rng = np.random.RandomState(0)
     q, a_pos, a_neg = synthetic_qa(rng, args.n, args.seq_len, args.embed)

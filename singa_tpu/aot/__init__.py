@@ -1,15 +1,17 @@
 """Cold-start elimination: durable, verified compiled programs.
 
 Every elastic restart (the exit-75 path), rescale, and serving-replica
-spin-up used to pay a full XLA recompile — BENCH_r05 burned half a day
-of round budget on cold-start probe timeouts alone. This subsystem
-makes compiled programs **durable artifacts** with a strict
-honored-or-refused contract:
+spin-up used to pay a full XLA recompile. This subsystem makes compiled
+programs **durable artifacts** with a strict honored-or-refused
+contract:
 
 - :mod:`.cache` — JAX's persistent compilation cache behind ONE policy
-  object (:class:`~singa_tpu.aot.cache.CachePolicy`: directory, size
-  budget with LRU GC, enable/disable), wired through ``Model.compile``
-  and ``Model.compile_serving`` (``compile_cache=``). Hits and misses
+  object (:class:`~singa_tpu.aot.cache.CachePolicy`: size budget with
+  LRU GC, enable/disable) and ONE directory rule
+  (``JAX_COMPILATION_CACHE_DIR`` when set, else the directory asked
+  for, else ``<checkout>/.jax_compile_cache``), wired through
+  ``Model.compile`` and ``Model.compile_serving`` (``compile_cache=``)
+  and installed by every entry point. Hits and misses
   are counted (``compile_cache_hits_total`` / ``_misses_total``) and
   every traced dispatch's ``compile_seconds`` observation carries a
   ``source="cache"|"fresh"`` label, so the win is visible in telemetry

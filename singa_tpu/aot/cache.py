@@ -1,19 +1,24 @@
-"""The persistent-compile-cache policy: one object, process-wide.
+"""The persistent-compile-cache policy: one object, one directory rule.
 
 JAX's persistent compilation cache turns a recompile of an
 already-seen program into a disk read, but its raw form is a scatter
 of config flags with no observability and no size control. This module
-fronts it with ONE policy object:
+fronts it with ONE policy object and ONE rule for where the cache
+lives, used by every entry point (``chip_smoke.py``, ``bench.py``, the
+examples, the trainer, ``Model.compile(compile_cache=...)`` /
+``Model.compile_serving(compile_cache=...)``):
 
-    from singa_tpu import aot
-    aot.install(aot.CachePolicy("/ckpts/aot/xla-cache",
-                                size_budget_bytes=2 << 30))
+- ``JAX_COMPILATION_CACHE_DIR`` set: the cache is that directory. jax
+  reads the variable itself at import, so nothing here sets a
+  directory in code and a directory asked for in code is not used —
+  whoever runs the program places the cache from outside.
+- unset: the cache is the directory asked for (``CachePolicy(dir)`` /
+  ``compile_cache="/path"``), else ``<checkout>/.jax_compile_cache``
+  (:func:`default_dir`) — a fixed path, because the path is part of
+  the cache key and a directory that moves never hits.
 
-or, through the surfaces that compile:
-``Model.compile(inputs, compile_cache=policy_or_dir)`` /
-``Model.compile_serving(compile_cache=...)``.
-
-What installing buys beyond the raw flags:
+What installing buys beyond the raw flags, attached to whichever
+directory the rule picked:
 
 - **hit/miss counters** — a process-wide ``jax.monitoring`` listener
   counts cache hits and misses into
@@ -28,22 +33,20 @@ What installing buys beyond the raw flags:
   an ``-atime`` companion per entry exactly for this), run at install
   and on demand (``tools/aot_cache.py gc``);
 - **enable/disable** — one switch, not four flags.
-
-Everything here is host-side and best-effort: a cache that cannot be
-installed degrades to fresh compiles with a warning, never a failed
-``compile``.
 """
 
 from __future__ import annotations
 
+import copy
 import os
 import threading
-import warnings
+
+from jax import monitoring
 
 from ..observability import metrics as _metrics
 
-# jax.monitoring event names (stable across the jax versions we
-# support; unknown names simply never fire)
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+
 _EVT_HIT = "/jax/compilation_cache/cache_hits"
 _EVT_MISS = "/jax/compilation_cache/cache_misses"
 
@@ -58,7 +61,9 @@ _COUNTS = {"hits": 0, "misses": 0}
 class CachePolicy:
     """Persistent-compile-cache configuration (see module docstring).
 
-    - ``directory``: where XLA executables persist.
+    - ``directory``: where XLA executables persist when
+      ``JAX_COMPILATION_CACHE_DIR`` is unset; None = :func:`default_dir`.
+      The policy :func:`install` returns carries the directory in effect.
     - ``enabled``: False turns the cache OFF at install (the one-switch
       opt-out).
     - ``size_budget_bytes``: LRU GC target; None = unbounded.
@@ -68,10 +73,11 @@ class CachePolicy:
       program set warm, not just the expensive tail.
     """
 
-    def __init__(self, directory, *, enabled=True,
+    def __init__(self, directory=None, *, enabled=True,
                  size_budget_bytes=None, min_compile_seconds=0.0,
                  min_entry_bytes=-1):
-        self.directory = os.path.abspath(str(directory))
+        self.directory = None if directory is None \
+            else os.path.abspath(os.fspath(directory))
         self.enabled = bool(enabled)
         self.size_budget_bytes = None if size_budget_bytes is None \
             else int(size_budget_bytes)
@@ -89,109 +95,91 @@ class CachePolicy:
 
 
 def _listener(event, **kw):
-    """jax.monitoring event listener — must NEVER raise into jax."""
-    try:
-        if event == _EVT_HIT:
-            _COUNTS["hits"] += 1
-            _metrics.default_registry().counter(
-                "compile_cache_hits_total",
-                "XLA compiles served from the persistent cache").inc()
-        elif event == _EVT_MISS:
-            _COUNTS["misses"] += 1
-            _metrics.default_registry().counter(
-                "compile_cache_misses_total",
-                "XLA compiles the persistent cache could not serve"
-            ).inc()
-    except Exception:       # noqa: BLE001 — telemetry must stay silent
-        pass
+    if event == _EVT_HIT:
+        _COUNTS["hits"] += 1
+        _metrics.default_registry().counter(
+            "compile_cache_hits_total",
+            "XLA compiles served from the persistent cache").inc()
+    elif event == _EVT_MISS:
+        _COUNTS["misses"] += 1
+        _metrics.default_registry().counter(
+            "compile_cache_misses_total",
+            "XLA compiles the persistent cache could not serve"
+        ).inc()
 
 
 def _ensure_listener():
     global _LISTENING
     with _LOCK:
-        if _LISTENING:
-            return
-        try:
-            try:        # public surface first; private path for jax
-                from jax import monitoring  # versions that lack it
-            except ImportError:
-                from jax._src import monitoring
+        if not _LISTENING:
             monitoring.register_event_listener(_listener)
             _LISTENING = True
-        except Exception as e:      # noqa: BLE001 — counters degrade
-            warnings.warn(
-                f"compile-cache hit/miss counters unavailable "
-                f"({type(e).__name__}: {e}); compile_seconds will "
-                "label every compile source=fresh", stacklevel=3)
 
 
 def resolve(policy):
     """Coerce a user-facing ``compile_cache=`` value to a
     :class:`CachePolicy`: a policy passes through, a path string/
-    PathLike becomes an enabled policy over it, ``False`` a disabled
-    one over the default directory."""
+    PathLike becomes an enabled policy over it, ``True``/``None`` an
+    enabled one over the rule's directory, ``False`` a disabled one."""
     if isinstance(policy, CachePolicy):
         return policy
     if policy is False:
-        return CachePolicy(default_dir(), enabled=False)
-    if policy is True:
-        return CachePolicy(default_dir())
-    return CachePolicy(os.fspath(policy))
+        return CachePolicy(enabled=False)
+    if policy is True or policy is None:
+        return CachePolicy()
+    return CachePolicy(policy)
 
 
 def default_dir():
-    return os.path.join(os.path.expanduser("~"), ".cache", "singa_tpu",
-                        "xla-cache")
+    """``<checkout>/.jax_compile_cache``: beside the ``singa_tpu``
+    package, listed in ``.gitignore``, and the same path on every run
+    from this checkout."""
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".jax_compile_cache")
 
 
-def cache_dir_for(aot_dir):
-    """The ONE definition of where the persistent compile cache lives
-    inside an ``aot/`` sidecar directory — the trainer, the serving
-    example, and the CLI all route through it so the layout can never
-    split the warm cache across divergent conventions."""
-    return os.path.join(os.path.abspath(str(aot_dir)), "xla-cache")
+def effective_dir(directory=None):
+    """(directory, source) the rule picks: the environment's when
+    ``JAX_COMPILATION_CACHE_DIR`` is set, else ``directory``, else
+    :func:`default_dir`."""
+    env = os.environ.get(ENV_DIR)
+    if env:
+        return os.path.abspath(env), "env"
+    if directory is not None:
+        return os.path.abspath(os.fspath(directory)), "explicit"
+    return default_dir(), "default"
 
 
-def install(policy):
-    """Install ``policy`` (a :class:`CachePolicy`, a directory, True
-    for the default directory, or False to disable) process-wide:
-    configure jax's persistent compilation cache, register the
+def install(policy=None):
+    """Install ``policy`` (a :class:`CachePolicy`, a directory, True/
+    None for the rule's directory, or False to disable) process-wide:
+    point jax's persistent compilation cache at the directory the rule
+    picks (module docstring), set the write thresholds, register the
     hit/miss listener, and GC down to the size budget. Returns the
-    active policy. Never raises — a cache that cannot install degrades
-    to fresh compiles, loudly."""
+    active policy, whose ``directory`` is the one in effect."""
     global _ACTIVE
-    pol = resolve(policy)
-    try:
-        import jax
-        if pol.enabled:
-            os.makedirs(pol.directory, exist_ok=True)
+    import jax
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _cc)
+    pol = copy.copy(resolve(policy))    # the caller's object stays as is
+    pol.directory, source = effective_dir(pol.directory)
+    jax.config.update("jax_enable_compilation_cache", pol.enabled)
+    if pol.enabled:
+        os.makedirs(pol.directory, exist_ok=True)
+        if source != "env":
             jax.config.update("jax_compilation_cache_dir", pol.directory)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              pol.min_compile_seconds)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              pol.min_entry_bytes)
-            # the config flag alone is only consulted when jax first
-            # checks its cache machinery — a process that already
-            # compiled something has memoized "no cache" for the whole
-            # task (is_cache_used's once-per-task check). reset_cache
-            # drops that memo so installing mid-process works too.
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-            _ensure_listener()
-            if pol.size_budget_bytes is not None:
-                gc(pol)
-        else:
-            jax.config.update("jax_compilation_cache_dir", None)
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-    except Exception as e:      # noqa: BLE001 — optimisation, not a gate
-        warnings.warn(
-            f"persistent compile cache unavailable "
-            f"({type(e).__name__}: {e}); compiles run fresh",
-            stacklevel=2)
-        return _ACTIVE
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          pol.min_compile_seconds)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          pol.min_entry_bytes)
+        _ensure_listener()
+        if pol.size_budget_bytes is not None:
+            gc(pol)
+    # jax memoizes "is the cache in use" once per task at its first
+    # compile; reset_cache drops that memo so installing mid-process
+    # takes effect
+    _cc.reset_cache()
     _ACTIVE = pol
     return pol
 
@@ -202,19 +190,18 @@ def active():
 
 
 def uninstall():
-    """Turn the persistent cache back off (tests, or a one-shot tool
-    that must not leave process-global config behind). The hit/miss
-    listener stays registered — with no cache configured it simply
-    never fires again."""
+    """Undo :func:`install` (tests, or a one-shot tool that must not
+    leave process-global config behind): a directory set in code is
+    cleared, one that came from the environment is left to jax's own
+    handling. The hit/miss listener stays registered."""
     global _ACTIVE
-    try:
-        import jax
+    import jax
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _cc)
+    if not os.environ.get(ENV_DIR):
         jax.config.update("jax_compilation_cache_dir", None)
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:       # noqa: BLE001 — symmetric with install
-        pass
+    jax.config.update("jax_enable_compilation_cache", True)
+    _cc.reset_cache()
     _ACTIVE = None
 
 
@@ -239,7 +226,7 @@ def stats(directory=None):
     """{entries, bytes} of a cache directory (the active policy's when
     None). Missing directory counts as empty."""
     d = directory if directory is not None else \
-        (_ACTIVE.directory if _ACTIVE is not None else default_dir())
+        (_ACTIVE.directory if _ACTIVE is not None else effective_dir()[0])
     entries = 0
     total = 0
     try:
@@ -271,7 +258,8 @@ def gc(policy=None, *, budget_bytes=None):
     if pol is None and budget_bytes is None:
         return {"removed": 0, "bytes_freed": 0, "entries": 0,
                 "bytes": 0}
-    directory = pol.directory if pol is not None else default_dir()
+    directory = (pol.directory if pol is not None else None) \
+        or effective_dir()[0]
     budget = budget_bytes if budget_bytes is not None \
         else getattr(pol, "size_budget_bytes", None)
     try:
@@ -320,5 +308,6 @@ def gc(policy=None, *, budget_bytes=None):
             "entries": len(entries) - removed, "bytes": total - freed}
 
 
-__all__ = ["CachePolicy", "install", "active", "resolve", "snapshot",
-           "classify", "stats", "gc", "default_dir", "cache_dir_for"]
+__all__ = ["CachePolicy", "install", "active", "uninstall", "resolve",
+           "snapshot", "classify", "stats", "gc", "default_dir",
+           "effective_dir", "ENV_DIR"]

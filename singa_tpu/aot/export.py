@@ -238,11 +238,18 @@ class AotStore:
                          donate_argnums=donate_argnums, policy=policy,
                          jax_device=jax_device,
                          expect_extra=expect_extra)
+        import jax
         from jax.experimental import serialize_executable
+        # every exportable program is single-device (sharded ones are
+        # refused at export): load it for the device it was compiled
+        # for — left to its default, jax loads the executable over ALL
+        # local devices and the first call fails on the shard count
+        target = jax_device if jax_device is not None else jax.devices()[0]
         try:
             parts = pickle.loads(blob)
             fn = serialize_executable.deserialize_and_load(
-                parts["payload"], parts["in_tree"], parts["out_tree"])
+                parts["payload"], parts["in_tree"], parts["out_tree"],
+                backend=target.client, execution_devices=[target])
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:      # noqa: BLE001 — refused, typed

@@ -158,10 +158,9 @@ class Device:
 
         NOTE: verbosity>=2 forces the FIRST graph-mode train call to run
         eagerly (per-op wall times only exist op-by-op), skipping the
-        zero-compute abstract rehearsal. On a network-tunneled
-        accelerator that eager pass costs one round trip per op and can
-        look like a hang on a big model — profile small, or at
-        verbosity 1."""
+        zero-compute abstract rehearsal. That eager pass is one device
+        dispatch per op, which is slow on a big model — profile small,
+        or at verbosity 1."""
         self.verbosity = int(verbosity)
 
     def SetSkipIteration(self, skip: int) -> None:
@@ -202,16 +201,20 @@ class CppCPU(Device):
 
 class TpuDevice(Device):
     """TPU device — the peer of the reference's CudaGPU
-    (src/core/device/cuda_gpu.cc), with XLA replacing cuDNN/cuBLAS/cnmem."""
+    (src/core/device/cuda_gpu.cc), with XLA replacing cuDNN/cuBLAS/cnmem.
+    Like the reference's ``create_cuda_gpu`` without a GPU, it raises
+    when this process has no accelerator: ask for ``create_cpu_device``
+    to run on the host."""
 
     def __init__(self, device_id: int = 0, jax_device=None):
         if jax_device is None:
-            local = jax.local_devices()
-            accel = [d for d in local if d.platform != "cpu"]
-            if accel:
-                jax_device = accel[device_id % len(accel)]
-            else:  # CPU fallback keeps the API usable off-TPU
-                jax_device = local[device_id % len(local)]
+            accel = [d for d in jax.local_devices() if d.platform != "cpu"]
+            if not accel:
+                raise RuntimeError(
+                    "no accelerator: jax.local_devices() lists only "
+                    f"{jax.default_backend()!r} devices. Use "
+                    "device.create_cpu_device() to run on the host.")
+            jax_device = accel[device_id % len(accel)]
         super().__init__(jax_device, device_id, lang="kTpu")
 
 
@@ -266,7 +269,8 @@ def create_tpu_devices(num: int):
 
 
 # CUDA-named aliases for drop-in compatibility with reference scripts
-# (python/singa/device.py:60-118): they return the accelerator present.
+# (python/singa/device.py:60-118): they return the accelerator present
+# and, like the reference without a GPU, raise when there is none.
 def create_cuda_gpu(set_default=True):  # noqa: ARG001 (parity signature)
     return create_tpu_device(0)
 

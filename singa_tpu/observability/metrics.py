@@ -55,26 +55,43 @@ DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                    0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
                    120.0, 300.0)
 
-# Peak dense matmul FLOP/s per chip by TPU generation (public bf16 MXU
-# figures) — the MFU denominator. The CANONICAL table: bench.py's
-# _peak_flops delegates here (keeping its env overrides), and the
-# trainer's train_mfu gauge reads it directly. Order matters: first
-# substring match wins, so the more specific tags come first.
-PEAK_FLOPS_BY_KIND = [
-    ("v6", 918e12), ("v5p", 459e12), ("v5e", 197e12), ("v5 lite", 197e12),
-    ("v5lite", 197e12), ("v5", 459e12), ("v4", 275e12), ("v3", 123e12),
-    ("v2", 45e12),
-]
+# Published peaks of one chip, keyed by the EXACT ``device_kind`` string
+# jax reports for it — the denominators of MFU and roofline shares. One
+# table for the benchmark, chip_smoke.py and the trainer's train_mfu
+# gauge. A row is added only with the kind string read off that chip and
+# the source of its numbers; a TPU that is not here is an error, never a
+# neighbouring generation's peak.
+DEVICE_PEAKS = {
+    # kind as printed by a v5e chip (chip_smoke.py's device phase)
+    "TPU v5 lite": {
+        "bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 819 GB/s HBM (as quoted in /opt/skills/guides/"
+                  "on-chip-measurement/SKILL.md section 3)"},
+}
 
 
-def device_peak_flops(device_kind):
-    """Peak FLOP/s for a device kind string (``jax_device.device_kind``),
-    or None when the generation is unknown (CPU, emulators)."""
-    kind = (device_kind or "").lower()
-    for tag, peak in PEAK_FLOPS_BY_KIND:
-        if tag in kind:
-            return peak
-    return None
+def device_peaks(jax_device):
+    """The :data:`DEVICE_PEAKS` row of a jax device; None for the CPU
+    backend (and for no device at all), where no peak is meaningful.
+    Any other device whose kind the table does not know raises."""
+    if jax_device is None or jax_device.platform == "cpu":
+        return None
+    kind = jax_device.device_kind
+    if kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peaks for device_kind {kind!r} (platform "
+            f"{jax_device.platform!r}): add a row with its source to "
+            "singa_tpu.observability.metrics.DEVICE_PEAKS "
+            f"(known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[kind]
+
+
+def device_peak_flops(jax_device):
+    """Peak dense bf16 matmul FLOP/s of a jax device (the MFU
+    denominator), by the rule of :func:`device_peaks`."""
+    row = device_peaks(jax_device)
+    return row["bf16_flops"] if row else None
 
 
 # resolved once per process (subprocess git call), then cached
@@ -637,8 +654,9 @@ def aggregate_summaries(summaries, ages=None, stale_after=None):
     return agg
 
 
-__all__ = ["SNAPSHOT_SCHEMA", "DEFAULT_BUCKETS", "PEAK_FLOPS_BY_KIND",
-           "STRAGGLER_FACTOR", "device_peak_flops", "build_stamp",
+__all__ = ["SNAPSHOT_SCHEMA", "DEFAULT_BUCKETS", "DEVICE_PEAKS",
+           "STRAGGLER_FACTOR", "device_peaks", "device_peak_flops",
+           "build_stamp",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "REGISTRY", "default_registry", "heartbeat_summary",
            "aggregate_summaries"]

@@ -70,15 +70,14 @@ def hbm_stats(jax_device, raise_errors=False):
     backend has no stats (CPU, emulators) or the read fails.
 
     ``raise_errors=True`` propagates a FAILING ``memory_stats()`` call
-    instead of folding it into None — diagnostic callers (the HBM
-    probe children) must report "the TPU runtime errored: <why>", not
-    the same silence a stats-less CPU produces.
+    instead of folding it into None — a diagnostic caller must report
+    "the TPU runtime errored: <why>", not the same silence a
+    stats-less CPU produces.
 
     NOTE: ``peak_bytes_in_use`` is a process-lifetime high-water mark —
     within one process it is monotonic across workloads. A precise
-    per-model peak needs a fresh process (what
-    ``tools/tpu_probe_extra.py``'s HBM children do); in-process samples
-    are an upper bound."""
+    per-model peak needs a fresh process; in-process samples are an
+    upper bound."""
     ms = getattr(jax_device, "memory_stats", None)
     if ms is None:
         return None

@@ -351,7 +351,7 @@ def record_timeline(tl, registry=None, site="train", waterfall_doc=None):
 def compact(tl):
     """The ONE compact serialized form of an analyzed timeline —
     rounded bucket fractions + exposed/total collective seconds + the
-    window — shared by every emitter (the bench legs' banked records,
+    window — shared by every emitter (the bench legs' records,
     the ``timeline.sample`` flight-recorder events) so their schemas
     cannot drift. Returns None for None."""
     if not tl:

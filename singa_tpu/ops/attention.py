@@ -28,6 +28,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..autograd_base import Operator
 from ..mixed_precision import cast_compute as _cast_compute
@@ -182,13 +184,6 @@ def _scan_flash_bwd(q, k, v, out, lse, g, causal, scale, block_k):
 # accumulator pattern below sound.
 # ---------------------------------------------------------------------------
 
-try:  # pallas import is TPU-oriented; keep CPU-only installs working
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
-
 # Test hook: run kernels in interpreter mode so CPU CI validates the exact
 # kernel math the TPU executes (tests/test_attention.py flips this).
 FORCE_PALLAS_INTERPRET = False
@@ -237,8 +232,8 @@ def _env_block(name):
 def _pick_blocks(Sq, Sk):
     """Largest Pallas block sizes that tile the sequence lengths.
 
-    Measured on TPU v5e (B8 H8 S1024 D64, fwd+bwd, slope-readback
-    timing): (512, 256) runs 3.1x faster than the (128, 128) minimum —
+    Measured on TPU v5e on 2026-07-31, before this round's records
+    (B8 H8 S1024 D64, fwd+bwd): (512, 256) runs 3.1x faster than the (128, 128) minimum —
     bigger q tiles amortise the k/v stream and keep the MXU busy.
     Falls back through 256 to the 128-lane minimum when the sequence
     length doesn't divide, so short or odd-length shapes still get the
@@ -286,8 +281,6 @@ def _pallas_blocks(q, k):
 
 
 def _use_pallas(q, k, block_q, block_k):
-    if not HAS_PALLAS:
-        return False
     bq = min(block_q, q.shape[2])
     bk = min(block_k, k.shape[2])
     if q.shape[2] % bq or k.shape[2] % bk:
@@ -518,6 +511,7 @@ def _pallas_flash_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*operands)
     return out.reshape(B, H, Sq, D), lse[..., 0].reshape(B, H, Sq)
 
@@ -559,6 +553,7 @@ def _pallas_flash_bwd(q, k, v, out, lse, g, causal, scale,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qr, kr, vr, gr, lser, delta)
 
     kvspec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
@@ -581,6 +576,7 @@ def _pallas_flash_bwd(q, k, v, out, lse, g, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lser, delta)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
             dv.reshape(B, H, Sk, D))

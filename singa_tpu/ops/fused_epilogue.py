@@ -24,8 +24,9 @@ output directly. Everything else falls through to the reference ops.
 
 House pattern as ``ops/attention.py``/``ops/fused_optim.py``:
 ``FORCE_PALLAS_INTERPRET`` runs the exact kernel on CPU for the
-``pallas`` CI tier; selection is measured-not-guessed (OFF by default,
-bench steers it through the banked ``conv_epilogue_ab`` A/B record).
+``pallas`` CI tier; OFF by default (``enable`` / ``enabled_scope``,
+bench's ``BENCH_CONV_EPILOGUE`` pin) until a chip A/B says otherwise
+(ROADMAP.md D3).
 """
 
 from __future__ import annotations
@@ -34,12 +35,9 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from . import fused_optim
-from .fused_optim import HAS_PALLAS
-
-if HAS_PALLAS:
-    from jax.experimental import pallas as pl
 
 _ENABLED = False
 
@@ -182,6 +180,7 @@ def _scale_shift_relu_impl(x, scale, shift, layout, residual):
             out_specs=blk,
             out_shape=jax.ShapeDtypeStruct((rows, C), x.dtype),
             interpret=_interpret(),
+            name="conv_epilogue_nhwc",
         )(*args, scale.reshape(1, C), shift.reshape(1, C))
         return out[:m].reshape(x.shape)
     # NCHW: collapse to one row per (image, channel); the per-row
@@ -212,6 +211,7 @@ def _scale_shift_relu_impl(x, scale, shift, layout, residual):
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows, L), x.dtype),
         interpret=_interpret(),
+        name="conv_epilogue_nchw",
     )(*args, s_rows, b_rows)
     return out[:N * C].reshape(x.shape)
 

@@ -10,16 +10,15 @@ master + aux once, with the aux/master outputs aliased onto their
 inputs. Parameters whose size is not a (rows×128)-tile multiple pay a
 pad/slice around the kernel (XLA fuses what it can, but the aliasing
 then covers the padded buffers, not the live state) — whether the
-fused form still wins for a given model is exactly what the banked
-``fused_optim_ab`` hardware A/B decides; it is never assumed.
+fused form still wins for a given model is for a chip A/B to decide
+(ROADMAP.md D3); it is never assumed.
 
 House pattern (``ops/attention.py``): availability gate that DECLINES
 to the reference path rather than erroring (``available``), interpreter
 mode on CPU so tier-1 CI pins the exact kernel math the TPU executes
 (``FORCE_PALLAS_INTERPRET`` — the ``pallas`` pytest marker selects
-these suites), and selection is measured-not-guessed: the optimizers
-only take this path when constructed with ``fused=True``, which bench
-steers through ``bench._measured_choice`` ("fused_optim_ab") — never
+these suites), and the optimizers only take this path when constructed
+with ``fused=True`` (bench: the ``BENCH_FUSED_OPTIM`` pin) — never
 unconditionally.
 
 FLOPs accounting: a Pallas kernel is a custom call XLA's cost analysis
@@ -41,13 +40,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:  # pallas import is TPU-oriented; keep CPU-only installs working
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 # Test hook, same contract as ops/attention.py: run the kernels under
 # pl.pallas_call(interpret=True) on CPU so CI validates the exact math.
@@ -103,11 +96,11 @@ def _interpret():
 
 
 def available(n_elems):
-    """Kernel-eligibility gate: Pallas importable, not inside
-    :func:`force_reference`, and either a real TPU backend with a
+    """Kernel-eligibility gate: not inside :func:`force_reference`,
+    and either a real TPU backend with a
     parameter big enough to amortise the launch, or the interpret-mode
     test hook (any size, so CI covers padding)."""
-    if not HAS_PALLAS or _FORCE_REFERENCE.get():
+    if _FORCE_REFERENCE.get():
         return False
     if jax.default_backend() == "tpu":
         return int(n_elems) >= MIN_FUSED_ELEMS
@@ -194,6 +187,7 @@ def sgd_momentum_update(p, g, m, lr, *, momentum, dampening=0.0,
         # "one HBM pass" contract
         input_output_aliases={1: 0, 3: 1},
         interpret=_interpret(),
+        name="fused_sgd",
     )(_scalar(lr), _to_rows(p, rows), _to_rows(g, rows),
       _to_rows(m, rows))
     return _from_rows(po, shape, n), _from_rows(mo, shape, n)
@@ -249,6 +243,7 @@ def adam_update(p, g, m, v, lr, bias_corr1, bias_corr2, *, beta_1,
                    jax.ShapeDtypeStruct((rows, _LANES), v.dtype)],
         input_output_aliases={3: 0, 5: 1, 6: 2},
         interpret=_interpret(),
+        name="fused_adam",
     )(_scalar(lr), _scalar(bias_corr1), _scalar(bias_corr2),
       _to_rows(p, rows), _to_rows(g, rows), _to_rows(m, rows),
       _to_rows(v, rows))
@@ -304,6 +299,7 @@ def rmsprop_update(p, g, r, lr, *, rho, epsilon, weight_decay=0.0):
                    jax.ShapeDtypeStruct((rows, _LANES), r.dtype)],
         input_output_aliases={1: 0, 3: 1},
         interpret=_interpret(),
+        name="fused_rmsprop",
     )(_scalar(lr), _to_rows(p, rows), _to_rows(g, rows),
       _to_rows(r, rows))
     return _from_rows(po, shape, n), _from_rows(ro, shape, n)
@@ -352,6 +348,7 @@ def adagrad_update(p, g, h, lr, *, epsilon, weight_decay=0.0):
                    jax.ShapeDtypeStruct((rows, _LANES), h.dtype)],
         input_output_aliases={1: 0, 3: 1},
         interpret=_interpret(),
+        name="fused_adagrad",
     )(_scalar(lr), _to_rows(p, rows), _to_rows(g, rows),
       _to_rows(h, rows))
     return _from_rows(po, shape, n), _from_rows(ho, shape, n)

@@ -13,9 +13,9 @@ consult at construction time:
   channels-last, with weights still stored OIHW so checkpoints are
   layout-independent.
 
-Which layout is faster is a hardware question, answered by the banked
-``resnet_layout_ab`` probe (tools/tpu_probe_extra.py) — bench.py picks
-the measured winner, never a guess.
+Which layout is faster is a hardware question that no chip run has
+answered yet (ROADMAP.md D3): NCHW is the default and NHWC an explicit
+choice (``create_model(layout=)``, ``BENCH_CONV_LAYOUT``).
 """
 
 from __future__ import annotations

@@ -319,8 +319,8 @@ class SGD(Optimizer):
     Ineligible params (regularizer/constraint attached, too small for a
     kernel launch, non-TPU backend without the interpret test hook)
     keep the reference path per-param. Parity is pinned in
-    tests/test_fused_kernels.py; bench selects the mode via the banked
-    ``fused_optim_ab`` A/B — never unconditionally."""
+    tests/test_fused_kernels.py; bench selects the mode by its
+    ``BENCH_FUSED_OPTIM`` pin — never unconditionally."""
 
     def __init__(self, lr=0.1, momentum=0.0, dampening=0.0,
                  weight_decay=0.0, nesterov=False, fused=False):

@@ -53,13 +53,9 @@ def active_axis(axis_name: str) -> bool:
 
 
 def axis_size(axis_name: str) -> int:
-    """Size of a bound mesh axis. ``jax.lax.axis_size`` only exists on
-    newer jax; on older versions ``psum(1, axis)`` is the idiom — and
-    it constant-folds to a python int at trace time, so callers can use
-    the result in static control flow either way."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Size of a bound mesh axis: a python int at trace time, so
+    callers can use it in static control flow."""
+    return lax.axis_size(axis_name)
 
 
 # Mesh axes the BATCH dimension is sharded over inside the current
@@ -140,13 +136,10 @@ def init_process(nccl_id: NcclIdHolder | None = None, rank: int = 0,
 
     On TPU pods the collectives ride ICI/DCN natively; on the CPU backend
     cross-process collectives need an explicit transport, so gloo is
-    enabled best-effort (this is what makes the multi-process examples and
+    enabled (this is what makes the multi-process examples and
     tests runnable on any machine — the reference needs real GPUs+NCCL)."""
     if world > 1:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:            # unknown option on this jax version
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=(nccl_id or NcclIdHolder()).
             coordinator_address,

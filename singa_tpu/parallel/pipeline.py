@@ -78,12 +78,8 @@ _pipe_descale.defvjp(_pipe_descale_fwd, _pipe_descale_bwd)
 
 def _mark_varying(v, axis_name):
     """Mark a value device-varying over ``axis_name`` for shard_map's
-    vma typecheck (API renamed across JAX versions)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(v, (axis_name,), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(v, (axis_name,))
-    return v
+    vma typecheck."""
+    return jax.lax.pcast(v, (axis_name,), to="varying")
 
 
 def _pipeline_fwd_core(dispatch, stage_params, x_microbatches, wire_shape,

@@ -174,7 +174,9 @@ class ResilientTrainer:
     - ``aot``: cold-start elimination (``singa_tpu.aot``). ``True``
       keeps an ``aot/`` sidecar beside the checkpoints (a path keeps
       it there instead): the persistent compilation cache is
-      installed under ``<aot>/xla-cache``, the compiled train step is
+      installed where ``aot.cache``'s rule puts it
+      (``JAX_COMPILATION_CACHE_DIR``, else
+      ``<checkout>/.jax_compile_cache``), the compiled train step is
       exported after the first step (single-device models; a
       mesh-sharded step rides the cache alone), and a restarted
       worker's restore path deserializes a MATCHING artifact instead
@@ -293,7 +295,7 @@ class ResilientTrainer:
             from ..aot import export as _aot_export
             aot_dir = os.path.join(str(ckpt_dir), "aot") \
                 if aot is True else os.path.abspath(str(aot))
-            _aot_cache.install(_aot_cache.cache_dir_for(aot_dir))
+            _aot_cache.install()
             self._aot_store = _aot_export.AotStore(aot_dir)
             # Model._run_step consults the store before tracing a
             # fresh signature (the warm-restart load path)
@@ -456,8 +458,7 @@ class ResilientTrainer:
                 # exposed-comm, MFU-loss waterfall) rides the same
                 # capture; FLOP counts only when someone already paid
                 # for a cost analysis (never forced on the step path)
-                peak = _metrics.device_peak_flops(getattr(
-                    self._jax_device(), "device_kind", None))
+                peak = _metrics.device_peak_flops(self._jax_device())
                 self._profiler.record(
                     step, table, capture_s=time.perf_counter() - t0,
                     events=events, step_flops=self._step_flops,
@@ -881,10 +882,7 @@ class ResilientTrainer:
             if first_arr is not None and len(first_arr.shape) > 0:
                 self._m_throughput.set(first_arr.shape[0] / step_s)
             if self._step_flops:
-                dev = getattr(self.model, "dev", None)
-                peak = _metrics.device_peak_flops(getattr(
-                    getattr(dev, "jax_device", None), "device_kind",
-                    None))
+                peak = _metrics.device_peak_flops(self._jax_device())
                 if peak:
                     self._m_mfu.set(self._step_flops / step_s / peak)
         # HBM at the step boundary (bytes_in_use / peak / limit gauges)

@@ -30,10 +30,20 @@ FIXTURE = os.path.join(os.path.dirname(__file__), "data",
                        "aot_fixture")
 
 
+REAL_DEFAULT_DIR = aot_cache.default_dir
+
+
 @pytest.fixture(autouse=True)
-def _no_global_cache():
+def _no_global_cache(monkeypatch, tmp_path):
     """Every test leaves the PROCESS-GLOBAL persistent cache off, so
-    later tests' compile_seconds classifications stay 'fresh'."""
+    later tests' compile_seconds classifications stay 'fresh' — and
+    starts hermetic: no directory placed from outside, and the default
+    one (``ResilientTrainer(aot=True)`` installs it) moved off the
+    checkout's shared cache, whose XLA:CPU executables other processes
+    wrote."""
+    monkeypatch.delenv(aot_cache.ENV_DIR, raising=False)
+    monkeypatch.setattr(aot_cache, "default_dir",
+                        lambda: str(tmp_path / "default-cache"))
     yield
     aot_cache.uninstall()
 
@@ -79,6 +89,78 @@ class TestCachePolicy:
         # counters landed on the registry too
         reg = obs_metrics.default_registry()
         assert reg.get("compile_cache_hits_total").total() >= 1
+
+    def test_directory_rule_env_unset(self, tmp_path):
+        """No JAX_COMPILATION_CACHE_DIR: the cache is the default
+        directory — the fixed <checkout>/.jax_compile_cache outside
+        this module's hermetic fixture — and an explicit directory —
+        CachePolicy or Model.compile(compile_cache=) spelling — is
+        honoured."""
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        assert REAL_DEFAULT_DIR() == os.path.join(
+            repo, ".jax_compile_cache")
+        pol = aot_cache.install()
+        assert pol.directory == aot_cache.default_dir()
+        assert jax.config.jax_compilation_cache_dir == pol.directory
+        explicit = str(tmp_path / "mine")
+        assert aot_cache.install(explicit).directory == explicit
+        assert jax.config.jax_compilation_cache_dir == explicit
+        aot_cache.uninstall()
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_directory_rule_env_set(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR set: a fresh process keeps its
+        cache there — filled, counted, no other directory created — and
+        never sets a directory in code, not even an explicit one."""
+        import subprocess
+        import sys
+        env_dir, other = tmp_path / "from_env", tmp_path / "explicit"
+        code = f"""
+import jax, jax.numpy as jnp
+calls = []
+real = jax.config.update
+jax.config.update = lambda k, v: (calls.append(k), real(k, v))[1]
+from singa_tpu.aot import cache
+pol = cache.install({str(other)!r})
+assert pol.directory == {str(env_dir)!r}, pol
+assert "jax_compilation_cache_dir" not in calls, calls
+jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(7)).block_until_ready()
+assert cache.stats()["entries"] > 0, cache.stats()
+assert cache.snapshot()["misses"] > 0
+cache.uninstall()
+assert jax.config.jax_compilation_cache_dir == {str(env_dir)!r}
+print("RULE_OK")
+"""
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(env_dir))
+        proc = subprocess.run(
+            [sys.executable, "-c", code], env=env, text=True,
+            capture_output=True, timeout=120,
+            cwd=os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))))
+        assert proc.returncode == 0 and "RULE_OK" in proc.stdout, \
+            proc.stderr[-2000:]
+        assert any(n.endswith("-cache") for n in os.listdir(env_dir))
+        assert not other.exists()
+
+    def test_only_the_cache_module_names_the_directory_option(self):
+        """The rule is implemented once: no other file of the program
+        touches jax's cache-directory option."""
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        hits = []
+        for root, dirs, files in os.walk(repo):
+            dirs[:] = [d for d in dirs
+                       if not d.startswith(".") and d != "tests"]
+            for f in files:
+                if f.endswith(".py"):
+                    path = os.path.join(root, f)
+                    with open(path) as fh:
+                        if "jax_compilation_cache_dir" in fh.read():
+                            hits.append(os.path.relpath(path, repo))
+        assert hits == [os.path.join("singa_tpu", "aot", "cache.py")], \
+            hits
 
     def test_classify_without_cache_is_fresh(self):
         s = aot_cache.snapshot()

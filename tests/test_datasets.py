@@ -232,7 +232,6 @@ class TestTransforms:
 def _run_train_cnn(args, timeout=600):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = ""
     proc = subprocess.run([sys.executable, "examples/train_cnn.py"] + args,
                           cwd=ROOT, env=env, capture_output=True,
                           text=True, timeout=timeout)

@@ -4,9 +4,8 @@ The reference stack is NCHW-only (cuDNN's native layout,
 src/model/operation/convolution.h:43-90). The TPU build adds an NHWC
 activation mode (ops/layout.py) because the MXU wants channels in the
 128-lane minor dim; weights stay OIHW so checkpoints are identical.
-These tests pin the invariant that makes the bench's measured layout
-A/B (tools/tpu_probe_extra.py resnet_layout_ab) a fair comparison:
-both layouts compute the SAME function.
+These tests pin the invariant that makes a layout A/B on the chip a
+fair comparison: both layouts compute the SAME function.
 """
 
 import os

@@ -131,10 +131,25 @@ class TestRegistry:
         assert series["buckets"] == [[0.1, 1], [1.0, 3], ["+Inf", 4]]
 
     def test_device_peak_flops_table(self):
-        assert metrics.device_peak_flops("TPU v5e") == 197e12
-        assert metrics.device_peak_flops("TPU v5p and friends") == 459e12
-        assert metrics.device_peak_flops("cpu") is None
+        """Exact ``device_kind`` keys: the v5e row, None for the CPU
+        backend, and an error — never a neighbouring generation's peak
+        — for a TPU the table does not know."""
+        import types
+
+        def dev(platform, kind):
+            return types.SimpleNamespace(platform=platform,
+                                         device_kind=kind)
+
+        v5e = dev("tpu", "TPU v5 lite")
+        assert metrics.device_peak_flops(v5e) == 197e12
+        assert metrics.device_peaks(v5e)["hbm_bytes_per_s"] == 819e9
+        assert metrics.device_peak_flops(dev("cpu", "cpu")) is None
         assert metrics.device_peak_flops(None) is None
+        for kind in ("TPU v5", "TPU v5p", "TPU v5 lite pod", "TPU v9"):
+            with pytest.raises(KeyError, match="no published peaks"):
+                metrics.device_peak_flops(dev("tpu", kind))
+        assert all(row["source"] for row in
+                   metrics.DEVICE_PEAKS.values())
 
 
 class TestHeartbeatSummaries:

@@ -288,10 +288,9 @@ class TestPipeline:
         def run(x_mb, Wstack):
             return pipeline.pipeline_spmd(stage, Wstack, x_mb, "pipe")
 
-        from singa_tpu.model import _shard_map_compat_kwargs
         mapped = shard_map(run, mesh=msh,
                            in_specs=(P(), P("pipe")),
-                           out_specs=P(), **_shard_map_compat_kwargs())
+                           out_specs=P(), check_vma=False)
         x_mb = pipeline.microbatch(x, n_micro)
         out = mapped(x_mb, np.stack(Ws))
 
@@ -318,9 +317,8 @@ class TestPipeline:
             out = pipeline.pipeline_spmd(stage, Wstack, x_mb, "pipe")
             return jnp.sum(out ** 2)
 
-        from singa_tpu.model import _shard_map_compat_kwargs
         mapped = shard_map(loss, mesh=msh, in_specs=(P("pipe"), P()),
-                           out_specs=P(), **_shard_map_compat_kwargs())
+                           out_specs=P(), check_vma=False)
         x_mb = pipeline.microbatch(x, n_micro)
         g = jax.grad(lambda W: jax.jit(mapped)(W, x_mb))(Ws)
 
@@ -1455,7 +1453,6 @@ class TestHeteroPipelineStress:
         the same base key — true only when the forward tick and the
         backward recompute draw the SAME dropout masks."""
         from singa_tpu.autograd_base import CTX
-        from singa_tpu.model import _shard_map_compat_kwargs
         from singa_tpu.parallel import pipeline as pl
 
         din, dh, classes = 8, 16, 4
@@ -1532,7 +1529,7 @@ class TestHeteroPipelineStress:
             mapped = shard_map(body, mesh=msh,
                                in_specs=(P("pipe"), P(), P(), P()),
                                out_specs=(P(), P("pipe")),
-                               **_shard_map_compat_kwargs())
+                               check_vma=False)
 
             m_loss, m_grads = jax.jit(mapped)(stacked, x_mb, y_mb,
                                               base_key)

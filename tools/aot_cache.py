@@ -17,11 +17,12 @@ Commands::
     python tools/aot_cache.py --selftest
 
 ``prebuild`` is the replica-fleet warm-up: compile the spec's programs
-ONCE on a build box (persistent cache populated under
-``<aot-dir>/xla-cache``, serialized executables + digest-verified
-manifests under ``<aot-dir>``), ship the directory with the
-checkpoint, and every restart/spin-up deserializes in seconds instead
-of recompiling. ``spec lm`` prebuilds the serving prefill/decode
+ONCE on a build box (serialized executables + digest-verified
+manifests under ``<aot-dir>``; the persistent compile cache is
+populated where ``singa_tpu.aot.cache``'s rule puts it — set
+``JAX_COMPILATION_CACHE_DIR`` on the build box and on the replicas to
+a directory that ships with the checkpoint), and every restart/spin-up
+deserializes in seconds instead of recompiling. ``spec lm`` prebuilds the serving prefill/decode
 programs of a TransformerLM (mirrors ``examples/serve_transformer.py``
 's flags); ``spec mlp`` prebuilds a train step.
 
@@ -48,12 +49,7 @@ def _cpu():
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
-def _cache_dir_for(aot_dir):
-    from singa_tpu.aot import cache as aot_cache
-    return aot_cache.cache_dir_for(aot_dir)
-
-
-def _build_lm_engine(args, aot_dir):
+def _build_lm_engine(args):
     import numpy as np
 
     from singa_tpu import device, tensor
@@ -71,10 +67,10 @@ def _build_lm_engine(args, aot_dir):
     return model.compile_serving(
         slots=args.slots, max_len=args.max_len,
         prefill_len=args.prefill_len, policy=args.policy,
-        compile_cache=_cache_dir_for(aot_dir))
+        compile_cache=True)
 
 
-def _build_mlp_step(args, aot_dir):
+def _build_mlp_step(args):
     import numpy as np
 
     from singa_tpu import device, layer, model as model_mod, opt, tensor
@@ -110,8 +106,7 @@ def _build_mlp_step(args, aot_dir):
     m = MLP()
     m.set_optimizer(opt.SGD(lr=0.05, momentum=0.9))
     m.compile([tx], is_train=True, use_graph=True,
-              policy=args.policy,
-              compile_cache=_cache_dir_for(aot_dir))
+              policy=args.policy, compile_cache=True)
     m(tx, ty)       # materialise + compile the step
     return m
 
@@ -121,11 +116,11 @@ def cmd_prebuild(args):
     aot_dir = os.path.abspath(args.aot_dir)
     store = aot_export.AotStore(aot_dir)
     if args.spec == "lm":
-        engine = _build_lm_engine(args, aot_dir)
+        engine = _build_lm_engine(args)
         docs = engine.export_aot(store)
         engine.stop()
     elif args.spec == "mlp":
-        model = _build_mlp_step(args, aot_dir)
+        model = _build_mlp_step(args)
         docs = {"train_step":
                 aot_export.export_train_step(model, store)}
     else:
@@ -133,7 +128,7 @@ def cmd_prebuild(args):
               file=sys.stderr)
         return 2
     from singa_tpu.aot import cache as aot_cache
-    st = aot_cache.stats(_cache_dir_for(aot_dir))
+    st = aot_cache.stats()
     if getattr(args, "json", False):
         # machine-readable doc: an autoscaler's spawn path (or CI)
         # parses this to assert the artifacts it will warm-admit
@@ -216,6 +211,9 @@ def selftest():
     import warnings
 
     _cpu()
+    # hermetic: the GC leg prunes its own temp cache, never the
+    # directory the environment placed (jax reads this at import)
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -1,16 +1,15 @@
 #!/usr/bin/env python
-"""Render the banked BENCH_r*.json trajectory as a table (or JSON).
+"""Render a directory of BENCH_r*.json records as a table (or JSON).
 
-Every benchmark round banks one record (``tools/tpu_watch.py`` /
-``bench.py``), but until now the trajectory was invisible — reading it
-meant eyeballing raw JSON blobs. This CLI folds the records into one
+Each record is one ``bench.py`` result line (bare, or wrapped as
+``{"n": round, "parsed": {...}}``). This CLI folds the records into one
 per-round table: per-leg throughput (img/s, tok/s), MFU, peak HBM,
 compile cost, serving SLOs, and the step-timeline decomposition
 (compute/exposed-comm/idle fractions) the MFU push steers by — each
 with its delta vs the previous record, and loud ``REGRESSION`` flags
 when a throughput metric drops more than the threshold::
 
-    python tools/bench_report.py                  # repo-root records
+    python tools/bench_report.py --dir runs/      # records under runs/
     python tools/bench_report.py --dir runs/ --json
     python tools/bench_report.py --threshold 0.10
     python tools/bench_report.py --selftest       # CI gate

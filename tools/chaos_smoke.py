@@ -1974,6 +1974,12 @@ def main():
                   f"({budget.remaining():.0f}s budget left)")
             sdir = os.path.join(root, name)
             os.makedirs(sdir)
+            # every child of the scenario keeps its compile cache here,
+            # placed from outside (singa_tpu.aot.cache's rule): shared
+            # between a run and its restart, and empty when the
+            # scenario's "cold" start begins
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = \
+                os.path.join(sdir, "xla-cache")
             try:
                 fn(sdir, budget)
             except TimeoutError:
