@@ -856,10 +856,15 @@ class Model(Layer):
             # eager dispatch can record (reference per-node timing)
             if self.dev.verbosity < 2 and \
                     os.environ.get("SINGA_EAGER_FIRST_STEP", "0") != "1":
+                from .observability import spans as _obs_spans
                 try:
                     tensor_args = [a for a in args if isinstance(a, Tensor)]
-                    self._eager_out = self._abstract_call(
-                        tensor_args, lambda: self.train_one_batch(*args))
+                    # the one part of set-up no other span covers (it
+                    # runs once, before the first compiled step)
+                    with _obs_spans.span("train_step.rehearse"):
+                        self._eager_out = self._abstract_call(
+                            tensor_args,
+                            lambda: self.train_one_batch(*args))
                     self._step_ready = True
                 except Exception as e:
                     import warnings

@@ -1,4 +1,4 @@
-"""Nested wall-clock trace spans and the crash flight recorder.
+"""Nested trace spans on two clocks, and the crash flight recorder.
 
 Spans are the narrative counterpart of the metrics registry: where a
 histogram says "step time p50 is 42 ms", the span stream says "step 317
@@ -17,6 +17,16 @@ JSON record::
   watchdog worker (which copies its caller's context) inherits it.
 - **Nesting** rides the same contextvar mechanism: a span records the
   name of the innermost enclosing span as ``parent``.
+- **Two clocks.** Besides its wall-clock record, every span enters a
+  ``jax.profiler.TraceAnnotation`` of its own name. With no profiler
+  session that is one enabled-check; under ``jax.profiler.trace`` the
+  span appears on the host plane (``/host:CPU``) of the trace, on the
+  device planes' clock, so a gap in the device's op line can be laid
+  against what the host was inside.
+- **Phases** (:meth:`span.phase`) split an open span without records of
+  their own: each is an annotation ``<span>.<phase>`` and a sum under
+  the span's ``phases`` — a 25 ticks/s serving engine names its tick's
+  parts without evicting the ring any faster.
 - **The flight recorder** is a bounded ring (``deque(maxlen=...)``) of
   the most recent records. It costs one append per span — nothing is
   written anywhere until :meth:`FlightRecorder.dump` is called, which
@@ -30,9 +40,12 @@ JSON record::
   mirrors every record to disk as it happens — what
   ``examples/train_cnn.py --telemetry`` turns on.
 
-Everything here is host-side stdlib; nothing imports jax, so span cost
-is a couple of ``perf_counter`` calls plus a dict build (~µs) and the
-compiled step's ``n_traces`` pin is untouched.
+Everything here is host-side: stdlib plus ``jax.profiler``'s
+annotation class, bound on the first span (a process whose jax cannot
+be imported keeps the wall-clock half). Span cost is a couple of
+``perf_counter`` calls, the annotation's enabled-check and a dict build
+(~µs); nothing is traced or dispatched, so the compiled step's
+``n_traces`` pin is untouched.
 """
 
 from __future__ import annotations
@@ -60,6 +73,26 @@ _OPEN_LOCK = threading.Lock()
 _OPEN = {}
 
 DEFAULT_CAPACITY = 1024
+
+# jax.profiler.TraceAnnotation, bound by the first span (None: not tried
+# yet; False: this process has no jax to import)
+_ANNOTATION = None
+
+
+def _annotate(name):
+    """An entered profiler annotation called ``name``, or None."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = False
+    if not _ANNOTATION:
+        return None
+    annotation = _ANNOTATION(name)
+    annotation.__enter__()
+    return annotation
 
 
 @contextlib.contextmanager
@@ -267,13 +300,24 @@ class span:
 
     On exit a record lands in the default recorder, stamped with the
     ambient :func:`context` attrs, the enclosing span's name, and — when
-    the body raised — the exception type under ``error``."""
+    the body raised — the exception type under ``error``. ``attrs`` may
+    be added to until then. Under a profiler session the span is also a
+    host event of the trace, under its name."""
 
-    __slots__ = ("name", "attrs", "_t0", "_token", "_wall0", "_ctx")
+    __slots__ = ("name", "attrs", "_t0", "_token", "_wall0", "_ctx",
+                 "_annotation", "_phases")
 
     def __init__(self, name, **attrs):
         self.name = name
         self.attrs = attrs
+        self._phases = None
+
+    def phase(self, name):
+        """Context manager for one part of this (open) span: the
+        profiler annotation ``<span name>.<name>``, and its elapsed
+        time added to the span record's ``phases[name]`` (summed when
+        entered more than once). It makes no record of its own."""
+        return _Phase(self, name)
 
     def __enter__(self):
         self._token = _STACK.set(_STACK.get() + (self.name,))
@@ -281,11 +325,14 @@ class span:
         self._ctx = _CTX.get()
         with _OPEN_LOCK:
             _OPEN[id(self)] = self
+        self._annotation = _annotate(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         with _OPEN_LOCK:
             _OPEN.pop(id(self), None)
         stack = _STACK.get()
@@ -299,9 +346,36 @@ class span:
             rec.update(ctx)
         if self.attrs:
             rec.update(self.attrs)
+        if self._phases:
+            rec["phases"] = self._phases
         if exc_type is not None:
             rec["error"] = exc_type.__name__
         _RECORDER.record(rec)
+        return False
+
+
+class _Phase:
+    """One entry of :meth:`span.phase` (see there)."""
+
+    __slots__ = ("_span", "_name", "_annotation", "_t0")
+
+    def __init__(self, owner, name):
+        self._span = owner
+        self._name = name
+
+    def __enter__(self):
+        self._annotation = _annotate(f"{self._span.name}.{self._name}")
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        phases = self._span._phases
+        if phases is None:
+            phases = self._span._phases = {}
+        phases[self._name] = phases.get(self._name, 0.0) + dur
         return False
 
 

@@ -242,7 +242,9 @@ class _EngineBase:
             "included — this is what the caller feels)")
         self._tok_lat = self._reg.histogram(
             "serve_token_seconds",
-            "per-token decode latency (one continuous-batching tick)")
+            "the decode part of one continuous-batching tick: input "
+            "packing, the program, the logits' read-back and host "
+            "sampling for every active slot (the serve.decode span)")
 
     # -- admission ---------------------------------------------------------
     def _admit(self, req):
@@ -1077,11 +1079,12 @@ class ServingEngine(_EngineBase):
                trace_id=None):
         """Queue one generation request; returns its
         :class:`~singa_tpu.serving.scheduler.ServeFuture` (``.result()``
-        is ``{"tokens": [...], "prompt_len": n, "ttft_s": ...}``).
-        Prompts longer than ``prefill_len`` are rejected here, typed
-        and synchronous. ``trace_id`` names the request in the
-        per-request flight-recorder trace (the gateway mints one per
-        HTTP request); defaults to ``req-<n>``."""
+        is ``{"tokens": [...], "prompt_len": n, "ttft_s": ...,
+        "queue_wait_s": ...}``; its ``token_times`` holds one stamp per
+        generated token). Prompts longer than ``prefill_len`` are
+        rejected here, typed and synchronous. ``trace_id`` names the
+        request in the per-request flight-recorder trace (the gateway
+        mints one per HTTP request); defaults to ``req-<n>``."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -1444,7 +1447,8 @@ class ServingEngine(_EngineBase):
             # the dying replica finished it between snapshot and send
             req.future.set_result({"tokens": list(req.tokens),
                                    "prompt_len": int(prompt.size),
-                                   "ttft_s": None})
+                                   "ttft_s": None,
+                                   "queue_wait_s": None})
             self.queue.finish("completed")
             return req.future
         self._injects.append((req, {"pos": pos, "tok": tok}, arrays,
@@ -1740,7 +1744,9 @@ class ServingEngine(_EngineBase):
                 "tokens": list(req.tokens),
                 "prompt_len": int(req.prompt.size),
                 "ttft_s": (req.first_token_at - req.submitted_at
-                           if req.first_token_at else None)})
+                           if req.first_token_at else None),
+                "queue_wait_s": (req.admitted_at - req.submitted_at
+                                 if req.admitted_at else None)})
         elif status == "timed_out":
             # same type a queued expiry raises: callers catch ONE
             # timeout error regardless of where the deadline hit
@@ -1751,18 +1757,21 @@ class ServingEngine(_EngineBase):
             req.future.set_error(ServingError(status))
         self.queue.finish(status)
 
-    def _sample_and_place(self, req, out_row, slot_idx, pos,
+    def _sample_and_place(self, req, out_row, slot_idx, pos, at,
                           alloc=None):
         """Shared first-token/next-token bookkeeping: resolve the
         program output row into a token, record, finish or keep the
         slot hot. ``out_row`` is a logits vector on the single-device
         engines and an in-graph-argmax'd token id on the sharded ones
-        — the ONE place that split is decided. ``alloc`` is the paged
-        block reservation riding the slot."""
+        — the ONE place that split is decided. ``at`` is the token's
+        stamp (``ServeFuture.token_times``): the clock as the program's
+        output reached the host, one reading for the whole batch.
+        ``alloc`` is the paged block reservation riding the slot."""
         tok = int(out_row) if self.sharded else _decode.sample_logits(
             out_row, temperature=req.temperature, top_k=req.top_k,
             rng=req.rng)
         req.tokens.append(tok)
+        req.future.token_times.append(at)
         self._tokens_total.inc()
         done = (len(req.tokens) >= req.max_new_tokens or
                 (req.eos_id is not None and tok == req.eos_id))
@@ -1772,19 +1781,31 @@ class ServingEngine(_EngineBase):
             self._finish_slot(slot_idx)
 
     def _tick(self):
+        """One continuous-batching tick, as ONE ``serve.tick`` span
+        whose phases name the host work around the two programs;
+        ``serve.prefill`` and ``serve.decode`` are spans of their own
+        inside it (docs/serving.md has the table)."""
+        with _spans.span("serve.tick", tick=self._tick_count,
+                         admitted=0, active=0) as tick:
+            self._tick_body(tick)
+
+    def _tick_body(self, tick):
         now = time.monotonic()
         tick_t0 = now
         # 0) deadline drain: migrate what the budget cannot cover;
         #    then place validated snapshot injects into free slots
         if self._draining and self._handoff is not None:
-            self._drain_handoff_pass(now)
+            with tick.phase("handoff"):
+                self._drain_handoff_pass(now)
         if self._injects:
-            self._place_injects(now)
+            with tick.phase("inject"):
+                self._place_injects(now)
         # 1) reap deadline-expired in-flight requests (their slot frees
         #    mid-batch — that is the continuous part of the batching)
-        for i, slot in enumerate(self._slots):
-            if slot is not None and slot["req"].expired(now):
-                self._finish_slot(i, status="timed_out")
+        with tick.phase("reap"):
+            for i, slot in enumerate(self._slots):
+                if slot is not None and slot["req"].expired(now):
+                    self._finish_slot(i, status="timed_out")
 
         # 2) admit: fill free slots, a fixed-width prefill batch per tick.
         #    A paged engine additionally gates each pop on the block
@@ -1793,170 +1814,197 @@ class ServingEngine(_EngineBase):
         #    over-commit the pool; a request that doesn't fit right now
         #    stays at the head of the queue (backpressure, FIFO-fair —
         #    live sequences are never evicted to make room).
-        free = [i for i, s in enumerate(self._slots) if s is None]
-        if free and len(self.queue) > 0:
-            admit = None
-            if self.kv_layout == "paged":
-                def admit(req):
-                    try:
-                        req._alloc = self._mgr.admit(
-                            req.prompt,
-                            int(req.prompt.size) + req.max_new_tokens)
-                        return True
-                    except BlockPoolExhausted:
-                        return False
-            batch = self.queue.pop_batch(
-                min(len(free), self.prefill_batch), now, admit=admit)
-            if batch:
-                try:
-                    with _spans.span("serve.prefill", n=len(batch)):
-                        self._run_prefill(batch, free)
-                except Exception as e:
-                    # popped-but-not-yet-slotted requests are in
-                    # neither the queue nor the slot table: the crash
-                    # path can't see them, so fail them HERE or they
-                    # hang forever (exactly-once applies to errors too)
-                    self._fail_batch(batch, e)
-                    raise
+        batch = []
+        with tick.phase("admit"):
+            free = [i for i, s in enumerate(self._slots) if s is None]
+            if free and len(self.queue) > 0:
+                admit = None
+                if self.kv_layout == "paged":
+                    def admit(req):
+                        try:
+                            req._alloc = self._mgr.admit(
+                                req.prompt,
+                                int(req.prompt.size) + req.max_new_tokens)
+                            return True
+                        except BlockPoolExhausted:
+                            return False
+                batch = self.queue.pop_batch(
+                    min(len(free), self.prefill_batch), now, admit=admit)
+            tick.attrs["admitted"] = len(batch)
+            tick.attrs["queue_depth"] = len(self.queue)
+        if batch:
+            try:
+                with _spans.span("serve.prefill", n=len(batch)) as sp:
+                    self._run_prefill(batch, free, sp)
+            except Exception as e:
+                # popped-but-not-yet-slotted requests are in
+                # neither the queue nor the slot table: the crash
+                # path can't see them, so fail them HERE or they
+                # hang forever (exactly-once applies to errors too)
+                self._fail_batch(batch, e)
+                raise
 
         # 2b) disaggregated pools: offer freshly-prefilled slots to the
         #     decode pool BEFORE paying a local decode tick (an
         #     accepted transfer frees the slot; a declined one decodes
         #     here — the colocate fallback)
         if self._transfer is not None:
-            self._transfer_pass()
+            with tick.phase("transfer"):
+                self._transfer_pass()
 
         # 3) decode: one token for EVERY active slot, one fixed program
-        if any(s is not None for s in self._slots):
+        active = tick.attrs["active"] = self.active_slots()
+        if active:
             t0 = time.perf_counter()
-            with _spans.span("serve.decode"):
-                self._run_decode()
+            with _spans.span("serve.decode") as sp:
+                self._run_decode(sp)
             # a PROFILED tick's dispatch runs under an active trace:
             # its inflated latency must not read as an SLO regression
             # (the sampling cost is serve_profile_capture_seconds)
             if not self._profiling_now:
                 self._tok_lat.observe(time.perf_counter() - t0)
             self._decode_steps.inc()
-        self._occupancy.set(self.active_slots())
-        self._sample_hbm()
-        # 4) cadence crash armor + the drain pass's tick-cost EWMA
-        if self.snapshot_every and \
-                self._tick_count % self.snapshot_every == 0:
-            self._checkpoint_inflight()
-        dt = time.monotonic() - tick_t0
-        self._tick_ewma = dt if not self._tick_ewma \
-            else 0.8 * self._tick_ewma + 0.2 * dt
+        with tick.phase("post"):
+            self._occupancy.set(self.active_slots())
+            self._sample_hbm()
+            # 4) cadence crash armor + the drain pass's tick-cost EWMA
+            if self.snapshot_every and \
+                    self._tick_count % self.snapshot_every == 0:
+                self._checkpoint_inflight()
+            dt = time.monotonic() - tick_t0
+            self._tick_ewma = dt if not self._tick_ewma \
+                else 0.8 * self._tick_ewma + 0.2 * dt
 
-    def _run_prefill(self, batch, free):
+    def _run_prefill(self, batch, free, sp):
+        """``sp`` is the open ``serve.prefill`` span; both layouts
+        split it into ``pack`` (the numpy inputs), ``dispatch`` (the
+        program call until it returns), ``readback`` (its output to
+        the host) and ``place`` (first tokens sampled, slots filled)."""
         if self.kv_layout == "paged":
-            return self._run_prefill_paged(batch, free)
-        return self._run_prefill_ring(batch, free)
+            return self._run_prefill_paged(batch, free, sp)
+        return self._run_prefill_ring(batch, free, sp)
 
-    def _run_prefill_ring(self, batch, free):
-        B, S = self.prefill_batch, self.prefill_len
-        tokens = np.zeros((B, S), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        slot_ids = np.zeros((B,), np.int32)
-        valid = np.zeros((B,), bool)
-        placed = []
-        for b, req in enumerate(batch):
-            n = req.prompt.size
-            tokens[b, :n] = req.prompt
-            lengths[b] = n
-            slot_ids[b] = free[b]
-            valid[b] = True
-            placed.append((req, free[b]))
-            self._prefill_tok.inc(int(n))
-        n0 = self._prefill_rec["n_traces"]
-        t0c = time.perf_counter()
-        cc0 = _cache_counts()
-        self._cache, out = _quiet_donation(
-            self._prefill, self._P, self._cache, tokens, lengths,
-            slot_ids, valid)
-        if self._prefill_rec["n_traces"] > n0:
-            _attribute_trace(self._prefill_rec, self._reg,
-                             "serve_prefill",
-                             [tokens, lengths, slot_ids, valid],
-                             ("tokens", "lengths", "slot_ids",
-                              "valid"), t0c, cc0)
-        # (B, V) logits single-device; (B,) in-graph argmax tokens when
-        # sharded (the full-vocab array never reaches the host)
-        out = np.asarray(out)
-        for b, (req, slot_idx) in enumerate(placed):
-            req.first_token_at = time.monotonic()
-            self._ttft.observe(req.first_token_at - req.submitted_at)
-            self._prefills.inc()
-            if self._trace_requests:
-                _spans.event("request.prefill", request=req.trace_id,
-                             slot=slot_idx,
-                             prompt_len=int(req.prompt.size))
-            # the first generated token sits at position prompt_len;
-            # its k/v are written by the NEXT decode tick
-            self._sample_and_place(req, out[b], slot_idx,
-                                   pos=int(req.prompt.size))
+    def _run_prefill_ring(self, batch, free, sp):
+        with sp.phase("pack"):
+            B, S = self.prefill_batch, self.prefill_len
+            tokens = np.zeros((B, S), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            slot_ids = np.zeros((B,), np.int32)
+            valid = np.zeros((B,), bool)
+            placed = []
+            for b, req in enumerate(batch):
+                n = req.prompt.size
+                tokens[b, :n] = req.prompt
+                lengths[b] = n
+                slot_ids[b] = free[b]
+                valid[b] = True
+                placed.append((req, free[b]))
+                self._prefill_tok.inc(int(n))
+        with sp.phase("dispatch"):
+            n0 = self._prefill_rec["n_traces"]
+            t0c = time.perf_counter()
+            cc0 = _cache_counts()
+            self._cache, out = _quiet_donation(
+                self._prefill, self._P, self._cache, tokens, lengths,
+                slot_ids, valid)
+            if self._prefill_rec["n_traces"] > n0:
+                _attribute_trace(self._prefill_rec, self._reg,
+                                 "serve_prefill",
+                                 [tokens, lengths, slot_ids, valid],
+                                 ("tokens", "lengths", "slot_ids",
+                                  "valid"), t0c, cc0)
+        with sp.phase("readback"):
+            # (B, V) logits single-device; (B,) in-graph argmax tokens
+            # when sharded (the full-vocab array never reaches the host)
+            out = np.asarray(out)
+        with sp.phase("place"):
+            at = time.monotonic()
+            for b, (req, slot_idx) in enumerate(placed):
+                req.first_token_at = at
+                self._ttft.observe(at - req.submitted_at)
+                self._prefills.inc()
+                if self._trace_requests:
+                    _spans.event("request.prefill",
+                                 request=req.trace_id, slot=slot_idx,
+                                 prompt_len=int(req.prompt.size))
+                # the first generated token sits at position prompt_len;
+                # its k/v are written by the NEXT decode tick
+                self._sample_and_place(req, out[b], slot_idx,
+                                       pos=int(req.prompt.size), at=at)
 
-    def _run_prefill_paged(self, batch, free):
+    def _run_prefill_paged(self, batch, free, sp):
         """Paged admission: each popped request arrives with its block
         reservation already taken (the pop predicate); a prefix-cache
         hit enters the compiled program with ``start > 0`` and only
         its SUFFIX tokens — the shared span's prefill is skipped
         entirely, its K/V served from the refcounted cached blocks."""
-        B, S = self.prefill_batch, self.prefill_len
-        tokens = np.zeros((B, S), np.int32)
-        starts = np.zeros((B,), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        tables = np.zeros((B, self._max_blocks), np.int32)
-        valid = np.zeros((B,), bool)
-        placed = []
-        for b, req in enumerate(batch):
-            alloc = req._alloc
-            suffix = req.prompt[alloc.shared_tokens:]
-            tokens[b, :suffix.size] = suffix
-            starts[b] = alloc.shared_tokens
-            lengths[b] = suffix.size
-            tables[b, :len(alloc.blocks)] = alloc.blocks
-            valid[b] = True
-            placed.append((req, free[b], alloc))
-            self._prefill_tok.inc(int(suffix.size))
-            if alloc.shared_tokens:
-                self._prefix_hits.inc()
-                self._prefix_tokens.inc(alloc.shared_tokens)
-        n0 = self._prefill_rec["n_traces"]
-        t0c = time.perf_counter()
-        cc0 = _cache_counts()
-        self._cache, out = _quiet_donation(
-            self._prefill, self._P, self._cache, tables, tokens,
-            starts, lengths, valid)
-        if self._prefill_rec["n_traces"] > n0:
-            _attribute_trace(self._prefill_rec, self._reg,
-                             "serve_prefill",
-                             [tables, tokens, starts, lengths, valid],
-                             ("tables", "tokens", "starts", "lengths",
-                              "valid"), t0c, cc0)
-        out = np.asarray(out)      # (B, V) logits, or (B,) sharded toks
-        self._update_pool_gauges()
-        for b, (req, slot_idx, alloc) in enumerate(placed):
-            req._alloc = None      # the slot owns the reservation now
-            req.first_token_at = time.monotonic()
-            self._ttft.observe(req.first_token_at - req.submitted_at)
-            self._prefills.inc()
-            if self._trace_requests:
-                _spans.event("request.prefill", request=req.trace_id,
-                             slot=slot_idx,
-                             prompt_len=int(req.prompt.size),
-                             prefix_hit_tokens=int(alloc.shared_tokens))
-            # the first generated token sits at position prompt_len;
-            # its k/v are written by the NEXT decode tick
-            self._sample_and_place(req, out[b], slot_idx,
-                                   pos=int(req.prompt.size),
-                                   alloc=alloc)
+        with sp.phase("pack"):
+            B, S = self.prefill_batch, self.prefill_len
+            tokens = np.zeros((B, S), np.int32)
+            starts = np.zeros((B,), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            tables = np.zeros((B, self._max_blocks), np.int32)
+            valid = np.zeros((B,), bool)
+            placed = []
+            for b, req in enumerate(batch):
+                alloc = req._alloc
+                suffix = req.prompt[alloc.shared_tokens:]
+                tokens[b, :suffix.size] = suffix
+                starts[b] = alloc.shared_tokens
+                lengths[b] = suffix.size
+                tables[b, :len(alloc.blocks)] = alloc.blocks
+                valid[b] = True
+                placed.append((req, free[b], alloc))
+                self._prefill_tok.inc(int(suffix.size))
+                if alloc.shared_tokens:
+                    self._prefix_hits.inc()
+                    self._prefix_tokens.inc(alloc.shared_tokens)
+        with sp.phase("dispatch"):
+            n0 = self._prefill_rec["n_traces"]
+            t0c = time.perf_counter()
+            cc0 = _cache_counts()
+            self._cache, out = _quiet_donation(
+                self._prefill, self._P, self._cache, tables, tokens,
+                starts, lengths, valid)
+            if self._prefill_rec["n_traces"] > n0:
+                _attribute_trace(self._prefill_rec, self._reg,
+                                 "serve_prefill",
+                                 [tables, tokens, starts, lengths,
+                                  valid],
+                                 ("tables", "tokens", "starts",
+                                  "lengths", "valid"), t0c, cc0)
+        with sp.phase("readback"):
+            out = np.asarray(out)  # (B, V) logits, or (B,) sharded toks
+        with sp.phase("place"):
+            at = time.monotonic()
+            self._update_pool_gauges()
+            for b, (req, slot_idx, alloc) in enumerate(placed):
+                req._alloc = None  # the slot owns the reservation now
+                req.first_token_at = at
+                self._ttft.observe(at - req.submitted_at)
+                self._prefills.inc()
+                if self._trace_requests:
+                    _spans.event(
+                        "request.prefill", request=req.trace_id,
+                        slot=slot_idx, prompt_len=int(req.prompt.size),
+                        prefix_hit_tokens=int(alloc.shared_tokens))
+                # the first generated token sits at position prompt_len;
+                # its k/v are written by the NEXT decode tick
+                self._sample_and_place(req, out[b], slot_idx,
+                                       pos=int(req.prompt.size), at=at,
+                                       alloc=alloc)
 
-    def _run_decode(self):
+    def _run_decode(self, sp):
+        """``sp`` is the open ``serve.decode`` span; both layouts split
+        it into ``pack`` (the numpy inputs, n-gram drafting),
+        ``dispatch`` (the program call until it returns), ``readback``
+        (wait for the device, logits to the host) and ``sample`` (the
+        per-slot loop: sampling, accept walk, events, finishing)."""
         if self.kv_layout == "paged":
-            return self._run_decode_paged()
-        return self._run_decode_ring()
+            return self._run_decode_paged(sp)
+        return self._run_decode_ring(sp)
 
-    def _run_decode_paged(self):
+    def _run_decode_paged(self, sp):
         """One verify tick: every active slot's row is its pending
         token plus up to ``speculative_k - 1`` n-gram drafts; the ONE
         compiled program writes all rows' k/v and scores every
@@ -1966,129 +2014,141 @@ class ServingEngine(_EngineBase):
         parity invariant). Rejected drafts leave stale rows at
         positions past the new ``pos``; the position-exact paged mask
         keeps them unreachable until overwritten."""
-        W, K = self.slots, self._spec_width
-        tokens = np.zeros((W, K), np.int32)
-        positions = np.zeros((W,), np.int32)
-        counts = np.zeros((W,), np.int32)
-        tables = np.zeros((W, self._max_blocks), np.int32)
-        rows = {}
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            req = slot["req"]
-            n = 1
-            if K > 1 and req.temperature == 0 \
-                    and not self._spec_throttled:
-                # greedy-only: the accept rule below is exact for
-                # argmax; a sampled request decodes one token per tick
-                # (its per-request rng draw order must not change)
-                remaining = req.max_new_tokens - len(req.tokens)
-                room = self.max_len - slot["pos"]
-                n = max(1, min(K, remaining, room))
-            row = [slot["tok"]]
-            if n > 1:
-                row += _decode.ngram_propose(
-                    list(req.prompt) + req.tokens, n - 1)
-                self._spec_proposed.inc(n - 1)
-            tokens[i, :len(row)] = row
-            positions[i] = slot["pos"]
-            counts[i] = len(row)
-            tables[i, :len(slot["alloc"].blocks)] = \
-                slot["alloc"].blocks
-            rows[i] = row
-        n0 = self._decode_rec["n_traces"]
-        t0c = time.perf_counter()
-        cc0 = _cache_counts()
-        self._cache, out = _quiet_donation(
-            self._decode, self._P, self._cache, tables, tokens,
-            positions, counts)
-        if self._decode_rec["n_traces"] > n0:
-            _attribute_trace(self._decode_rec, self._reg,
-                             "serve_decode",
-                             [tables, tokens, positions, counts],
-                             ("tables", "tokens", "positions",
-                              "counts"), t0c, cc0)
-        # (W, K, V) logits single-device; (W, K) in-graph argmax tokens
-        # when sharded — the accept walk below only ever needs argmax
-        out = np.asarray(out)
-        for i, slot in enumerate(list(self._slots)):
-            if slot is None:
-                continue
-            req, row, cnt = slot["req"], rows[i], int(counts[i])
-            emitted = 0
-            done = False
-            for j in range(cnt):
-                tok = int(out[i, j]) if self.sharded else \
-                    _decode.sample_logits(
-                        out[i, j], temperature=req.temperature,
-                        top_k=req.top_k, rng=req.rng)
-                req.tokens.append(tok)
-                self._tokens_total.inc()
-                emitted += 1
-                done = (len(req.tokens) >= req.max_new_tokens or
-                        (req.eos_id is not None and tok == req.eos_id))
-                if done:
-                    break
-                if j + 1 < cnt and row[j + 1] == tok:
-                    continue        # draft accepted: its k/v row is
-                break               # already correct; score the next
-            if cnt > 1:
-                self._spec_accepted.inc(emitted - 1)
-                proposed = self._spec_proposed.total()
-                if proposed:
-                    self._spec_ratio.set(
-                        self._spec_accepted.total() / proposed)
-            n_tok = len(req.tokens)
-            if self._trace_requests and \
-                    (n_tok < 16 or n_tok % 16 < emitted):
-                _spans.event("request.decode_tick",
-                             request=req.trace_id, slot=i,
-                             pos=slot["pos"] + emitted,
-                             emitted=emitted)
-            self._slots[i] = {"req": req, "pos": slot["pos"] + emitted,
-                              "tok": req.tokens[-1],
-                              "alloc": slot["alloc"]}
-            if done:
-                self._finish_slot(i)
-
-    def _run_decode_ring(self):
-        W = self.slots
-        tokens = np.zeros((W,), np.int32)
-        positions = np.zeros((W,), np.int32)
-        active = np.zeros((W,), bool)
-        for i, slot in enumerate(self._slots):
-            if slot is not None:
-                tokens[i] = slot["tok"]
+        with sp.phase("pack"):
+            W, K = self.slots, self._spec_width
+            tokens = np.zeros((W, K), np.int32)
+            positions = np.zeros((W,), np.int32)
+            counts = np.zeros((W,), np.int32)
+            tables = np.zeros((W, self._max_blocks), np.int32)
+            rows = {}
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                req = slot["req"]
+                n = 1
+                if K > 1 and req.temperature == 0 \
+                        and not self._spec_throttled:
+                    # greedy-only: the accept rule below is exact for
+                    # argmax; a sampled request decodes one token per tick
+                    # (its per-request rng draw order must not change)
+                    remaining = req.max_new_tokens - len(req.tokens)
+                    room = self.max_len - slot["pos"]
+                    n = max(1, min(K, remaining, room))
+                row = [slot["tok"]]
+                if n > 1:
+                    row += _decode.ngram_propose(
+                        list(req.prompt) + req.tokens, n - 1)
+                    self._spec_proposed.inc(n - 1)
+                tokens[i, :len(row)] = row
                 positions[i] = slot["pos"]
-                active[i] = True
-        n0 = self._decode_rec["n_traces"]
-        t0c = time.perf_counter()
-        cc0 = _cache_counts()
-        self._cache, out = _quiet_donation(
-            self._decode, self._P, self._cache, tokens, positions,
-            active)
-        if self._decode_rec["n_traces"] > n0:
-            _attribute_trace(self._decode_rec, self._reg,
-                             "serve_decode",
-                             [tokens, positions, active],
-                             ("tokens", "positions", "active"), t0c,
-                             cc0)
-        out = np.asarray(out)      # (W, V) logits, or (W,) sharded toks
-        for i, slot in enumerate(list(self._slots)):
-            if slot is None:
-                continue
-            # decimated past the first 16 tokens: a 4-slot engine
-            # generating hundreds of tokens per request would otherwise
-            # evict the whole flight-recorder ring (capacity 1024) with
-            # ticks, beheading every request lane and crash blackbox
-            n_tok = len(slot["req"].tokens)
-            if self._trace_requests and \
-                    (n_tok < 16 or n_tok % 16 == 0):
-                _spans.event("request.decode_tick",
-                             request=slot["req"].trace_id, slot=i,
-                             pos=slot["pos"] + 1)
-            self._sample_and_place(slot["req"], out[i], i,
-                                   pos=slot["pos"] + 1)
+                counts[i] = len(row)
+                tables[i, :len(slot["alloc"].blocks)] = \
+                    slot["alloc"].blocks
+                rows[i] = row
+        with sp.phase("dispatch"):
+            n0 = self._decode_rec["n_traces"]
+            t0c = time.perf_counter()
+            cc0 = _cache_counts()
+            self._cache, out = _quiet_donation(
+                self._decode, self._P, self._cache, tables, tokens,
+                positions, counts)
+            if self._decode_rec["n_traces"] > n0:
+                _attribute_trace(self._decode_rec, self._reg,
+                                 "serve_decode",
+                                 [tables, tokens, positions, counts],
+                                 ("tables", "tokens", "positions",
+                                  "counts"), t0c, cc0)
+        with sp.phase("readback"):
+            # (W, K, V) logits single-device; (W, K) in-graph argmax tokens
+            # when sharded — the accept walk below only ever needs argmax
+            out = np.asarray(out)
+        with sp.phase("sample"):
+            at = time.monotonic()
+            for i, slot in enumerate(list(self._slots)):
+                if slot is None:
+                    continue
+                req, row, cnt = slot["req"], rows[i], int(counts[i])
+                emitted = 0
+                done = False
+                for j in range(cnt):
+                    tok = int(out[i, j]) if self.sharded else \
+                        _decode.sample_logits(
+                            out[i, j], temperature=req.temperature,
+                            top_k=req.top_k, rng=req.rng)
+                    req.tokens.append(tok)
+                    req.future.token_times.append(at)
+                    self._tokens_total.inc()
+                    emitted += 1
+                    done = (len(req.tokens) >= req.max_new_tokens or
+                            (req.eos_id is not None and tok == req.eos_id))
+                    if done:
+                        break
+                    if j + 1 < cnt and row[j + 1] == tok:
+                        continue        # draft accepted: its k/v row is
+                    break               # already correct; score the next
+                if cnt > 1:
+                    self._spec_accepted.inc(emitted - 1)
+                    proposed = self._spec_proposed.total()
+                    if proposed:
+                        self._spec_ratio.set(
+                            self._spec_accepted.total() / proposed)
+                n_tok = len(req.tokens)
+                if self._trace_requests and \
+                        (n_tok < 16 or n_tok % 16 < emitted):
+                    _spans.event("request.decode_tick",
+                                 request=req.trace_id, slot=i,
+                                 pos=slot["pos"] + emitted,
+                                 emitted=emitted)
+                self._slots[i] = {"req": req, "pos": slot["pos"] + emitted,
+                                  "tok": req.tokens[-1],
+                                  "alloc": slot["alloc"]}
+                if done:
+                    self._finish_slot(i)
+
+    def _run_decode_ring(self, sp):
+        with sp.phase("pack"):
+            W = self.slots
+            tokens = np.zeros((W,), np.int32)
+            positions = np.zeros((W,), np.int32)
+            active = np.zeros((W,), bool)
+            for i, slot in enumerate(self._slots):
+                if slot is not None:
+                    tokens[i] = slot["tok"]
+                    positions[i] = slot["pos"]
+                    active[i] = True
+        with sp.phase("dispatch"):
+            n0 = self._decode_rec["n_traces"]
+            t0c = time.perf_counter()
+            cc0 = _cache_counts()
+            self._cache, out = _quiet_donation(
+                self._decode, self._P, self._cache, tokens, positions,
+                active)
+            if self._decode_rec["n_traces"] > n0:
+                _attribute_trace(self._decode_rec, self._reg,
+                                 "serve_decode",
+                                 [tokens, positions, active],
+                                 ("tokens", "positions", "active"), t0c,
+                                 cc0)
+        with sp.phase("readback"):
+            # (W, V) logits, or (W,) sharded toks
+            out = np.asarray(out)
+        with sp.phase("sample"):
+            at = time.monotonic()
+            for i, slot in enumerate(list(self._slots)):
+                if slot is None:
+                    continue
+                # decimated past the first 16 tokens: a 4-slot engine
+                # generating hundreds of tokens per request would otherwise
+                # evict the whole flight-recorder ring (capacity 1024) with
+                # ticks, beheading every request lane and crash blackbox
+                n_tok = len(slot["req"].tokens)
+                if self._trace_requests and \
+                        (n_tok < 16 or n_tok % 16 == 0):
+                    _spans.event("request.decode_tick",
+                                 request=slot["req"].trace_id, slot=i,
+                                 pos=slot["pos"] + 1)
+                self._sample_and_place(slot["req"], out[i], i,
+                                       pos=slot["pos"] + 1, at=at)
 
 
 class BatchServingEngine(_EngineBase):

@@ -7,7 +7,8 @@ edge of the engine, not a web framework. Endpoints:
   ``{"prompt": [ids...], "max_new_tokens": n, "temperature": t,
   "top_k": k, "eos_id": id, "seed": s, "timeout": secs}`` (everything
   but ``prompt`` optional); 200 with the completed
-  ``{"tokens": [...], "prompt_len": n, "ttft_s": ...}``.
+  ``{"tokens": [...], "prompt_len": n, "ttft_s": ...,
+  "queue_wait_s": ...}``.
 - ``POST /v1/predict`` — stateless engines. ``{"input": nested list}``;
   200 with ``{"output": nested list}`` (or ``"outputs"`` for
   multi-output models).

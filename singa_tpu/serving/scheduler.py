@@ -15,8 +15,11 @@ registry):
 - ``serve_requests_total{status=...}`` (counter) — terminal outcome of
   every request: ``completed`` | ``rejected`` | ``timed_out`` |
   ``failed`` | ``cancelled``;
-- admission wait rides the engine's TTFT histogram (queue time is part
-  of time-to-first-token, which is what the user feels).
+- ``serve_queue_wait_seconds`` (histogram) — submit until
+  :meth:`RequestQueue.pop_batch` took the request off the queue; the
+  same wait is also inside the engine's TTFT histogram (queue time is
+  part of time-to-first-token, which is what the user feels), so TTFT
+  splits into waiting and prefill.
 """
 
 from __future__ import annotations
@@ -126,7 +129,21 @@ class ServeFuture:
     ``result(timeout)`` blocks for the response and re-raises the
     request's error. ``deliveries`` counts fulfillment attempts — the
     exactly-once chaos test asserts it is 1 for every request, and a
-    second delivery attempt raises instead of silently overwriting."""
+    second delivery attempt raises instead of silently overwriting.
+
+    ``token_times`` holds one ``time.monotonic()`` stamp per token the
+    engine that owns this future generated, in order: the engine reads
+    the clock once when a program's output has reached the host and
+    gives that reading to every token of that prefill batch or decode
+    tick (tokens a speculative tick emits together share it). The first
+    entry is the request's ``first_token_at``; consecutive differences
+    are the inter-token gaps. The engine's thread appends while the
+    request runs; read it once the future is done. A request injected
+    or migrated into another engine continues under a NEW future there:
+    that list starts empty and holds stamps only for the tokens that
+    engine generates (its clock), so it lines up with the tail of
+    ``result()["tokens"]``; the tokens the snapshot carried have no
+    stamp and the continuation's ``ttft_s`` is None."""
 
     def __init__(self):
         self._event = threading.Event()
@@ -134,6 +151,7 @@ class ServeFuture:
         self._result = None
         self._error = None
         self.deliveries = 0
+        self.token_times: list = []
 
     def _fulfill(self, result=None, error=None):
         with self._lock:
@@ -194,6 +212,7 @@ class Request:
         # (a fail-fast probe), the opposite of no deadline
         self.deadline = (self.submitted_at + float(timeout)
                          if timeout is not None else None)
+        self.admitted_at = None         # set where pop_batch takes it
         self.first_token_at = None      # set by the engine at prefill
         self.future = ServeFuture()
         self.tokens: list = []          # generated ids (engine-owned)
@@ -217,6 +236,10 @@ class RequestQueue:
         self._outcomes = self._reg.counter(
             "serve_requests_total",
             "terminal request outcomes", labels=("status",))
+        self._wait = self._reg.histogram(
+            "serve_queue_wait_seconds",
+            "request submit until it was taken off the queue for a "
+            "slot (the waiting part of serve_ttft_seconds)")
 
     def finish(self, status):
         """Record a request's terminal outcome (engine calls this at
@@ -269,6 +292,13 @@ class RequestQueue:
                 taken.append(self._q.popleft())
             depth = len(self._q)
         self._depth.set(depth)
+        if taken:
+            # a fresh reading: `now` is the caller's tick start, and a
+            # request can have been submitted since
+            admitted_at = time.monotonic()
+            for req in taken:
+                req.admitted_at = admitted_at
+                self._wait.observe(admitted_at - req.submitted_at)
         for req in expired:
             req.future.set_error(RequestTimeout(
                 "deadline passed while queued"))
