@@ -10,7 +10,8 @@ long-context machinery the TPU build makes first-class:
   streaming k/v blocks through VMEM with running max/sum accumulators, so
   the S×S score matrix never hits HBM). Elsewhere (CPU mesh tests) an
   identical-math `lax.scan` implementation runs. Backward recomputes
-  per-block scores (flash style) via the scan path under `jax.custom_vjp`.
+  per-block scores (flash style) under `jax.custom_vjp`: two more Pallas
+  kernels on TPU, the scan path elsewhere.
 - :func:`ring_attention` — q/k/v sharded over a 'seq' mesh axis inside
   `shard_map`; k/v blocks rotate around the ICI ring via `lax.ppermute`
   while each device folds them into its online-softmax accumulator.
@@ -181,7 +182,17 @@ def _scan_flash_bwd(q, k, v, out, lse, g, causal, scale, block_k):
 # the dK/dV kernel — so VMEM holds O(block · D) regardless of sequence
 # length (the whole point of the long-context path). TPU grids iterate the
 # trailing dimension sequentially, which is what makes the scratch-ref
-# accumulator pattern below sound.
+# accumulator pattern below sound. The leading dimension steps over groups
+# of (batch, head) slices (`_heads_per_step`); every block and scratch has
+# that group as its first axis.
+#
+# Matrix products take their operands in the dtype the kernel was given
+# and accumulate in float32; m, l, lse, delta, the exp and the mask are
+# float32 always (the v5e's vector unit has no bf16 arithmetic). On the
+# v5e a float32 product at Mosaic's default precision is one bf16 pass
+# too (measured, PR 25: the same error against plain attention, 3e-3 of
+# the largest value, and the same time either way), so float32 callers
+# differ from bf16 ones in bytes, not in passes.
 # ---------------------------------------------------------------------------
 
 # Test hook: run kernels in interpreter mode so CPU CI validates the exact
@@ -191,11 +202,10 @@ FORCE_PALLAS_INTERPRET = False
 
 _DECLINE_LOGGED = set()
 
-# Mosaic requires the last two dims of every block to be (8k, 128k) or
-# equal to the array's dims, so per-row statistics (m/l/lse/delta) are
-# carried lane-broadcast at this width — the same layout the canonical
-# TPU flash kernels use. Interpreter mode never enforced this; the real
-# chip does.
+# Lane width of a vector register. The kernels keep their running row
+# statistics (m, l) in VMEM as (block_q, _LANES) with every lane of a row
+# holding the same scalar; between HBM and the kernels lse and delta
+# travel as (B*H, 1, S) with the sequence on the lanes — 4 bytes a row.
 _LANES = 128
 
 
@@ -230,11 +240,20 @@ def _env_block(name):
 
 
 def _pick_blocks(Sq, Sk):
-    """Largest Pallas block sizes that tile the sequence lengths.
+    """Largest Pallas block sizes, up to 512, that tile the sequence
+    lengths.
 
-    Measured on TPU v5e on 2026-07-31, before this round's records
-    (B8 H8 S1024 D64, fwd+bwd): (512, 256) runs 3.1x faster than the (128, 128) minimum —
-    bigger q tiles amortise the k/v stream and keep the MXU busy.
+    Measured on TPU v5e on 2026-10-01 (PR 25; forward + backward of the
+    three kernels, causal, ms a call): at B4 H16 S1024 D64 bf16, the
+    benchmark's train cell, (512, 512) 0.698, (1024, 1024) 0.720,
+    (256, 256) 0.754, and the (512, 256) of the earlier pick 0.87;
+    (512, 512) also leads at S2048 and ties at S512. Larger tiles share
+    a row's statistics and a grid step over more keys; past 512 the
+    tiles the diagonal crosses waste more than that saves there. At
+    S1024 float32 and D128 ran another 8-18 % faster at (1024, 1024),
+    which this rule does not pick: one shape each is no rule on head
+    size or dtype (PERF.md section 6 has the table, section 7 the open
+    question). :func:`_heads_per_step` fits the step to the VMEM.
     Falls back through 256 to the 128-lane minimum when the sequence
     length doesn't divide, so short or odd-length shapes still get the
     fused kernel whenever a legal tiling exists. Override for tuning
@@ -242,8 +261,8 @@ def _pick_blocks(Sq, Sk):
     does not divide the sequence length is warned about (once per
     shape) and ignored, so a bad knob can never silently cost the
     fused kernel."""
-    bq = min(next((b for b in (512, 256, 128) if Sq % b == 0), 128), Sq)
-    bk = min(next((b for b in (256, 128) if Sk % b == 0), 128), Sk)
+    bq, bk = (min(next((b for b in (512, 256, 128) if S % b == 0), 128), S)
+              for S in (Sq, Sk))
     # a partial override keeps the adaptive pick for the other axis
     out = []
     for name, env, adaptive, S in (("Q", _env_block("SINGA_FLASH_BLOCK_Q"),
@@ -306,12 +325,90 @@ def _interpret():
     return FORCE_PALLAS_INTERPRET or jax.default_backend() != "tpu"
 
 
-def _causal_positions(qi, kj, block_q, block_k):
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return q_pos, k_pos
+_NT = (((1,), (1,)), ((), ()))    # a @ b.T: contract the last axis of both
+_NN = (((1,), (0,)), ((), ()))    # a @ b
+
+
+def _mxu(a, b, dims=_NN):
+    """Matrix product of two tiles in the dtype they arrive in (bf16 under
+    bf16_mixed, float32 for float32 callers), accumulated in float32."""
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scaled(q, scale):
+    """q * scale in q's dtype, so the (block_q, block_k) score tile needs
+    no multiply of its own. Exact for a power-of-two scale (head sizes 16,
+    64, 256); otherwise one rounding of q, the same in all three kernels,
+    so the backward's recomputed probabilities match the saved lse."""
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
+def _rows_to_lanes(x):
+    """(n, _LANES) lane-broadcast row statistic -> (1, n), rows on lanes."""
+    return x.T[:1]
+
+
+def _lanes_to_rows(x):
+    """(1, n) statistic with rows on lanes -> (n, _LANES) lane-broadcast."""
+    return jnp.broadcast_to(x, (_LANES, x.shape[1])).T
+
+
+def _across(stat, n):
+    """(rows, _LANES) lane-broadcast statistic -> (rows, n). Whole lane
+    tiles repeat and a narrower tile is a slice, neither of which moves
+    data between lanes (a ``stat[:, :1]`` broadcast does, once a row group
+    a tile, and was most of the forward's time)."""
+    if n % _LANES == 0:
+        return pltpu.repeat(stat, n // _LANES, axis=1)
+    if n < _LANES:
+        return stat[:, :n]
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], n))
+
+
+def _visible(shape, q_axis, bound):
+    """Causal mask of a score tile whose first query lies ``bound``
+    positions after its first key: key index - query index <= bound.
+    ``q_axis`` is the tile axis the queries lie on (0 for s = q k^T, 1
+    for the dK/dV kernel's s^T = k q^T)."""
+    q_idx = lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_idx = lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return k_idx - q_idx <= bound
+
+
+def _on_causal_tiles(causal, qi, kj, block_q, block_k, split, body):
+    """Run ``body(parts)`` on the (qi, kj) tile unless the causal prune
+    skips it. ``parts`` are the sub-tiles to compute, each (q0, nq, k0,
+    nk, bound): rows q0..q0+nq of the q block against rows k0..k0+nk of
+    the k block, masked by :func:`_visible` with ``bound`` unless it is
+    None. A tile wholly above the diagonal does not run and one wholly
+    below it runs whole and unmasked. A tile the diagonal crosses runs
+    whole under a mask — or, where it is square and the diagonal runs
+    from corner to corner, as two parts that leave the masked upper right
+    quarter out: halves of the q rows (``split`` 'q': a row's statistics
+    are visited once) or of the k rows ('k', for the dK/dV kernel)."""
+    whole = (0, block_q, 0, block_k)
+    if not causal:
+        body([whole + (None,)])
+        return
+    first_k, first_q = kj * block_k, qi * block_q
+    below = first_k + block_k - 1 <= first_q
+    crosses = jnp.logical_and(first_k <= first_q + block_q - 1,
+                              jnp.logical_not(below))
+    half = block_q // 2
+    if block_q == block_k and half % _LANES == 0:
+        # square blocks: the tiles the diagonal crosses are qi == kj
+        parts = ([(0, half, 0, half, 0), (half, half, 0, block_k, half)]
+                 if split == "q" else
+                 [(0, block_q, 0, half, 0), (half, half, half, half, 0)])
+    else:
+        parts = [whole + (first_q - first_k,)]
+    pl.when(below)(lambda: body([whole + (None,)]))
+    pl.when(crosses)(lambda: body(parts))
+
+
+def _last_k_block(qi, block_q, block_k, nkb):
+    """Last k block a causal q block attends to."""
+    return jnp.minimum(nkb - 1, ((qi + 1) * block_q - 1) // block_k)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -325,6 +422,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     everything — so the write happens at the final k block."""
     qi = pl.program_id(1)
     kj = pl.program_id(2)
+    pruned = causal and offset_ref is None
+    heads, _, D = acc_ref.shape
 
     @pl.when(kj == 0)
     def _init():
@@ -332,123 +431,182 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    if causal and offset_ref is None:
-        run = kj * block_k <= (qi + 1) * block_q - 1
-    else:
-        run = kj >= 0
+    def _compute(parts):
+        for q0, nq, k0, nk, bound in parts:
+            rows, keys = slice(q0, q0 + nq), slice(k0, k0 + nk)
+            if bound is not None:
+                visible = _visible((nq, nk), 0, bound)
+            for h in range(heads):
+                v = v_ref[h, keys]
+                s = _mxu(_scaled(q_ref[h, rows], scale), k_ref[h, keys],
+                         _NT)
+                if bound is not None:
+                    s = jnp.where(visible, s, _NEG_INF)
+                # m/l live lane-broadcast as (block_q, _LANES)
+                m = m_ref[h, rows]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - _across(m_new, nk))
+                if bound is not None and offset_ref is not None:
+                    # a FULLY-masked row has m_new == _NEG_INF (finite),
+                    # making exp(s - m_new) == 1 on masked entries — zero
+                    # them explicitly (offset grids are not pruned, so
+                    # such blocks do occur)
+                    p = jnp.where(visible, p, 0.0)
+                alpha = jnp.exp(m - m_new)
+                l_ref[h, rows] = l_ref[h, rows] * alpha + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                acc_ref[h, rows] = acc_ref[h, rows] * _across(
+                    alpha, D) + _mxu(p.astype(v.dtype), v)
+                m_ref[h, rows] = m_new
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)          # (block_q, D)
-        kblk = k_ref[0].astype(jnp.float32)       # (block_k, D)
-        vblk = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, kblk.T, preferred_element_type=jnp.float32) * scale
-        mask = None
-        if causal:
-            q_pos, k_pos = _causal_positions(qi, kj, block_q, block_k)
-            if offset_ref is not None:
-                q_pos = q_pos + offset_ref[0, 0]
-            mask = k_pos <= q_pos
-            s = jnp.where(mask, s, _NEG_INF)
-        # m/l live lane-broadcast as (block_q, _LANES); every lane of a
-        # row holds the same scalar
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new[:, :1])
-        if mask is not None and offset_ref is not None:
-            # a FULLY-masked row has m_new == _NEG_INF (finite), making
-            # exp(s - m_new) == 1 on masked entries — zero them explicitly
-            # (offset grids are not pruned, so such blocks do occur)
-            p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jnp.dot(
-            p, vblk, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    if causal and offset_ref is not None:
+        _compute([(0, block_q, 0, block_k,
+                   qi * block_q - kj * block_k + offset_ref[0, 0])])
+    else:
+        _on_causal_tiles(causal, qi, kj, block_q, block_k, "q", _compute)
 
     # the last k-block this q-block attends to writes the result
-    last = jnp.minimum(nkb - 1, ((qi + 1) * block_q - 1) // block_k) \
-        if (causal and offset_ref is None) else nkb - 1
+    last = _last_k_block(qi, block_q, block_k, nkb) if pruned else nkb - 1
 
     @pl.when(kj == last)
     def _write():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        for h in range(heads):
+            l = jnp.maximum(l_ref[h], 1e-30)
+            o_ref[h] = (acc_ref[h] / _across(l, D)).astype(o_ref.dtype)
+            lse_ref[h] = _rows_to_lanes(m_ref[h] + jnp.log(l))
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *,
+                         dq_ref, acc_ref, lse_col, delta_col, *,
                          causal, scale, block_q, block_k, nkb):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
+    heads = acc_ref.shape[0]
 
     @pl.when(kj == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        # the statistics arrive with the rows on lanes; this kernel's
+        # tiles have the q rows on sublanes: turn them once a q block
+        for h in range(heads):
+            lse_col[h] = _lanes_to_rows(lse_ref[h])
+            delta_col[h] = _lanes_to_rows(delta_ref[h])
 
-    run = (kj * block_k <= (qi + 1) * block_q - 1) if causal else kj >= 0
+    def _compute(parts):
+        for q0, nq, k0, nk, bound in parts:
+            rows, keys = slice(q0, q0 + nq), slice(k0, k0 + nk)
+            if bound is not None:
+                visible = _visible((nq, nk), 0, bound)
+            for h in range(heads):
+                k = k_ref[h, keys]
+                s = _mxu(_scaled(q_ref[h, rows], scale), k, _NT)
+                if bound is not None:
+                    s = jnp.where(visible, s, _NEG_INF)
+                p = jnp.exp(s - _across(lse_col[h, rows], nk))
+                dp = _mxu(g_ref[h, rows], v_ref[h, keys], _NT)
+                ds = p * (dp - _across(delta_col[h, rows], nk))
+                acc_ref[h, rows] += _mxu(ds.astype(k.dtype), k)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        kblk = k_ref[0].astype(jnp.float32)
-        vblk = v_ref[0].astype(jnp.float32)
-        g = g_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, kblk.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos, k_pos = _causal_positions(qi, kj, block_q, block_k)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jnp.dot(g, vblk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        acc_ref[...] += jnp.dot(ds, kblk,
-                                preferred_element_type=jnp.float32)
+    _on_causal_tiles(causal, qi, kj, block_q, block_k, "q", _compute)
 
-    last = jnp.minimum(nkb - 1, ((qi + 1) * block_q - 1) // block_k) \
-        if causal else nkb - 1
+    last = _last_k_block(qi, block_q, block_k, nkb) if causal else nkb - 1
 
     @pl.when(kj == last)
     def _write():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *,
                           causal, scale, block_q, block_k, nqb):
+    """Works on the transposed tile s^T = k q^T (block_k, block_q): the q
+    rows lie on lanes, as lse and delta arrive, so a row's statistic
+    broadcasts down the sublanes and p^T g, ds^T q need no transpose."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
+    heads = dk_acc.shape[0]
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = ((qi + 1) * block_q - 1 >= kj * block_k) if causal else qi >= 0
+    def _compute(parts):
+        for q0, nq, k0, nk, bound in parts:
+            rows, keys = slice(q0, q0 + nq), slice(k0, k0 + nk)
+            if bound is not None:
+                visible = _visible((nk, nq), 1, bound)
+            for h in range(heads):
+                q = q_ref[h, rows]
+                g = g_ref[h, rows]
+                st = _mxu(k_ref[h, keys], _scaled(q, scale), _NT)
+                if bound is not None:
+                    st = jnp.where(visible, st, _NEG_INF)
+                pt = jnp.exp(st - lse_ref[h, :, rows])
+                dpt = _mxu(v_ref[h, keys], g, _NT)
+                dst = pt * (dpt - delta_ref[h, :, rows])
+                dv_acc[h, keys] += _mxu(pt.astype(g.dtype), g)
+                dk_acc[h, keys] += _mxu(dst.astype(q.dtype), q)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        kblk = k_ref[0].astype(jnp.float32)
-        vblk = v_ref[0].astype(jnp.float32)
-        g = g_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, kblk.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos, k_pos = _causal_positions(qi, kj, block_q, block_k)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jnp.dot(g, vblk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dk_acc[...] += jnp.dot(ds.T, q,
-                               preferred_element_type=jnp.float32)
-        dv_acc[...] += jnp.dot(p.T, g,
-                               preferred_element_type=jnp.float32)
+    _on_causal_tiles(causal, qi, kj, block_q, block_k, "k", _compute)
 
     @pl.when(qi == nqb - 1)
     def _write():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# Scoped VMEM a Mosaic call may use on the v5e unless told otherwise. Asking
+# for more was measured to cost more than it buys (the ops around the call
+# slow down), so the kernels size a grid step to fit it.
+_VMEM_SCOPED = 16 * 2 ** 20
+
+
+def _heads_per_step(n, block_q, block_k, D, itemsize):
+    """How many of the ``n`` (batch, head) slices one grid step works on.
+
+    The body is unrolled over them, so the scheduler overlaps one head's
+    matrix products with another's exp and reductions, and the fixed cost
+    of a grid step is shared. Forward + backward at (4, 16, 1024, 64)
+    bf16 causal, v5e, 2026-10-01: (512, 512) tiles take 0.758 ms one head
+    a step and 0.698 ms four; (256, 256) tiles 1.24 ms and, eight a
+    step, 0.754 ms; a loop that is not unrolled gains nothing. The price
+    is Mosaic's compile time, about 0.65 s a layer's three kernels at one
+    head and 2.0 s at four, paid when a step compiles cold. As many as
+    divide ``n`` and fit the scoped VMEM: the double-buffered blocks and
+    the scratch of the largest kernel (head size padded to whole lane
+    tiles, as VMEM holds it), with room for the float32 score tiles;
+    8 at most, which bounds the unrolled code."""
+    row = -(-D // _LANES) * _LANES
+    per_head = max(
+        # dQ: q, g, dq and k, v blocks twice; acc, lse, delta scratch
+        2 * (3 * block_q + 2 * block_k) * row * itemsize
+        + block_q * (row + 2 * _LANES) * 4,
+        # dK/dV: q, g and k, v, dk, dv blocks twice; two accumulators
+        2 * (2 * block_q + 4 * block_k) * row * itemsize
+        + 2 * block_k * row * 4)
+    # six live score-sized float32 tiles and 2 MiB of slack
+    room = _VMEM_SCOPED - 2 ** 21 - 6 * block_q * block_k * 4
+    return next(h for h in (8, 4, 2, 1)
+                if h == 1 or (n % h == 0 and h * per_head <= room))
+
+
+def _clamped_blocks(q, k, block_q, block_k):
+    block_q = min(block_q, q.shape[2])
+    block_k = min(block_k, k.shape[2])
+    assert q.shape[2] % block_q == 0 and k.shape[2] % block_k == 0, \
+        "flash kernel needs sequence divisible by block size"
+    return block_q, block_k
+
+
+def _kv_index_map(pruned, block_q, block_k, nkb):
+    """Index map of the K/V blocks on a (b, q block, k block) grid. Steps
+    the causal prune skips name the last block that ran, so they fetch
+    nothing."""
+    if pruned:
+        return lambda b, i, j: (
+            b, jnp.minimum(j, _last_k_block(i, block_q, block_k, nkb)), 0)
+    return lambda b, i, j: (b, j, 0)
 
 
 def _pallas_flash_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
@@ -460,13 +618,25 @@ def _pallas_flash_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
     shards (ring attention feeds the visiting k/v block's offset per ring
     step). With a delta, causal masking uses global positions and the
     k grid is not pruned."""
+    block_q, block_k = _clamped_blocks(q, k, block_q, block_k)
+    return _flash_fwd_call(q, k, v, pos_delta, causal=causal,
+                           scale=float(scale), block_q=block_q,
+                           block_k=block_k, interpret=_interpret())
+
+
+# The calls are jitted so that a model of many layers traces and lowers a
+# kernel once a shape, not once a layer (the unrolled bodies take a few
+# tenths of a second to trace; 24 layers, three traces of a train step).
+_KERNEL_STATICS = ("causal", "scale", "block_q", "block_k", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _flash_fwd_call(q, k, v, pos_delta, *, causal, scale, block_q, block_k,
+                    interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    assert Sq % block_q == 0 and Sk % block_k == 0, \
-        "flash kernel needs sequence divisible by block size"
     nkb = Sk // block_k
+    hb = _heads_per_step(B * H, block_q, block_k, D, q.dtype.itemsize)
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * H, Sk, D)
     vr = v.reshape(B * H, Sk, D)
@@ -483,10 +653,11 @@ def _pallas_flash_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
                           block_q=block_q, block_k=block_k, nkb=nkb,
                           offset_ref=off_ref)
 
+    kv_map = _kv_index_map(causal and not with_off, block_q, block_k, nkb)
     in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((hb, block_q, D), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((hb, block_k, D), kv_map),
+        pl.BlockSpec((hb, block_k, D), kv_map),
     ]
     operands = [qr, kr, vr]
     if with_off:
@@ -495,87 +666,97 @@ def _pallas_flash_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
             operands
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, Sq // block_q, nkb),
+        grid=(B * H // hb, Sq // block_q, nkb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((hb, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((hb, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((hb, block_q, D), jnp.float32),
+            pltpu.VMEM((hb, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((hb, block_q, _LANES), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_fwd",
     )(*operands)
-    return out.reshape(B, H, Sq, D), lse[..., 0].reshape(B, H, Sq)
+    return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
 
 def _pallas_flash_bwd(q, k, v, out, lse, g, causal, scale,
                       block_q=128, block_k=128):
+    block_q, block_k = _clamped_blocks(q, k, block_q, block_k)
+    return _flash_bwd_call(q, k, v, out, lse, g, causal=causal,
+                           scale=float(scale), block_q=block_q,
+                           block_k=block_k, interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _flash_bwd_call(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
+                    interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    assert Sq % block_q == 0 and Sk % block_k == 0, \
-        "flash kernel needs sequence divisible by block size"
     nqb, nkb = Sq // block_q, Sk // block_k
+    hb = _heads_per_step(B * H, block_q, block_k, D, q.dtype.itemsize)
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * H, Sk, D)
     vr = v.reshape(B * H, Sk, D)
     gr = g.reshape(B * H, Sq, D)
-    # row stats enter the kernels lane-broadcast (see _LANES)
-    lser = jnp.broadcast_to(lse.reshape(B * H, Sq)[..., None],
-                            (B * H, Sq, _LANES))
-    delta = jnp.broadcast_to(
-        jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                axis=-1).reshape(B * H, Sq)[..., None],
-        (B * H, Sq, _LANES))
+    # row statistics enter the kernels with the sequence on lanes
+    lser = lse.reshape(B * H, 1, Sq)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(B * H, 1, Sq)
 
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, x, y: (b, x, 0))
-    rowspec = pl.BlockSpec((1, block_q, _LANES), lambda b, x, y: (b, x, 0))
+    kv_map = _kv_index_map(causal, block_q, block_k, nkb)
+
+    def first_q(i, j):
+        # the dK/dV grid's q side: steps before the first q block a k
+        # block's prune lets run name that block, so they fetch nothing
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    qspec = pl.BlockSpec((hb, block_q, D), lambda b, i, j: (b, i, 0))
+    rowspec = pl.BlockSpec((hb, 1, block_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k, nkb=nkb),
-        grid=(B * H, nqb, nkb),
+        grid=(B * H // hb, nqb, nkb),
         in_specs=[
             qspec,
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((hb, block_k, D), kv_map),
+            pl.BlockSpec((hb, block_k, D), kv_map),
             qspec, rowspec, rowspec,
         ],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=_interpret(),
+        scratch_shapes=[pltpu.VMEM((hb, block_q, D), jnp.float32),
+                        pltpu.VMEM((hb, block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((hb, block_q, _LANES), jnp.float32)],
+        interpret=interpret,
         name="flash_bwd_dq",
     )(qr, kr, vr, gr, lser, delta)
 
-    kvspec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    kvspec = pl.BlockSpec((hb, block_k, D), lambda b, j, i: (b, j, 0))
+    qside = pl.BlockSpec((hb, block_q, D),
+                         lambda b, j, i: (b, first_q(i, j), 0))
+    rowside = pl.BlockSpec((hb, 1, block_q),
+                           lambda b, j, i: (b, 0, first_q(i, j)))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k, nqb=nqb),
-        grid=(B * H, nkb, nqb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-            kvspec, kvspec,
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0)),
-        ],
+        grid=(B * H // hb, nkb, nqb),
+        in_specs=[qside, kvspec, kvspec, qside, rowside, rowside],
         out_specs=[kvspec, kvspec],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=_interpret(),
+        scratch_shapes=[pltpu.VMEM((hb, block_k, D), jnp.float32),
+                        pltpu.VMEM((hb, block_k, D), jnp.float32)],
+        interpret=interpret,
         name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lser, delta)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),

@@ -457,12 +457,12 @@ class TestUlyssesAttention:
 
 class TestPickBlocks:
     """Block-size selection for the Pallas kernels (tuned on v5e:
-    (512,256) measured 3.1x faster than (128,128) at S=1024)."""
+    (512,512) measured fastest at S=1024, PR 25)."""
 
     def test_large_sequences_get_big_tiles(self):
         from singa_tpu.ops.attention import _pick_blocks
-        assert _pick_blocks(1024, 1024) == (512, 256)
-        assert _pick_blocks(512, 512) == (512, 256)
+        assert _pick_blocks(1024, 1024) == (512, 512)
+        assert _pick_blocks(512, 512) == (512, 512)
 
     def test_fallback_chain_to_lane_minimum(self):
         from singa_tpu.ops.attention import _pick_blocks
@@ -483,7 +483,7 @@ class TestPickBlocks:
             self, monkeypatch):
         from singa_tpu.ops.attention import _pick_blocks
         monkeypatch.setenv("SINGA_FLASH_BLOCK_Q", "512")
-        assert _pick_blocks(1024, 1024) == (512, 256)
+        assert _pick_blocks(1024, 1024) == (512, 512)
         monkeypatch.delenv("SINGA_FLASH_BLOCK_Q")
         monkeypatch.setenv("SINGA_FLASH_BLOCK_K", "128")
         assert _pick_blocks(1024, 1024) == (512, 128)
@@ -497,10 +497,10 @@ class TestPickBlocks:
         monkeypatch.setenv("SINGA_FLASH_BLOCK_Q", "huge")
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
-            assert _pick_blocks(1024, 1024) == (512, 256)
+            assert _pick_blocks(1024, 1024) == (512, 512)
         assert any("not a positive integer" in str(x.message) for x in w)
         monkeypatch.setenv("SINGA_FLASH_BLOCK_Q", "-64")
-        assert _pick_blocks(1024, 1024) == (512, 256)
+        assert _pick_blocks(1024, 1024) == (512, 512)
 
     def test_oversized_env_value_clamps_to_sequence(self, monkeypatch):
         """env block > S must clamp, not reach the kernel raw (an
@@ -508,7 +508,7 @@ class TestPickBlocks:
         output is never written)."""
         from singa_tpu.ops.attention import _pick_blocks
         monkeypatch.setenv("SINGA_FLASH_BLOCK_Q", "2048")
-        assert _pick_blocks(1024, 1024) == (1024, 256)
+        assert _pick_blocks(1024, 1024) == (1024, 512)
         monkeypatch.setenv("SINGA_FLASH_BLOCK_K", "4096")
         assert _pick_blocks(1024, 1024) == (1024, 1024)
 
@@ -520,10 +520,10 @@ class TestPickBlocks:
         attention._ENV_BLOCK_WARNED.clear()
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
-            assert attention._pick_blocks(1024, 1024) == (512, 256)
+            assert attention._pick_blocks(1024, 1024) == (512, 512)
             # warned exactly once per (axis, value, length), even
             # across repeated dispatches of the same shape
-            assert attention._pick_blocks(1024, 1024) == (512, 256)
+            assert attention._pick_blocks(1024, 1024) == (512, 512)
         hits = [x for x in w if "does not divide" in str(x.message)]
         assert len(hits) == 1, [str(x.message) for x in w]
 
@@ -561,3 +561,148 @@ class TestPickBlocks:
         for got, want in zip(g, gr):
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=2e-3, atol=2e-3)
+
+
+def _eqns_named(jaxpr, primitive):
+    """Every equation of that primitive in a jaxpr, nested jaxprs
+    included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_eqns_named(sub, primitive))
+    return found
+
+
+def _flash_fwd_bwd(q, k, v, g, causal=True):
+    out, vjp = jax.vjp(lambda a, b, c: ATTN.flash_attention(a, b, c, causal),
+                       q, k, v)
+    return (out,) + vjp(g)
+
+
+@pytest.fixture
+def interpret_kernels():
+    prev = ATTN.FORCE_PALLAS_INTERPRET
+    ATTN.FORCE_PALLAS_INTERPRET = True
+    yield
+    ATTN.FORCE_PALLAS_INTERPRET = prev
+
+
+_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+@pytest.mark.pallas
+class TestPallasComputeDtype:
+    """The kernels feed the MXU in the dtype they were given and carry
+    lse/delta at 4 bytes a row (PR 25). Interpret mode runs the exact
+    kernel bodies; the jaxpr cases read what the chip would be handed."""
+
+    B, H, S, D = 1, 2, 256, 64
+    _cache = {}
+
+    def _inputs(self, dtype, S=S, seed=21):
+        rng = np.random.RandomState(seed)
+        return tuple(jnp.asarray(rng.randn(self.B, self.H, S, self.D),
+                                 dtype) for _ in range(4))
+
+    def _calls(self, dtype):
+        """name -> the pallas_call equation, of forward + backward."""
+        if dtype not in self._cache:
+            jaxpr = jax.make_jaxpr(_flash_fwd_bwd)(*self._inputs(dtype))
+            self._cache[dtype] = _eqns_named(jaxpr.jaxpr, "pallas_call")
+        return self._cache[dtype]
+
+    @pytest.mark.parametrize("name", ("out", "dq", "dk", "dv"))
+    @pytest.mark.parametrize("causal", (True, False))
+    @pytest.mark.parametrize("blocks", ((128, 64), (64, 128), (256, 256)))
+    def test_bf16_matches_float32_naive(self, blocks, causal, name,
+                                        monkeypatch, interpret_kernels):
+        """(a) bf16 q, k, v, g over a multi-block grid at D 64 (tiles
+        below, on and above the diagonal; square blocks take the
+        diagonal tiles in two parts) against float32 naive attention of
+        the same bf16 values."""
+        key = ("values", blocks, causal)
+        if key not in self._cache:
+            monkeypatch.setenv("SINGA_FLASH_BLOCK_Q", str(blocks[0]))
+            monkeypatch.setenv("SINGA_FLASH_BLOCK_K", str(blocks[1]))
+            q, k, v, g = self._inputs(jnp.bfloat16, 2 * max(blocks))
+            got = _flash_fwd_bwd(q, k, v, g, causal)
+            q32, k32, v32, g32 = (t.astype(jnp.float32)
+                                  for t in (q, k, v, g))
+            out, vjp = jax.vjp(
+                lambda a, b, c: naive_attention(a, b, c, causal),
+                q32, k32, v32)
+            self._cache[key] = dict(zip(
+                ("out", "dq", "dk", "dv"),
+                zip(got, (out,) + vjp(g32))))
+        got, want = self._cache[key][name]
+        assert got.dtype == jnp.bfloat16
+        got, want = np.asarray(got, np.float32), np.asarray(want)
+        assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()
+
+    @pytest.mark.parametrize("kernel", _KERNELS)
+    @pytest.mark.parametrize("dtype", (jnp.bfloat16, jnp.float32),
+                             ids=("bf16", "f32"))
+    def test_products_take_the_input_dtype(self, dtype, kernel,
+                                           interpret_kernels):
+        """(b) every matrix product inside a kernel has operands of the
+        dtype the kernel was given and a float32 result: bf16 callers get
+        one-pass bf16 products, float32 callers (the ring path, the 2e-5
+        cases above) float32 products."""
+        (call,) = [c for c in self._calls(dtype)
+                   if c.params["name"] == kernel]
+        dots = _eqns_named(call.params["jaxpr"], "dot_general")
+        assert len(dots) >= {"flash_fwd": 2, "flash_bwd_dq": 3,
+                             "flash_bwd_dkv": 4}[kernel]
+        for dot in dots:
+            assert [v.aval.dtype for v in dot.invars] == [dtype, dtype], dot
+            assert dot.outvars[0].aval.dtype == jnp.float32, dot
+
+    @pytest.mark.parametrize("kernel", _KERNELS)
+    def test_row_statistics_cross_hbm_narrow(self, kernel,
+                                             interpret_kernels):
+        """(c) no operand or result of a kernel is a float32 array of
+        shape (..., S, 128): with bf16 q, k, v, g the only float32 arrays
+        are lse and delta, at no more than 8 lanes a row."""
+        (call,) = [c for c in self._calls(jnp.bfloat16)
+                   if c.params["name"] == kernel]
+        stats = [v.aval for v in list(call.invars) + list(call.outvars)
+                 if v.aval.dtype == jnp.float32]
+        assert len(stats) == (1 if kernel == "flash_fwd" else 2)
+        for aval in stats:
+            assert tuple(aval.shape[-2:]) != (self.S, 128), aval
+            assert aval.size * 4 <= self.B * self.H * self.S * 4 * 8, aval
+
+    def test_kernel_names_are_what_the_benchmark_reads(
+            self, interpret_kernels):
+        """(d) forward + backward is three pallas_calls, each under a name
+        that `attention_roofline` (and chip_smoke's HLO check) finds."""
+        import json
+        import os
+        import re
+        metric = os.path.join(os.path.dirname(__file__), os.pardir,
+                              "benchmarks", "layer_metrics",
+                              "attention_roofline.json")
+        with open(metric) as f:
+            patterns = json.load(f)["args"]["patterns"]
+        assert sorted(patterns) == sorted(_KERNELS)
+        names = [c.params["name"] for c in self._calls(jnp.bfloat16)]
+        assert sorted(names) == sorted(_KERNELS)
+        for name in names:
+            assert any(re.search(p, name) for p in patterns), name
+
+    def test_lse_keeps_its_shape_and_meaning(self, interpret_kernels):
+        """The forward still returns (out, lse[B, H, S]) — ring attention
+        merges partials on it."""
+        q, k, v, _ = self._inputs(jnp.float32)
+        scale = 1.0 / np.sqrt(self.D)
+        out, lse = ATTN._pallas_flash_fwd(q, k, v, True, scale, 128, 64)
+        assert lse.shape == (self.B, self.H, self.S)
+        assert lse.dtype == jnp.float32
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        s = jnp.where(jnp.tril(jnp.ones((self.S, self.S), bool)), s, -1e30)
+        np.testing.assert_allclose(
+            np.asarray(lse),
+            np.asarray(jax.scipy.special.logsumexp(s, axis=-1)),
+            rtol=1e-5, atol=1e-5)
