@@ -37,6 +37,13 @@ Phases (each prints one JSON line with platform, device_kind, n_devices):
                   paged KV, mixed prompt lengths through submit(): every
                   future resolves, decode traced once, chosen tokens
                   agree with the eager forward's logits.
+- moe_serve       at a toy size, in bf16: the share-aware expert layer
+                  (parallel/moe.py; few rows, every held expert on every
+                  row; many rows, the pairs sorted and worked off in
+                  tiles) against its plain form, and the parallel-block
+                  LM (models/cohere_moe.py) served with prompts longer
+                  than the window until the window rings have wrapped,
+                  against its own eval forward (plain masked attention).
 - multichip       with >= 4 devices: ResNet-50 through DistOpt (the
                   shard_map driver) and through the GSPMD step with FSDP,
                   the LM at dp2 x tp2; state on four devices, FSDP bytes
@@ -67,6 +74,14 @@ SEED = 0
 RESNET_LR = 2e-4
 LM_LR = 0.05
 
+# the expert share and the mixed rings are checked at one toy size on the
+# chip and in the dry run alike (the benchmark measures the real one)
+MOE_TOY = {"hidden": 128, "heads": 4, "kv_heads": 2, "head_dim": 32,
+           "ff": 256, "experts": 16, "held": 4, "held_from": 4, "top_k": 4,
+           "shared": 2, "window": 16, "layers": 4, "vocab": 512,
+           "rows": (32, 300), "slots": 4, "max_len": 64,
+           "prefill_len": 32, "new_tokens": 24}
+
 FULL = {
     "resnet": {"depth": 50, "batch": 32, "image": 224, "steps": 5,
                "timed_steps": 20},
@@ -77,6 +92,7 @@ FULL = {
     "ring": {"batch": 2, "heads": 8, "seq": 512, "hd": 64},
     "serve": {"slots": 4, "max_len": 256, "prefill_len": 64,
               "new_tokens": 12, "ref_len": 128},
+    "moe": MOE_TOY,
 }
 # --dry-run: the same control flow where a CPU can finish it. 224 px stays
 # because the ResNet's 7x7 average pool needs the 7x7 final feature map.
@@ -90,6 +106,7 @@ DRY = {
     "ring": {"batch": 1, "heads": 2, "seq": 128, "hd": 64},
     "serve": {"slots": 4, "max_len": 64, "prefill_len": 16,
               "new_tokens": 4, "ref_len": 128},
+    "moe": MOE_TOY,
 }
 
 
@@ -586,6 +603,123 @@ def phase_serve(ctx):
 
 
 # ---------------------------------------------------------------------------
+# moe_serve
+# ---------------------------------------------------------------------------
+
+def _expert_share_vs_plain(ctx):
+    """`expert_share_ffn` in bf16 on both of its paths against every
+    held expert on every row in float32 at the highest precision."""
+    import jax
+    import jax.numpy as jnp
+    from singa_tpu.parallel import moe
+    c = ctx.sizes["moe"]
+    D, F, G, S = c["hidden"], c["ff"], c["held"], c["shared"]
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 5), 16))
+    draw = lambda *shape: (0.08 * jax.random.normal(           # noqa: E731
+        next(keys), shape)).astype(jnp.bfloat16)
+    p = {"router": draw(D, c["experts"]), "w_gate": draw(G, D, F),
+         "w_up": draw(G, D, F), "w_down": draw(G, F, D),
+         "s_gate": draw(S, D, F), "s_up": draw(S, D, F),
+         "s_down": draw(S, F, D)}
+
+    def plain(p, h):
+        f = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p)
+        idx, w = moe.route_sigmoid_topk(h, p["router"], c["top_k"])
+        gated = lambda x, a, b, d: (jax.nn.silu(x @ a) * (x @ b)) @ d  # noqa: E731
+        y = sum(gated(h, f["s_gate"][j], f["s_up"][j], f["s_down"][j])
+                for j in range(S)) / S
+        for g in range(G):
+            weight = jnp.sum(jnp.where(idx == c["held_from"] + g, w, 0.0),
+                             axis=-1)
+            y = y + weight[:, None] * gated(h, f["w_gate"][g], f["w_up"][g],
+                                            f["w_down"][g])
+        return y
+
+    out = {}
+    for rows in c["rows"]:
+        h = jax.random.normal(next(keys), (rows, D)).astype(jnp.bfloat16)
+        y, stats = jax.jit(lambda p, h: moe.expert_share_ffn(
+            p, h, top_k=c["top_k"], held_from=c["held_from"],
+            h_route=h.astype(jnp.float32)))(p, h)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(plain)(p, h.astype(jnp.float32))
+        err = _rel_err(y, want)
+        # bf16 operands, float32 sums: 2^-8 a product
+        assert err < 3e-2, f"expert share at {rows} rows: {err}"
+        assert int(stats["pairs_here"] + stats["pairs_absent"]) == \
+            rows * c["top_k"], stats
+        out[f"rows_{rows}_rel_err"] = round(err, 5)
+    return out
+
+
+def phase_moe_serve(ctx):
+    """What PR 26 added to the serving path, at a toy size in bf16: the
+    expert share against its plain form, then a parallel-block LM served
+    with prompts longer than its window until every window ring has
+    wrapped, each served token read in the model's own eval forward
+    (plain masked attention, no cache)."""
+    import jax.numpy as jnp
+    from singa_tpu.models.cohere_moe import CohereMoELM
+    from singa_tpu.observability import metrics as obs_metrics
+    c = ctx.sizes["moe"]
+    out = {"expert_share": _expert_share_vs_plain(ctx)}
+    m = CohereMoELM(
+        c["vocab"], hidden_size=c["hidden"], num_layers=c["layers"],
+        num_heads=c["heads"], num_kv_heads=c["kv_heads"],
+        head_dim=c["head_dim"], intermediate_size=c["ff"],
+        num_experts=c["held"], router_width=c["experts"], top_k=c["top_k"],
+        num_shared_experts=c["shared"], experts_held_from=c["held_from"],
+        sliding_window=c["window"], init_std=0.08, out_std=0.03)
+    ids = _put(ctx, jnp.zeros((1, c["max_len"]), jnp.float32))
+    m.compile([ids], is_train=False, use_graph=True, policy="bfloat16")
+    m.eval()
+    reg = obs_metrics.MetricsRegistry()
+    eng = m.compile_serving(slots=c["slots"], max_len=c["max_len"],
+                            prefill_len=c["prefill_len"], prefill_batch=1,
+                            policy="bfloat16", registry=reg)
+    lengths = [[lv[n].shape[2] for n in ("k", "v")] for lv in eng._cache]
+    assert lengths == [[c["window"]] * 2] * 3 + [[c["max_len"]] * 2], lengths
+    rng = np.random.RandomState(SEED + 7)
+    prompts = [rng.randint(1, c["vocab"], (n,))
+               for n in (c["prefill_len"], 5, c["window"] + 3, 11)]
+    futs = [eng.submit(p, max_new_tokens=c["new_tokens"]) for p in prompts]
+    eng.run_until_idle()
+    served = [f.result(timeout=5)["tokens"] for f in futs]
+    info = eng.compiled_step_info()
+    eng.stop()
+    assert info["n_traces"] == 1 and info["kv_layout"] == "ring", info
+    seqs = np.zeros((len(prompts), c["max_len"]), np.float32)
+    for r, (p, toks) in enumerate(zip(prompts, served)):
+        assert len(toks) == c["new_tokens"], toks
+        seqs[r, :len(p) + len(toks)] = np.concatenate([p, toks])
+    logits = np.asarray(m(_put(ctx, seqs)).data, np.float32)
+    assert np.isfinite(logits).all(), "non-finite logits"
+    gaps = np.asarray([logits[r, len(p) - 1 + j].max()
+                       - logits[r, len(p) - 1 + j, tok]
+                       for r, (p, toks) in enumerate(zip(prompts, served))
+                       for j, tok in enumerate(toks)])
+    # both sides in bf16 by different routes (rings against one masked
+    # pass): a chosen token may trail the eager best by rounding, and a
+    # rounding that swaps a token's last pick of experts moves that one
+    # token by about the spread of the logits, so the MEAN gap decides
+    # (as in the benchmark's cell). Read in the dry run: 0 as it stands;
+    # 0.048 of the spread with the window rings' mask left open or their
+    # writes one row off
+    spread = float(logits.std())
+    worst, mean = float(gaps.max()), float(gaps.mean())
+    assert mean < 0.03 * spread, \
+        f"served tokens trail the eval forward by {mean} on average " \
+        f"(at most {worst}) of {spread}"
+    out.update(
+        max_logit_gap_vs_eval=round(worst, 4),
+        mean_logit_gap_vs_eval=round(mean, 5), logit_std=round(spread, 2),
+        longest_context=max(len(p) for p in prompts) + c["new_tokens"],
+        pairs_here=reg.get("moe_pairs_total").value(held="here"),
+        pairs_absent=reg.get("moe_pairs_total").value(held="absent"))
+    return out
+
+
+# ---------------------------------------------------------------------------
 # multichip
 # ---------------------------------------------------------------------------
 
@@ -743,6 +877,7 @@ PHASES = (("device", phase_device),
           ("lm_train", phase_lm_train),
           ("kernels", phase_kernels),
           ("serve", phase_serve),
+          ("moe_serve", phase_moe_serve),
           ("multichip", phase_multichip),
           ("cache", phase_cache))
 

@@ -595,9 +595,12 @@ class _LMServeAdapter:
         """Engine-construction-time limits the engine itself can't see:
         a prompt longer than the positional-embedding table would crash
         the first compiled prefill with a shape error; fail typed and
-        early instead. (decode clips positions to the table — the ring
-        has made attention sliding-window by then — but prefill indexes
-        ``pos[:S]`` directly.)"""
+        early instead. (decode clips positions to the table: past
+        ``max_len`` tokens this adapter's rings, all ``max_len`` long,
+        have wrapped, and that wrap is the only windowing this model
+        has — an adapter whose layers window by design gives those
+        layers rings of the window's length, ``models/cohere_moe.py`` —
+        but prefill indexes ``pos[:S]`` directly.)"""
         table = int(self.m.pos_emb.input_dim)
         if int(prefill_len) > table:
             raise ValueError(
@@ -831,8 +834,9 @@ class _LMServeAdapter:
         def fn(P, cache, tokens, positions, active):
             positions = positions.astype(jnp.int32)
             # the learned position table is finite; a sequence decoding
-            # past it holds the last embedding (the ring has already
-            # made attention sliding-window by then)
+            # past it holds the last embedding (every ring here is
+            # max_len long and has wrapped by then: attention runs over
+            # the last max_len tokens, by the wrap and not by a mask)
             pos_ids = jnp.minimum(positions, P["pos"].shape[0] - 1)
             x = (jnp.take(P["tok"], tokens, axis=0)
                  + jnp.take(P["pos"], pos_ids, axis=0))[:, None, :] \

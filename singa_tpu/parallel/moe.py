@@ -179,4 +179,255 @@ class MoEFFN(Layer):
                 "w2": self.w2, "b2": self.b2}
 
 
-__all__ = ["MoEFFN"]
+# ---------------------------------------------------------------------------
+# the share-aware expert layer: told which experts it holds
+# ---------------------------------------------------------------------------
+
+def route_sigmoid_topk(h, router, top_k):
+    """Sigmoid scores over ALL experts in float32, the ``top_k`` best,
+    their scores renormalised over the picks: ``(idx (T, k) int32,
+    w (T, k) float32)``. The matrix product runs at the highest
+    precision (a TPU's default rounds float32 operands to bf16): the
+    router is 128 columns wide, and which expert comes eighth hangs on
+    the fourth decimal."""
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    w, idx = lax.top_k(s, top_k)
+    return idx.astype(jnp.int32), w / jnp.sum(w, -1, keepdims=True)
+
+
+def _gated(x, w_gate, w_up, w_down):
+    """One SwiGLU expert on rows ``x``: operands in ``x.dtype``, sums
+    and the activation in float32."""
+    f32 = jnp.float32
+    g = jnp.dot(x, w_gate, preferred_element_type=f32)
+    u = jnp.dot(x, w_up, preferred_element_type=f32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                   preferred_element_type=f32)
+
+
+def rows_in_blocks(fn, x, n_blocks, block_rows):
+    """``fn`` on the first ``n_blocks`` blocks of ``block_rows`` rows of
+    ``x`` (T, D), the rest left nought: ``n_blocks`` is read on the
+    device (a loop of that length), so a padded prompt pays for the
+    blocks that hold a token and not for its padding. ``fn`` maps
+    (block_rows, D) to (block_rows, D_out)."""
+    out = jax.eval_shape(fn, jax.ShapeDtypeStruct(
+        (block_rows, x.shape[1]), x.dtype))
+
+    def one(i, acc):
+        rows = lax.dynamic_slice_in_dim(x, i * block_rows, block_rows)
+        return lax.dynamic_update_slice_in_dim(
+            acc, fn(rows).astype(out.dtype), i * block_rows, 0)
+
+    return lax.fori_loop(0, n_blocks, one,
+                         jnp.zeros((x.shape[0], out.shape[1]), out.dtype))
+
+
+def _routed_dense(h, local, w, here, w_gate, w_up, w_down):
+    """Every held expert on every row, weighted by its pick's weight or
+    nought: right where the rows are few (a decode tick), since each
+    expert's matrices are read once whatever the rows."""
+    f32 = jnp.float32
+    G = w_gate.shape[0]
+    g = jnp.einsum("td,gdf->gtf", h, w_gate, preferred_element_type=f32)
+    u = jnp.einsum("td,gdf->gtf", h, w_up, preferred_element_type=f32)
+    y = jnp.einsum("gtf,gfd->gtd", (jax.nn.silu(g) * u).astype(h.dtype),
+                   w_down, preferred_element_type=f32)
+    hot = (local[:, :, None] == jnp.arange(G)[None, None, :]) \
+        & here[:, :, None]                                   # (T, k, G)
+    weight = jnp.sum(jnp.where(hot, w[:, :, None], 0.0), axis=1)  # (T, G)
+    return jnp.einsum("tg,gtd->td", weight, y)
+
+
+def _routed_sorted(h, group, counts, w, w_gate, w_up, w_down, tile):
+    """The pairs routed here, sorted by expert and worked off in tiles of
+    ``tile`` rows, each tile one expert's: as many tiles as the pairs
+    need (a loop whose length is read on the device), so the work
+    follows the pairs and no pair is dropped, whatever the router does.
+    ``group``: (T*k,) held expert of each pair, G for a pair routed
+    elsewhere; ``counts``: (G,) pairs an expert. Static shapes
+    throughout."""
+    T, k = w.shape
+    i32 = jnp.int32
+    order = jnp.argsort(group, stable=True).astype(i32)      # absent last
+    tiles = (counts + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    row_start = jnp.cumsum(counts) - counts
+    flat_w = w.reshape(-1)
+    lane = jnp.arange(tile, dtype=i32)
+
+    def one_tile(i, acc):
+        g = jnp.searchsorted(tile_end, i, side="right").astype(i32)
+        rows = row_start[g] + (i - tile_start[g]) * tile + lane
+        live = rows < row_start[g] + counts[g]
+        pair = order[jnp.minimum(rows, T * k - 1)]
+        tok = pair // k
+        y = _gated(h[tok], w_gate[g], w_up[g], w_down[g])
+        y = y * jnp.where(live, flat_w[pair], 0.0)[:, None]
+        # a token picks an expert once, so live rows of a tile never
+        # share a token; dead rows add nought
+        return acc.at[tok].add(y)
+
+    return lax.fori_loop(0, tile_end[-1], one_tile,
+                         jnp.zeros(h.shape, jnp.float32))
+
+
+# up to DENSE_ROWS rows every held expert runs on every row (a decode
+# tick: each expert's matrices are read once whatever the rows); above,
+# the pairs are sorted by expert and worked off in tiles of SORTED_TILE
+DENSE_ROWS = 128
+SORTED_TILE = 256
+
+
+def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
+                     axis_name=None, blocks=None):
+    """The part of a sigmoid top-k expert layer that ONE share gives.
+
+    ``p``: ``router`` (D, E) over all E experts; ``w_gate``/``w_up``
+    (G, D, F) and ``w_down`` (G, F, D), the G experts held here
+    (experts ``held_from .. held_from + G - 1``); ``s_gate``/``s_up``
+    (S, D, F), ``s_down`` (S, F, D), the shared experts, held whole.
+    ``h``: (T, D) rows in the compute dtype; ``h_route``: the same rows
+    in float32 for the router (default ``h``); ``rows``: (T,) bool,
+    False for padding (such rows are routed nowhere and counted
+    nowhere); ``blocks``: ``(n_blocks, block_rows)`` when only the
+    first ``n_blocks`` (a device scalar) blocks of rows hold a token —
+    the shared experts then run on those blocks alone
+    (:func:`rows_in_blocks`), as the routed ones do by their pairs.
+
+    Routes over all E, renormalises the picks' weights over all
+    ``top_k`` of them, computes only the pairs whose expert lives here
+    and drops none; picks that go to absent experts add nothing. No
+    capacity factor. The shared experts' mean is added once. On an
+    active ``axis_name`` every peer holds its own G experts
+    (``held_from`` is then the peer's index times G), all peers see
+    the same rows, and the routed parts are summed over the axis; on
+    one chip the layer runs without the exchange.
+
+    Returns ``(y (T, D) float32, stats)`` with ``stats`` int32 scalars:
+    ``pairs_here``, ``pairs_absent``, ``experts_touched`` (held experts
+    that got any pair)."""
+    T = h.shape[0]
+    G = p["w_gate"].shape[0]
+    if axis_name is not None and active_axis(axis_name):
+        held_from = lax.axis_index(axis_name) * G
+    with jax.named_scope("moe_route"):
+        idx, w = route_sigmoid_topk(h if h_route is None else h_route,
+                                    p["router"], top_k)
+        local = idx - held_from
+        here = (local >= 0) & (local < G)
+        real = jnp.ones((T,), bool) if rows is None else rows
+        here = here & real[:, None]
+        group = jnp.where(here, local, G).reshape(-1)
+        counts = jnp.zeros((G + 1,), jnp.int32).at[group].add(1)[:G]
+        pairs_here = jnp.sum(counts)
+        stats = {"pairs_here": pairs_here,
+                 "pairs_absent": jnp.sum(real.astype(jnp.int32)) * top_k
+                 - pairs_here,
+                 "experts_touched": jnp.sum((counts > 0).astype(jnp.int32))}
+    with jax.named_scope("moe_experts"):
+        if T <= DENSE_ROWS:
+            y = _routed_dense(h, local, w, here, p["w_gate"], p["w_up"],
+                              p["w_down"])
+        else:
+            y = _routed_sorted(h, group, counts, w, p["w_gate"], p["w_up"],
+                               p["w_down"], min(SORTED_TILE, T))
+        if axis_name is not None and active_axis(axis_name):
+            y = lax.psum(y, axis_name)
+    with jax.named_scope("moe_shared"):
+        S = p["s_gate"].shape[0]
+
+        def shared(rows_):
+            return sum(_gated(rows_, p["s_gate"][j], p["s_up"][j],
+                              p["s_down"][j]) for j in range(S)) / S
+
+        if S:
+            y = y + (shared(h) if blocks is None
+                     else rows_in_blocks(shared, h, *blocks))
+    return y, stats
+
+
+class _ExpertShareFFN(Operator):
+    """Tape node of :func:`expert_share_ffn` (forward only: the sorted
+    path's loop has no reverse)."""
+
+    differentiable = False
+
+    def __init__(self, top_k, held_from, axis_name):
+        super().__init__()
+        self.kw = dict(top_k=top_k, held_from=held_from,
+                       axis_name=axis_name)
+        self.stats = None
+
+    def forward(self, h, *leaves):
+        y, self.stats = expert_share_ffn(
+            dict(zip(ExpertShareFFN.LEAVES, leaves)), h, **self.kw)
+        return y.astype(h.dtype)
+
+
+class ExpertShareFFN(Layer):
+    """Sigmoid top-k experts of which this layer holds ``held_count``
+    (from ``held_from``) out of ``n_experts``, with ``n_shared`` shared
+    experts averaged beside them — :func:`expert_share_ffn` as a layer
+    (inference only). ``self.stats`` holds the last call's counts."""
+
+    LEAVES = ("router", "w_gate", "w_up", "w_down", "s_gate", "s_up",
+              "s_down")
+
+    def __init__(self, n_experts, d_ff, top_k, held_count=None,
+                 held_from=0, n_shared=0, axis_name="expert",
+                 init_std=0.02, out_std=None):
+        super().__init__()
+        held_count = n_experts if held_count is None else held_count
+        if top_k > n_experts or held_from + held_count > n_experts:
+            raise ValueError(
+                f"top_k={top_k}, held {held_from}..{held_from + held_count}"
+                f" do not fit n_experts={n_experts}")
+        self.n_experts, self.d_ff, self.top_k = n_experts, d_ff, top_k
+        self.held_count, self.held_from = held_count, held_from
+        self.n_shared, self.axis_name = n_shared, axis_name
+        self.init_std = init_std
+        self.out_std = init_std if out_std is None else out_std
+        self.stats = None
+
+    def initialize(self, x):
+        D, F, dev = x.shape[-1], self.d_ff, x.device
+        G, S = self.held_count, self.n_shared
+        shapes = {"router": (D, self.n_experts),
+                  "w_gate": (G, D, F), "w_up": (G, D, F),
+                  "w_down": (G, F, D), "s_gate": (S, D, F),
+                  "s_up": (S, D, F), "s_down": (S, F, D)}
+        for name, shape in shapes.items():
+            t = _param(shape, dev, dtype=x.dtype)
+            std = self.out_std if name.endswith("down") else self.init_std
+            if len(shape) == 3 and shape[0]:
+                # a bank is drawn a matrix at a time: one draw of a
+                # 0.5 GB bank holds several times that in temporaries
+                t.data = jnp.stack([
+                    _param(shape[1:], dev, dtype=x.dtype).gaussian(0.0, std)
+                    .data for _ in range(shape[0])])
+            else:
+                t.gaussian(0.0, std)
+            if self.axis_name and name.startswith("w_"):
+                t.spec = expert_spec(self.axis_name)
+            setattr(self, name, t)
+
+    def forward(self, x):
+        from .. import autograd
+        shape = x.shape
+        if len(shape) > 2:
+            x = autograd.reshape(x, (-1, shape[-1]))
+        op = _ExpertShareFFN(self.top_k, self.held_from, self.axis_name)
+        y = op(x, *(getattr(self, n) for n in self.LEAVES))
+        self.stats = op.stats
+        return autograd.reshape(y, shape) if len(shape) > 2 else y
+
+    def _own_params(self):
+        return {n: getattr(self, n) for n in self.LEAVES}
+
+
+__all__ = ["MoEFFN", "ExpertShareFFN", "expert_share_ffn",
+           "route_sigmoid_topk", "rows_in_blocks"]

@@ -899,6 +899,40 @@ class ServingEngine(_EngineBase):
         self._reg.gauge("serve_slots",
                         "slot array width (max in-flight sequences)"
                         ).set(self.slots)
+        self._ring_lengths = None
+        if self.kv_layout == "ring" and isinstance(self._cache, list) \
+                and all(isinstance(lv, dict) and "k" in lv
+                        for lv in self._cache):
+            # what the rings hold, by kind of layer (a recurrent
+            # adapter's state is no ring and has no such gauge): an
+            # adapter whose layers keep rings of different lengths names
+            # each level's kind (``cache_kinds``); one geometry reads as
+            # "full"
+            kinds = getattr(adapter, "cache_kinds", None)
+            kinds = kinds() if kinds is not None \
+                else ["full"] * len(self._cache)
+            kv_bytes = self._reg.gauge(
+                "serve_kv_bytes", "bytes of ring KV state, by kind of "
+                "layer (window: min(window, max_len) positions a slot; "
+                "full: max_len)", labels=("kind",))
+            for kind in sorted(set(kinds)):
+                kv_bytes.set(sum(
+                    int(a.size) * a.dtype.itemsize
+                    for k, level in zip(kinds, self._cache) if k == kind
+                    for a in level.values()), kind=kind)
+            self._ring_lengths = np.asarray(
+                [int(level["k"].shape[2]) for level in self._cache])
+            self._kv_rows = self._reg.counter(
+                "serve_kv_rows_attended_total", "ring rows holding a token "
+                "that decode ticks attended to, summed over layers and "
+                "active slots (what a tick has to read of the cache)")
+        # an adapter whose programs return ``(logits, stats)`` (a small
+        # array of per-call counts that rides the logits' read-back, no
+        # sync of its own) publishes them itself: ``stats_recorder(
+        # registry)`` gives ``record(program, stats) -> span attrs``;
+        # the engine knows neither their names nor their meaning
+        make = getattr(adapter, "stats_recorder", None)
+        self._record_stats = None if make is None else make(self._reg)
         self._tokens_total = self._reg.counter(
             "serve_tokens_total", "tokens generated")
         self._decode_steps = self._reg.counter(
@@ -1303,6 +1337,10 @@ class ServingEngine(_EngineBase):
              if self.policy is not None else None}
         if self.kv_layout == "paged":
             g["block_size"] = int(self.kv_block_size)
+        elif self._ring_lengths is not None and \
+                any(n != self.max_len for n in self._ring_lengths):
+            # layers with rings of their own length (window layers)
+            g["ring_lengths"] = [int(n) for n in self._ring_lengths]
         return g
 
     @staticmethod
@@ -1884,6 +1922,17 @@ class ServingEngine(_EngineBase):
             return self._run_prefill_paged(batch, free, sp)
         return self._run_prefill_ring(batch, free, sp)
 
+    def _read_out(self, out, sp, program):
+        """A ring program's output to the host. Where the adapter records
+        its programs' counts (``stats_recorder``), they come over with
+        the logits, and what it returns goes on the span."""
+        if self._record_stats is None:
+            return np.asarray(out)
+        out, stats = out
+        out = np.asarray(out)
+        sp.attrs.update(self._record_stats(program, np.asarray(stats)))
+        return out
+
     def _run_prefill_ring(self, batch, free, sp):
         with sp.phase("pack"):
             B, S = self.prefill_batch, self.prefill_len
@@ -1916,7 +1965,7 @@ class ServingEngine(_EngineBase):
         with sp.phase("readback"):
             # (B, V) logits single-device; (B,) in-graph argmax tokens
             # when sharded (the full-vocab array never reaches the host)
-            out = np.asarray(out)
+            out = self._read_out(out, sp, "prefill")
         with sp.phase("place"):
             at = time.monotonic()
             for b, (req, slot_idx) in enumerate(placed):
@@ -2116,6 +2165,10 @@ class ServingEngine(_EngineBase):
                     tokens[i] = slot["tok"]
                     positions[i] = slot["pos"]
                     active[i] = True
+            if self._ring_lengths is not None:
+                sp.attrs["kv_rows"] = int(np.minimum(
+                    positions[active, None] + 1, self._ring_lengths).sum())
+                self._kv_rows.inc(sp.attrs["kv_rows"])
         with sp.phase("dispatch"):
             n0 = self._decode_rec["n_traces"]
             t0c = time.perf_counter()
@@ -2131,7 +2184,7 @@ class ServingEngine(_EngineBase):
                                  cc0)
         with sp.phase("readback"):
             # (W, V) logits, or (W,) sharded toks
-            out = np.asarray(out)
+            out = self._read_out(out, sp, "decode")
         with sp.phase("sample"):
             at = time.monotonic()
             for i, slot in enumerate(list(self._slots)):

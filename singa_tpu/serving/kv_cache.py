@@ -113,7 +113,9 @@ def affinity_hash(key, salt=""):
 
 def init_cache(n_slots, n_heads, length, head_dim, dtype=jnp.float32):
     """One layer's ring cache: zeroed ``{"k","v"}`` of shape
-    ``(n_slots, n_heads, length, head_dim)``.
+    ``(n_slots, n_heads, length, head_dim)``. ``n_heads`` counts KV
+    heads (fewer than the query heads under grouped attention) and
+    ``length`` is this layer's own (levels of one cache may differ).
 
     ``dtype=int8`` builds the QUANTIZED ring (the
     ``singa_tpu.quant`` serving presets): int8 payloads plus one fp32
@@ -238,19 +240,28 @@ def attend(q, level, pos, scale):
 
     ``q``: ``(W, H, 1, D)`` (the new token's query, already written to
     the ring along with its k/v); ``pos``: ``(W,)`` — the new token's
-    position. Softmax in f32 regardless of cache dtype (bf16 AND int8
-    serving keep their numerics sane — a quantized ring dequantizes
-    its rows here, payload × per-row scale, before the f32 scores),
-    result cast back to ``q.dtype``. Returns ``(W, H, 1, D)``."""
+    position. The level may hold fewer heads than ``q`` (grouped KV
+    heads: query head ``i`` reads KV head ``i // (H / H_kv)``), and its
+    own length (a window layer's ring is ``min(window, max_len)`` long
+    beside a full layer's ``max_len``). Softmax in f32 regardless of
+    cache dtype (bf16 AND int8 serving keep their numerics sane — a
+    quantized ring dequantizes its rows here, payload × per-row scale,
+    before the f32 scores), result cast back to ``q.dtype``. Returns
+    ``(W, H, 1, D)``."""
     L = level["k"].shape[2]
     kf, vf = _dequant_level(level)
+    shape = q.shape
+    if shape[1] != kf.shape[1]:
+        # grouped heads: the G query heads that read one KV head ride
+        # the query axis, so the ring is read once for all of them
+        q = q.reshape(shape[0], kf.shape[1], -1, shape[3])
     s = jnp.einsum("whqd,whld->whql", q.astype(jnp.float32),
                    kf.astype(jnp.float32)) * scale
     mask = ring_mask(pos, L)[:, None, None, :]
     s = jnp.where(mask, s, -jnp.inf)
     a = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("whql,whld->whqd", a, vf.astype(jnp.float32))
-    return out.astype(q.dtype)
+    return out.astype(q.dtype).reshape(shape)
 
 
 # ---------------------------------------------------------------------------

@@ -81,12 +81,14 @@ def test_chip_smoke_dry_run_walks_every_phase(tmp_path):
     phases = {d["phase"]: d for d in _json_lines(proc.stdout)
               if "phase" in d}
     assert list(phases) == ["device", "resnet50_train", "lm_train",
-                            "kernels", "serve", "multichip", "cache"]
+                            "kernels", "serve", "moe_serve", "multichip",
+                            "cache"]
     assert all(d["ok"] and d["platform"] == "cpu"
                for d in phases.values())
     assert not phases["multichip"].get("skipped")
     assert phases["multichip"]["resnet_gspmd_fsdp"]["ratio"] > 3.2
     assert phases["serve"]["paged"]["decode_n_traces"] == 1
+    assert phases["moe_serve"]["longest_context"] > 16      # the window
     assert phases["cache"]["cache_dir"] == str(cache)
     assert phases["cache"]["compile_cache_misses_total"] > 0
     assert any(n.endswith("-cache") for n in os.listdir(cache))
