@@ -32,18 +32,23 @@ Phases (each prints one JSON line with platform, device_kind, n_devices):
                   against its reference: the four fused optimizer
                   updates, the conv epilogue in both layouts with and
                   without the residual, the flash forward with a
-                  position delta (the ring-attention form).
+                  position delta (the ring-attention form), the ring
+                  decode kernel in both its forms against
+                  kv_cache.write_token + attend.
 - serve           the same LM through Model.compile_serving, ring and
                   paged KV, mixed prompt lengths through submit(): every
                   future resolves, decode traced once, chosen tokens
-                  agree with the eager forward's logits.
+                  agree with the eager forward's logits; the ring
+                  engine's compiled decode program holds the ring_decode
+                  Mosaic call once a level and rewrites no level.
 - moe_serve       at a toy size, in bf16: the share-aware expert layer
                   (parallel/moe.py; few rows, every held expert on every
                   row; many rows, the pairs sorted and worked off in
                   tiles) against its plain form, and the parallel-block
                   LM (models/cohere_moe.py) served with prompts longer
                   than the window until the window rings have wrapped,
-                  against its own eval forward (plain masked attention).
+                  against its own eval forward (plain masked attention);
+                  its rings of two lengths go through ring_decode too.
 - multichip       with >= 4 devices: ResNet-50 through DistOpt (the
                   shard_map driver) and through the GSPMD step with FSDP,
                   the LM at dp2 x tp2; state on four devices, FSDP bytes
@@ -61,6 +66,7 @@ same place shows hits and no misses. The last line of standard output is
 import argparse
 import gc
 import json
+import re
 import sys
 import time
 import warnings
@@ -76,11 +82,13 @@ LM_LR = 0.05
 
 # the expert share and the mixed rings are checked at one toy size on the
 # chip and in the dry run alike (the benchmark measures the real one)
-MOE_TOY = {"hidden": 128, "heads": 4, "kv_heads": 2, "head_dim": 32,
+# (rings of 128 and 256 rows and four KV heads of 32: the least the ring
+# decode kernel takes)
+MOE_TOY = {"hidden": 128, "heads": 8, "kv_heads": 4, "head_dim": 32,
            "ff": 256, "experts": 16, "held": 4, "held_from": 4, "top_k": 4,
-           "shared": 2, "window": 16, "layers": 4, "vocab": 512,
-           "rows": (32, 300), "slots": 4, "max_len": 64,
-           "prefill_len": 32, "new_tokens": 24}
+           "shared": 2, "window": 128, "layers": 4, "vocab": 512,
+           "rows": (32, 300), "slots": 4, "max_len": 256,
+           "prefill_len": 160, "new_tokens": 24}
 
 FULL = {
     "resnet": {"depth": 50, "batch": 32, "image": 224, "steps": 5,
@@ -90,6 +98,9 @@ FULL = {
     "optim_mlp": (2048, 1000, 77),
     "epilogue": [(32, 64, 112, 112), (32, 256, 56, 56)],
     "ring": {"batch": 2, "heads": 8, "seq": 512, "hd": 64},
+    # (slots, KV heads, query heads a KV head, ring, head size, dtype)
+    "ring_decode": {"d64": (8, 16, 1, 1024, 64, "bfloat16"),
+                    "d128": (8, 1, 16, 2048, 128, "bfloat16")},
     "serve": {"slots": 4, "max_len": 256, "prefill_len": 64,
               "new_tokens": 12, "ref_len": 128},
     "moe": MOE_TOY,
@@ -99,12 +110,14 @@ FULL = {
 DRY = {
     "resnet": {"depth": 18, "batch": 4, "image": 224, "steps": 3,
                "timed_steps": 2},
-    "lm": {"d_model": 64, "n_heads": 2, "n_layers": 1, "seq": 128,
+    "lm": {"d_model": 128, "n_heads": 4, "n_layers": 1, "seq": 128,
            "vocab": 512, "head_chunk": 256, "batch": 4, "steps": 3},
     "optim_mlp": (64, 72, 7),
     "epilogue": [(2, 8, 12, 12)],
     "ring": {"batch": 1, "heads": 2, "seq": 128, "hd": 64},
-    "serve": {"slots": 4, "max_len": 64, "prefill_len": 16,
+    "ring_decode": {"d64": (6, 2, 1, 256, 64, "float32"),
+                    "d128": (6, 1, 4, 384, 128, "bfloat16")},
+    "serve": {"slots": 4, "max_len": 128, "prefill_len": 16,
               "new_tokens": 4, "ref_len": 128},
     "moe": MOE_TOY,
 }
@@ -163,6 +176,30 @@ def _compiled_for_chip(ctx, jitted, args, what, n=1):
         assert got >= n, f"{what}: {got} Mosaic custom calls in the " \
             f"HLO, expected >= {n} — the kernel did not reach the chip"
     return compiled
+
+
+def _ring_kernel_in_decode(ctx, eng):
+    """The compiled decode program of a ring engine holds the
+    ``ring_decode`` Mosaic call once a level and no
+    ``dynamic-update-slice`` of a level's size: the level is walked and
+    written by the kernel, in place. Returns the count of calls. (The
+    dry run interprets the kernel; nothing is compiled for a chip.)"""
+    if ctx.dry:
+        return None
+    from singa_tpu.aot import export as aot_export
+    avals = aot_export.serving_program_avals(eng)[1]
+    hlo = eng._decode.lower(*avals).compile().as_text()
+    calls = len(re.findall(r"^\s*%?ring_decode[.\d]* = .*custom-call\(",
+                           hlo, re.M))
+    assert calls == len(eng._cache), \
+        f"{calls} ring_decode calls in the decode program for " \
+        f"{len(eng._cache)} levels"
+    least = min(lv["k"].size for lv in eng._cache)
+    for dims in re.findall(r"= \w+\[([\d,]+)\]\S* dynamic-update-slice\(",
+                           hlo):
+        assert np.prod([int(d) for d in dims.split(",")]) < least, \
+            f"the decode program rewrites [{dims}], a level's size"
+    return calls
 
 
 # ---------------------------------------------------------------------------
@@ -527,10 +564,64 @@ def _ring_form_vs_scan(ctx):
     return {k: round(v, 5) for k, v in errs.items()}
 
 
+def _ring_decode_vs_twins(ctx):
+    """One decode tick of a ring level through the kernel
+    (ops/ring_decode.py) against kv_cache.write_token + attend, in both
+    forms of the kernel: head size 64 (XLA keeps that level with the
+    ring on the lanes) with one query head a KV head, and head size 128
+    (row-major) with a group of query heads on one KV head. Slots short
+    of a block, on a block's edge, wrapped, and dead; the level after
+    the tick is write_token's bit for bit where the slot is live and
+    untouched where it is not."""
+    import jax
+    import jax.numpy as jnp
+    from singa_tpu.serving import kv_cache
+    rng = np.random.RandomState(SEED + 4)
+    errs = {}
+    for name, (W, n_kv, G, L, D, dtype) in ctx.sizes["ring_decode"].items():
+        dtype = jnp.dtype(dtype)
+        draw = lambda *shape: jnp.asarray(rng.randn(*shape), dtype)  # noqa: E731
+        level = {"k": draw(W, n_kv, L, D), "v": draw(W, n_kv, L, D)}
+        q, k_new, v_new = draw(W, n_kv * G, 1, D), draw(W, n_kv, D), \
+            draw(W, n_kv, D)
+        pos = np.resize([0, 130, L - 1, L, 3 * L + 7, L // 2, 5, 255], W)
+        live = np.resize([True, True, True, True, True, False], W)
+        scale = 1.0 / np.sqrt(D)
+        assert kv_cache.ring_block(level), f"{name}: kernel declined"
+
+        def kernel(level, q, k_new, v_new, pos, live):
+            return kv_cache.decode_token(level, q, k_new, v_new, pos, live,
+                                         scale)
+
+        def twins(level, q, k_new, v_new, pos):
+            level = kv_cache.write_token(level, k_new, v_new, pos)
+            return kv_cache.attend(q, level, pos, scale), level
+
+        args = (level, q, k_new, v_new, jnp.asarray(pos, jnp.int32))
+        out, got = _compiled_for_chip(
+            ctx, jax.jit(kernel), args + (jnp.asarray(live),),
+            f"ring_decode {name}")(*args, jnp.asarray(live))
+        ref, want = jax.jit(twins)(*args)
+        bits = lambda a: np.ascontiguousarray(np.asarray(a)).view(np.uint8)  # noqa: E731
+        for n in ("k", "v"):
+            assert np.array_equal(bits(got[n])[live], bits(want[n])[live]), \
+                f"{name}: {n} of a live slot differs from write_token's"
+            assert np.array_equal(bits(got[n])[~live],
+                                  bits(level[n])[~live]), \
+                f"{name}: {n} of a dead slot was written"
+        errs[name] = _rel_err(np.asarray(out, np.float32)[live],
+                              np.asarray(ref, np.float32)[live])
+    # probabilities rounded to the level's dtype for the value product,
+    # and the output to it: 2^-8 relative each
+    assert max(errs.values()) < 2e-2, errs
+    return {k: round(v, 5) for k, v in errs.items()}
+
+
 def phase_kernels(ctx):
     return {"fused_optim_rel_err": _optimizer_twins(ctx),
             "conv_epilogue_rel_err": _epilogue_vs_reference(ctx),
-            "flash_pos_delta_rel_err": _ring_form_vs_scan(ctx)}
+            "flash_pos_delta_rel_err": _ring_form_vs_scan(ctx),
+            "ring_decode_rel_err": _ring_decode_vs_twins(ctx)}
 
 
 # ---------------------------------------------------------------------------
@@ -562,6 +653,8 @@ def phase_serve(ctx):
         eng.run_until_idle()
         results = [f.result(timeout=5) for f in futs]
         info = eng.compiled_step_info()
+        if kv_layout == "ring":
+            out["ring_decode_calls"] = _ring_kernel_in_decode(ctx, eng)
         eng.stop()
         assert info["kv_layout"] == kv_layout and \
             "kv_layout_declined" not in info, info
@@ -686,6 +779,7 @@ def phase_moe_serve(ctx):
     eng.run_until_idle()
     served = [f.result(timeout=5)["tokens"] for f in futs]
     info = eng.compiled_step_info()
+    out["ring_decode_calls"] = _ring_kernel_in_decode(ctx, eng)
     eng.stop()
     assert info["n_traces"] == 1 and info["kv_layout"] == "ring", info
     seqs = np.zeros((len(prompts), c["max_len"]), np.float32)

@@ -535,10 +535,9 @@ class _ServeAdapter:
             for kind, p, level in zip(cfg.layer_types, P["layers"], cache):
 
                 def attend(q, k, v, level=level):
-                    level = kv_cache.write_token(level, k[:, 0], v[:, 0],
-                                                 positions)
-                    o = kv_cache.attend(q.swapaxes(1, 2), level, positions,
-                                        cfg.scale)
+                    o, level = kv_cache.decode_token(
+                        level, q.swapaxes(1, 2), k[:, 0], v[:, 0],
+                        positions, active, cfg.scale)
                     return o.swapaxes(1, 2), level
 
                 x, level, st = block_apply(cfg, kind, p, x,

@@ -843,10 +843,10 @@ class _LMServeAdapter:
                 .astype(cdt)
 
             def attend(q, k, v, level):
-                level = kv_cache.write_token(
-                    level, k[:, :, 0], v[:, :, 0], positions)
-                return _merge_heads(kv_cache.attend(
-                    q, level, positions, scale)), level
+                o, level = kv_cache.decode_token(
+                    level, q, k[:, :, 0], v[:, :, 0], positions, active,
+                    scale)
+                return _merge_heads(o), level
 
             new_cache = []
             for p, level in zip(P["blocks"], cache):

@@ -318,6 +318,12 @@ def _use_pallas(q, k, block_q, block_k):
                     "the sequence to a multiple of 128 to get the "
                     "Pallas kernel", stacklevel=3)
         return False
+    return kernels_run()
+
+
+def kernels_run():
+    """Whether a Pallas kernel can run here: on the TPU, or anywhere
+    under the interpret-mode test hook."""
     return jax.default_backend() == "tpu" or FORCE_PALLAS_INTERPRET
 
 

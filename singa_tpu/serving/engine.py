@@ -71,6 +71,7 @@ dumped and every pending future is failed — once each.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -823,11 +824,18 @@ class ServingEngine(_EngineBase):
                 return prefill_raw(P, cache, tokens, lengths, slot_ids,
                                    valid)
 
+            from . import kv_cache as _kvc
+            # a Mosaic call cannot be partitioned: the sharded engine's
+            # rings keep the XLA path
+            rings = _kvc.xla_rings if self.sharded \
+                else contextlib.nullcontext
+
             def decode_body(P, cache, tokens, positions, active):
                 # host-side trace counter, same contract as
                 # Model._build_step: the serve path must keep this at 1
                 decode_rec["n_traces"] += 1
-                return decode_raw(P, cache, tokens, positions, active)
+                with rings():
+                    return decode_raw(P, cache, tokens, positions, active)
 
         jit_kw_prefill = {}
         jit_kw_decode = {}
@@ -922,10 +930,19 @@ class ServingEngine(_EngineBase):
                     for a in level.values()), kind=kind)
             self._ring_lengths = np.asarray(
                 [int(level["k"].shape[2]) for level in self._cache])
+            # a ring that no block divides is walked whole
+            from ..ops.ring_decode import block_rows
+            self._ring_blocks = np.asarray(
+                [block_rows(n) or n for n in self._ring_lengths])
             self._kv_rows = self._reg.counter(
                 "serve_kv_rows_attended_total", "ring rows holding a token "
                 "that decode ticks attended to, summed over layers and "
                 "active slots (what a tick has to read of the cache)")
+            self._kv_blocks = self._reg.counter(
+                "serve_kv_blocks_walked_total", "blocks of the rings "
+                "holding a token, as the ring decode kernel cuts them, "
+                "summed over layers and active slots (against slots x "
+                "blocks a ring: the share of the whole walk)")
         # an adapter whose programs return ``(logits, stats)`` (a small
         # array of per-call counts that rides the logits' read-back, no
         # sync of its own) publishes them itself: ``stats_recorder(
@@ -2166,9 +2183,13 @@ class ServingEngine(_EngineBase):
                     positions[i] = slot["pos"]
                     active[i] = True
             if self._ring_lengths is not None:
-                sp.attrs["kv_rows"] = int(np.minimum(
-                    positions[active, None] + 1, self._ring_lengths).sum())
+                rows = np.minimum(positions[active, None] + 1,
+                                  self._ring_lengths)
+                sp.attrs["kv_rows"] = int(rows.sum())
+                sp.attrs["kv_blocks"] = int(
+                    (-(-rows // self._ring_blocks)).sum())
                 self._kv_rows.inc(sp.attrs["kv_rows"])
+                self._kv_blocks.inc(sp.attrs["kv_blocks"])
         with sp.phase("dispatch"):
             n0 = self._decode_rec["n_traces"]
             t0c = time.perf_counter()

@@ -15,7 +15,14 @@ last ``length`` tokens, and for sequences that fit (``pos < length``)
 it is exactly full causal attention — the wraparound-vs-reference test
 in ``tests/test_serving.py`` pins both. One ring level is
 ``(n_slots, n_heads, length, head_dim)`` — a W×L×H×D monolith whether
-the slots are long, short, or empty.
+the slots are long, short, or empty. A decode tick goes through
+:func:`decode_token`, one call a level: on the TPU a float level whose
+ring a block divides takes the Pallas kernel of ``ops/ring_decode.py``
+(only the blocks of each live slot's ring that hold a token are read,
+one tile of the level is written, a dead slot is neither read nor
+written); a quantized level, the GSPMD-sharded engine, an odd ring
+length and every other backend keep the XLA twins :func:`write_token`
++ :func:`attend`, which write every slot and score the whole level.
 
 **Paged** (``compile_serving(kv_layout="paged")``): one fixed POOL of
 ``(n_blocks, n_heads, block_size, head_dim)`` KV blocks per layer plus
@@ -58,7 +65,9 @@ no flags, no per-slot host state, just arithmetic on ``p``.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -175,11 +184,12 @@ def write_token(level, k_new, v_new, pos):
 
     ``level``: ``{"k","v"}`` of ``(W, H, L, D)``;
     ``k_new``/``v_new``: ``(W, H, D)``; ``pos``: ``(W,)`` int — the new
-    token's position. Returns the updated level. Every slot is written
-    (the engine masks dead slots by never attending to them; a freed
-    slot's rows are fully overwritten by its next prefill before any
-    mask can reach them). A quantized level additionally writes each
-    row's fp32 scale into its per-slot scale row."""
+    token's position. Returns the updated level. The XLA path of
+    :func:`decode_token`: every slot is written here, dead ones too (the
+    position mask admits a ring index only once its occupant has written
+    it, so what a dead slot's rows hold is unreachable; the kernel path
+    does not write them at all). A quantized level additionally writes
+    each row's fp32 scale into its per-slot scale row."""
     L = level["k"].shape[2]
     pos = pos.astype(jnp.int32)
 
@@ -201,6 +211,58 @@ def write_token(level, k_new, v_new, pos):
             "v": jax.vmap(upd)(level["v"], vq, pos),
             "k_scale": jax.vmap(upd_s)(level["k_scale"], ks, pos),
             "v_scale": jax.vmap(upd_s)(level["v_scale"], vs, pos)}
+
+
+_XLA_RINGS = threading.local()
+
+
+@contextlib.contextmanager
+def xla_rings():
+    """While tracing inside this scope :func:`decode_token` keeps the
+    XLA path: the GSPMD-sharded engine enters it, because a Mosaic call
+    cannot be partitioned by a sharded jit."""
+    prev = getattr(_XLA_RINGS, "on", False)
+    _XLA_RINGS.on = True
+    try:
+        yield
+    finally:
+        _XLA_RINGS.on = prev
+
+
+def ring_block(level):
+    """Rows of a block of the ring decode kernel for this level, or None
+    where the level keeps :func:`write_token` + :func:`attend`: a
+    quantized level, a traced scope of :func:`xla_rings`, a backend
+    other than the TPU (outside the interpret-mode test hook of
+    ``ops/attention.py``), a ring length or head size the kernel has no
+    form for (``ops/ring_decode.kernel_block``)."""
+    from ..ops import ring_decode as _rd
+    if "k_scale" in level or getattr(_XLA_RINGS, "on", False):
+        return None
+    k = level["k"]
+    if k.dtype not in (jnp.bfloat16, jnp.float32) or not _rd.kernels_run():
+        return None
+    return _rd.kernel_block(*k.shape[1:])
+
+
+def decode_token(level, q, k_new, v_new, pos, active, scale):
+    """One decode tick of one ring level: write the new token's
+    ``k_new`` / ``v_new`` ``(W, H_kv, D)`` at its ring index and attend
+    ``q`` ``(W, H, 1, D)`` over the ring. Returns ``(out (W, H, 1, D),
+    level)``. The one entry the models' decode programs call; it sends a
+    level the kernel can take (:func:`ring_block`, a test on what it is
+    given) through ``ops/ring_decode.py`` — one pass over the blocks
+    that hold a token, one tile of the cache written, nothing for a slot
+    that is not ``active`` — and any other through :func:`write_token`
+    + :func:`attend`."""
+    block = ring_block(level)
+    if block is None:
+        level = write_token(level, k_new, v_new, pos)
+        return attend(q, level, pos, scale), level
+    from ..ops import ring_decode as _rd
+    out, k, v = _rd.ring_decode(q, k_new, v_new, level["k"], level["v"],
+                                pos, active, scale, block)
+    return out, {"k": k, "v": v}
 
 
 def write_prompt(level, slot, k_rows, v_rows, valid):
@@ -682,7 +744,8 @@ class BlockManager:
 
 
 __all__ = ["init_cache", "ring_positions", "ring_mask", "write_token",
-           "write_prompt", "attend", "init_pool", "write_rows",
+           "write_prompt", "attend", "decode_token", "ring_block",
+           "xla_rings", "init_pool", "write_rows",
            "gather_pages", "attend_pages", "SlotAlloc", "BlockManager",
            "HostSpillTier", "chain_keys", "prefix_chain_key",
            "affinity_hash"]

@@ -234,6 +234,9 @@ def test_engine_counts_pairs_touched_experts_and_ring_rows(toy):
     # each tick's share of the ring rows rides its span
     assert [r["kv_rows"] for r in decode[-5:]] == \
         [3 * 8 + (pos + 1) for pos in range(10, 15)]
+    # no block of the kernel divides these toy rings: each is one block
+    assert [r["kv_blocks"] for r in decode[-5:]] == [4] * 5
+    assert reg.get("serve_kv_blocks_walked_total").value() == 4 * 5
 
 
 def test_the_engine_takes_whatever_counts_an_adapter_publishes(toy,

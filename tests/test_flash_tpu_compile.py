@@ -99,3 +99,60 @@ def test_ring_form_compiles(one_chip, for_the_chip):
                                       pos_delta=delta)
     hlo = jax.jit(f).lower(x, x, x, d).compile().as_text()
     assert hlo.count("tpu_custom_call") == 1 and "flash_fwd" in hlo
+
+
+# ---------------------------------------------------------------------------
+# the ring decode kernel (ops/ring_decode.py), in this file because only one
+# test file of a run may describe the topology (module docstring)
+# ---------------------------------------------------------------------------
+
+# (slots, KV heads, query heads a KV head, ring, head size), dtype: the two
+# serve cells' levels first (GPT-2's lies with the ring on the lanes,
+# Command A+'s two are row-major), then float32 levels, grouped KV heads at
+# head size 128, and chip_smoke's toy rings
+RING_LEVELS = [
+    ((64, 16, 1, 1024, 64), jnp.bfloat16),
+    ((64, 1, 16, 4096, 128), jnp.bfloat16),
+    ((64, 1, 16, 5120, 128), jnp.bfloat16),
+    ((8, 16, 1, 1024, 64), jnp.float32),
+    ((8, 2, 1, 1024, 128), jnp.float32),
+    ((8, 8, 4, 2048, 128), jnp.bfloat16),
+    ((4, 8, 1, 256, 64), jnp.bfloat16),
+    ((4, 4, 2, 128, 32), jnp.bfloat16),
+]
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize(
+    "shape,dtype", RING_LEVELS,
+    ids=[f"{'x'.join(map(str, s))}-{jnp.dtype(d).name}"
+         for s, d in RING_LEVELS])
+def test_ring_decode_compiles_and_writes_the_level_in_place(
+        one_chip, for_the_chip, monkeypatch, shape, dtype):
+    """The kernel compiles at the level's own block, once, and the
+    compiled program aliases both halves of the level to its outputs and
+    holds no temporary of a level's size: the donated cache is written
+    where it lies, not copied."""
+    from singa_tpu.ops import ring_decode
+    monkeypatch.setattr(ring_decode, "_interpret", lambda: False)
+    W, n_kv, G, L, D = shape
+    block = ring_decode.kernel_block(n_kv, L, D)
+    assert block is not None
+
+    def sds(*s, dt=dtype):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def f(q, k_new, v_new, k, v, pos, active):
+        return ring_decode.ring_decode(q, k_new, v_new, k, v, pos, active,
+                                       D ** -0.5, block)
+
+    compiled = jax.jit(f, donate_argnums=(3, 4)).lower(
+        sds(W, n_kv * G, 1, D), sds(W, n_kv, D), sds(W, n_kv, D),
+        sds(W, n_kv, L, D), sds(W, n_kv, L, D), sds(W, dt=jnp.int32),
+        sds(W, dt=jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "ring_decode" in hlo
+    level = W * n_kv * L * D * jnp.dtype(dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * level
+    assert mem.temp_size_in_bytes < level // 4
