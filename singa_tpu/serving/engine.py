@@ -6,53 +6,44 @@ background loop / drain / fault handling / SLO metrics / crash
 blackbox):
 
 - :class:`ServingEngine` — autoregressive models (transformer LM,
-  char-rnn). TWO fixed-shape compiled programs per model:
+  char-rnn, the sparse-expert LM). TWO fixed-shape compiled programs a
+  model, each taking ``(P, state, *host arrays)`` and returning the
+  DONATED KV state and one output row a request:
 
-  * **prefill**: ``(P, cache, tokens (B_p, S_pad), lengths, slots,
-    valid) -> (cache, logits (B_p, V))`` — a fixed-width batch of
-    padded prompts writes the DONATED ring KV cache rows of its
-    assigned slots and returns last-token logits. ``valid`` masks
-    padding rows, so admitting 1 or B_p requests runs the same
-    executable.
-  * **decode**: ``(P, cache, tokens (W,), positions (W,), active (W,))
-    -> (cache, logits (W, V))`` — ONE token for every slot in O(1):
-    write the new k/v at ``pos % max_len``, attend over the ring,
-    return logits. The slot array has fixed width ``W``; finished
-    sequences free their slot mid-batch and new requests refill it via
-    the ``active`` validity mask (the ``pad_last`` mask idiom from
-    data.py), so the program NEVER retraces —
+  * **prefill**: a fixed-width batch of padded prompts writes its
+    assigned slots' KV rows and returns last-token logits; a ``valid``
+    mask covers padding rows, so admitting 1 or B_p requests runs the
+    same executable.
+  * **decode**: one tick for EVERY slot of the fixed-width slot array;
+    finished sequences free their slot mid-batch and new requests
+    refill it, so the program NEVER retraces —
     ``compiled_step_info()["n_traces"]`` is pinned at 1 by CI exactly
     like the train step's retrace guard.
 
-  Sampling happens host-side per slot through the shared
-  :mod:`singa_tpu.models.decode` helper, which is what lets
-  per-request temperature/top_k/seed vary without touching the
-  compiled program.
+  **The tick is written once; the KV format is not its business.** The
+  engine owns the slot table, the queue, sampling (host-side per slot
+  through :mod:`singa_tpu.models.decode`, which is what lets
+  per-request temperature/top_k/seed vary without touching a compiled
+  program), the spans, fault points, the counters every format has,
+  and the device state itself (``_cache``). A *layout* of
+  :mod:`.kv_cache` (``RingLayout``, ``PagedLayout``; picked once by
+  ``pick_layout`` from ``kv_layout=`` and what the adapter supports,
+  declining LOUDLY what it cannot honour) owns how that state is
+  built, which of the adapter's programs run on it, what a request
+  reserves before it is popped, how each program's host arrays are
+  packed from the batch or the slot table, a slot's candidate row
+  (one pending token; under ``speculative_k`` n-gram drafts behind it,
+  which the one accept walk of ``_run_decode`` verifies against what
+  was sampled), and how one slot's rows leave and enter the state for
+  a snapshot or a spilled block.
 
-  ``mesh=`` / ``model_shards=N`` runs BOTH programs GSPMD-sharded
-  over a named (batch × model) mesh (``parallel/gspmd.py``): params
-  and KV state are annotated with NamedSharding (heads/MLP hidden/
-  vocab over 'model', slots over 'batch'), the SAME pure bodies are
-  jitted once, and XLA inserts every collective — no hand-written
-  psum anywhere on the serve path. The sharded programs compute the
-  greedy argmax IN GRAPH over the vocab-sharded logits (the full
-  (rows, V) array never exists on any device or the host), so
-  sampled requests are a typed submit-time rejection. Every engine
-  invariant survives sharding: one trace per program, whole-state
-  donation, typed declines for configs the mesh cannot honor.
-
-  ``kv_layout="paged"`` swaps the ring for the paged BLOCK POOL
-  (:mod:`.kv_cache`): memory scales with live tokens, identical
-  prompt prefixes share refcounted blocks (a prefix-cache hit skips
-  prefill for the shared span), and pool exhaustion is a typed
-  admission refusal — never an eviction of a live sequence.
-  ``speculative_k=K`` (paged only) turns the decode program into a
-  K-token VERIFY program: a host-side n-gram proposer drafts K-1
-  tokens, one tick scores all of them, and the greedy accept/reject
-  walk emits up to K tokens with token-for-token identity to
-  sequential greedy decoding (CI-pinned). Both are still the same
-  two-fixed-shape-program contract; ineligible configurations decline
-  LOUDLY (warning + ring/plain decode), never silently.
+  ``mesh=`` / ``model_shards=N`` runs BOTH programs GSPMD-sharded over
+  a named (batch × model) mesh (``parallel/gspmd.py``): params and KV
+  state are annotated with NamedSharding, the SAME pure bodies are
+  jitted once, and XLA inserts every collective. The sharded programs
+  compute the greedy argmax IN GRAPH over the vocab-sharded logits
+  (the full (rows, V) array never exists on any device or the host),
+  so sampled requests are a typed submit-time rejection.
 
 - :class:`BatchServingEngine` — stateless models (the CNN/MLP zoo and
   ONNX imports through ``sonnx.SONNXModel``): each tick gathers up to
@@ -86,6 +77,7 @@ from ..observability import perf as _perf
 from ..observability import spans as _spans
 from ..resilience.faults import NULL_PLAN, FaultInjected
 from ..models import decode as _decode
+from . import kv_cache as _kvc
 from .scheduler import (BlockPoolExhausted, EngineDraining,
                         HandoffRefused, QueueFull, ReplicaCrashed,
                         Request, RequestQueue, RequestTimeout,
@@ -107,12 +99,6 @@ def _quiet_donation(fn, *args):
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
         return fn(*args)
-
-
-# KV level arrays in their ONE canonical serialization order: every
-# snapshot/spill frame packs present keys in this order, so the bytes
-# on both sides of a handoff agree by construction.
-_LEVEL_KEYS = ("k", "v", "k_scale", "v_scale")
 
 
 def _pack_arrays(arrays):
@@ -713,132 +699,60 @@ class ServingEngine(_EngineBase):
             self._part = part
         self.sharded = self._part is not None
 
-        # -- KV layout resolution (decline loudly, never silently) -------
-        kv_layout = str(kv_layout)
-        if kv_layout not in ("ring", "paged"):
-            raise ValueError(
-                f"kv_layout must be 'ring' or 'paged', got "
-                f"{kv_layout!r}")
-        self._kv_declined = None
-        if kv_layout == "paged" and \
-                not getattr(adapter, "supports_paged", False):
-            warnings.warn(
-                f"kv_layout='paged' declined: "
-                f"{type(adapter).__name__} has no paged block-pool "
-                "programs (its decode state is not per-position KV "
-                "rows); serving on the ring layout instead",
-                stacklevel=3)
-            self._kv_declined = "adapter_unsupported"
-            kv_layout = "ring"
-        self.kv_layout = kv_layout
-        # speculative_k = verify-program width: up to speculative_k
-        # tokens emitted per tick (speculative_k - 1 of them drafted).
-        # It needs the paged mask's position-exactness — a wrapped
-        # ring re-attributes a rejected draft's stale row INTO the
-        # sliding window (pos+1 wraps to pos-L+1), so the ring path
-        # declines rather than risking silent corruption.
-        spec = int(speculative_k or 0)
-        self._spec_declined = None
-        if spec > 1 and self.kv_layout != "paged":
-            warnings.warn(
-                "speculative_k declined: speculative decoding needs "
-                "kv_layout='paged' (the ring's wraparound would "
-                "re-attribute rejected-draft rows into the attention "
-                "window); decoding one token per tick",
-                stacklevel=3)
-            self._spec_declined = "requires_paged_layout"
-            spec = 0
-        self._spec_width = max(1, spec)
-        self.speculative_k = self._spec_width \
-            if self._spec_width > 1 else 0
+        # -- the KV layout (serving/kv_cache.py): picked once, declined
+        #    loudly where it cannot be honoured; what it decided is
+        #    republished under the names AOT export, the fleet and the
+        #    tests read
+        layout = self._layout = _kvc.pick_layout(
+            kv_layout, adapter, self._reg, slots=self.slots,
+            max_len=self.max_len, prefill_len=self.prefill_len,
+            prefill_batch=self.prefill_batch,
+            kv_block_size=kv_block_size, kv_blocks=kv_blocks,
+            speculative_k=speculative_k, spill_bytes=spill_bytes,
+            sharded=self.sharded)
+        self.kv_layout = layout.name
+        self._mgr = layout.mgr
+        self.kv_block_size = layout.block_size
+        self.kv_blocks = layout.n_blocks
+        self._max_blocks = layout.max_blocks
+        self._spec_width = layout.spec_width
+        self.speculative_k = layout.spec_width \
+            if layout.spec_width > 1 else 0
+        self.spill_bytes = int(spill_bytes or 0)
         # brownout knob: while set, no drafts are proposed (each tick
         # emits one token through the SAME compiled verify program —
         # rows padded to width 1, no retrace, greedy identity intact).
         # A fleet shed policy flips this before refusing outright.
         self._spec_throttled = False
+        self._cache = layout.init_state()
+        if layout.spill_tier is not None:
+            layout.attach_spill(self._spill_block_read,
+                                self._spill_block_write)
 
         self._prefill_rec = {"n_traces": 0}
         self._decode_rec = {"n_traces": 0}
         prefill_rec, decode_rec = self._prefill_rec, self._decode_rec
+        prefill_raw, decode_raw = layout.programs(self.sharded)
+        # a Mosaic call cannot be partitioned: under a sharded jit a
+        # ring level keeps the XLA path (a pool has no such call)
+        rings = _kvc.xla_rings if self.sharded \
+            else contextlib.nullcontext
 
-        if self.kv_layout == "paged":
-            from . import kv_cache as _kvc
-            self.kv_block_size = int(kv_block_size)
-            if self.kv_block_size < 1:
-                raise ValueError(
-                    f"kv_block_size must be >= 1, got {kv_block_size}")
-            self._max_blocks = -(-self.max_len // self.kv_block_size)
-            # default pool covers slots × max_len (no saving, full
-            # safety); a smaller kv_blocks is where paged memory
-            # elasticity lives — admission backpressure keeps it safe
-            self.kv_blocks = int(kv_blocks) if kv_blocks \
-                else self.slots * self._max_blocks
-            if self.kv_blocks < 1:
-                raise ValueError(
-                    f"kv_blocks must be >= 1, got {kv_blocks}")
-            self._mgr = _kvc.BlockManager(self.kv_blocks,
-                                          self.kv_block_size)
-            self._cache = adapter.init_pool(self.kv_blocks,
-                                            self.kv_block_size)
-            if self.sharded:
-                # sharded programs return argmax TOKENS computed over
-                # the vocab-sharded logits in graph — the full (R, V)
-                # logits array is never gathered or output
-                prefill_raw = adapter.greedy_paged_prefill_fn()
-                decode_raw = adapter.greedy_paged_decode_fn()
-            else:
-                prefill_raw = adapter.paged_prefill_fn()
-                decode_raw = adapter.paged_decode_fn()
+        def prefill_body(P, state, *host):
+            prefill_rec["n_traces"] += 1
+            return prefill_raw(P, state, *host)
 
-            def prefill_body(P, pool, tables, tokens, starts, lengths,
-                             valid):
-                prefill_rec["n_traces"] += 1
-                return prefill_raw(P, pool, tables, tokens, starts,
-                                   lengths, valid)
+        def decode_body(P, state, *host):
+            # host-side trace counter, same contract as
+            # Model._build_step: 1 forever (CI-pinned) — slots, block
+            # tables and draft rows vary per tick but their SHAPES are
+            # fixed, so refills, prefix hits and speculative ticks
+            # reuse the one executable
+            decode_rec["n_traces"] += 1
+            with rings():
+                return decode_raw(P, state, *host)
 
-            def decode_body(P, pool, tables, tokens, positions,
-                            counts):
-                # host-side trace counter, same contract as
-                # Model._build_step: 1 forever (CI-pinned) — block
-                # tables/draft rows vary per tick but their SHAPES are
-                # fixed, so prefix hits and speculative ticks reuse
-                # the one executable
-                decode_rec["n_traces"] += 1
-                return decode_raw(P, pool, tables, tokens, positions,
-                                  counts)
-        else:
-            self._mgr = None
-            self.kv_block_size = None
-            self.kv_blocks = None
-            self._cache = adapter.init_cache(self.slots, self.max_len)
-            if self.sharded:
-                prefill_raw = adapter.greedy_prefill_fn()
-                decode_raw = adapter.greedy_decode_fn()
-            else:
-                prefill_raw = adapter.prefill_fn()
-                decode_raw = adapter.decode_fn()
-
-            def prefill_body(P, cache, tokens, lengths, slot_ids,
-                             valid):
-                prefill_rec["n_traces"] += 1
-                return prefill_raw(P, cache, tokens, lengths, slot_ids,
-                                   valid)
-
-            from . import kv_cache as _kvc
-            # a Mosaic call cannot be partitioned: the sharded engine's
-            # rings keep the XLA path
-            rings = _kvc.xla_rings if self.sharded \
-                else contextlib.nullcontext
-
-            def decode_body(P, cache, tokens, positions, active):
-                # host-side trace counter, same contract as
-                # Model._build_step: the serve path must keep this at 1
-                decode_rec["n_traces"] += 1
-                with rings():
-                    return decode_raw(P, cache, tokens, positions, active)
-
-        jit_kw_prefill = {}
-        jit_kw_decode = {}
+        jit_kw = {"prefill": {}, "decode": {}}
         if self._part is not None:
             # annotate the named state + KV layout once, jit the same
             # pure bodies: XLA's SPMD partitioner inserts the
@@ -855,23 +769,19 @@ class ServingEngine(_EngineBase):
             p_sh = self._part.sharding_tree(pspecs)
             c_sh = self._part.sharding_tree(cspecs)
             tok_sh = self._part.sharding(io["tokens_out"])
-            arg = self._part.sharding
-            jit_kw_prefill = dict(
-                in_shardings=(p_sh, c_sh,
-                              *(arg(s) for s in io["prefill"])),
-                out_shardings=(c_sh, tok_sh))
-            jit_kw_decode = dict(
-                in_shardings=(p_sh, c_sh,
-                              *(arg(s) for s in io["decode"])),
-                out_shardings=(c_sh, tok_sh))
+            for program, kw in jit_kw.items():
+                kw.update(
+                    in_shardings=(p_sh, c_sh, *(self._part.sharding(s)
+                                                for s in io[program])),
+                    out_shardings=(c_sh, tok_sh))
         self._hbm_dev = _perf.first_jax_device(self._cache)
         # the KV state (ring cache or block pool) is DONATED: the one
         # large serving buffer is updated in place by XLA instead of
         # doubling per tick
         self._prefill = jax.jit(prefill_body, donate_argnums=(1,),
-                                **jit_kw_prefill)
+                                **jit_kw["prefill"])
         self._decode = jax.jit(decode_body, donate_argnums=(1,),
-                               **jit_kw_decode)
+                               **jit_kw["decode"])
         # warm restart: deserialize previously exported prefill/decode
         # executables (honored-or-refused per artifact — a refused one
         # compiles fresh, loudly). The trace that produced a loaded
@@ -907,42 +817,6 @@ class ServingEngine(_EngineBase):
         self._reg.gauge("serve_slots",
                         "slot array width (max in-flight sequences)"
                         ).set(self.slots)
-        self._ring_lengths = None
-        if self.kv_layout == "ring" and isinstance(self._cache, list) \
-                and all(isinstance(lv, dict) and "k" in lv
-                        for lv in self._cache):
-            # what the rings hold, by kind of layer (a recurrent
-            # adapter's state is no ring and has no such gauge): an
-            # adapter whose layers keep rings of different lengths names
-            # each level's kind (``cache_kinds``); one geometry reads as
-            # "full"
-            kinds = getattr(adapter, "cache_kinds", None)
-            kinds = kinds() if kinds is not None \
-                else ["full"] * len(self._cache)
-            kv_bytes = self._reg.gauge(
-                "serve_kv_bytes", "bytes of ring KV state, by kind of "
-                "layer (window: min(window, max_len) positions a slot; "
-                "full: max_len)", labels=("kind",))
-            for kind in sorted(set(kinds)):
-                kv_bytes.set(sum(
-                    int(a.size) * a.dtype.itemsize
-                    for k, level in zip(kinds, self._cache) if k == kind
-                    for a in level.values()), kind=kind)
-            self._ring_lengths = np.asarray(
-                [int(level["k"].shape[2]) for level in self._cache])
-            # a ring that no block divides is walked whole
-            from ..ops.ring_decode import block_rows
-            self._ring_blocks = np.asarray(
-                [block_rows(n) or n for n in self._ring_lengths])
-            self._kv_rows = self._reg.counter(
-                "serve_kv_rows_attended_total", "ring rows holding a token "
-                "that decode ticks attended to, summed over layers and "
-                "active slots (what a tick has to read of the cache)")
-            self._kv_blocks = self._reg.counter(
-                "serve_kv_blocks_walked_total", "blocks of the rings "
-                "holding a token, as the ring decode kernel cuts them, "
-                "summed over layers and active slots (against slots x "
-                "blocks a ring: the share of the whole walk)")
         # an adapter whose programs return ``(logits, stats)`` (a small
         # array of per-call counts that rides the logits' read-back, no
         # sync of its own) publishes them itself: ``stats_recorder(
@@ -981,39 +855,6 @@ class ServingEngine(_EngineBase):
             "in-flight KV snapshots checkpointed on the "
             "snapshot_every cadence (crash re-dispatch resumes from "
             "the newest one instead of token zero)")
-        if self.kv_layout == "paged":
-            # pool-pressure gauges: what /metrics.json and the
-            # heartbeat fleet view read to see a replica running out
-            # of KV blocks before requests start backing up
-            self._reg.gauge(
-                "kv_blocks_total",
-                "paged KV pool size in blocks").set(self.kv_blocks)
-            self._blocks_in_use = self._reg.gauge(
-                "kv_blocks_in_use",
-                "pool blocks referenced by live sequences (never "
-                "evicted)")
-            self._blocks_cached = self._reg.gauge(
-                "kv_blocks_cached",
-                "unreferenced blocks held by the prefix cache "
-                "(reclaimable, LRU)")
-            self._prefix_hits = self._reg.counter(
-                "prefix_cache_hits_total",
-                "admitted prompts whose prefix matched cached blocks "
-                "(prefill skipped for the shared span)")
-            self._prefix_tokens = self._reg.counter(
-                "prefix_cache_tokens_total",
-                "prompt tokens served from cached prefix blocks "
-                "instead of prefill compute")
-            self._spec_proposed = self._reg.counter(
-                "speculative_proposed_total",
-                "draft tokens proposed to the verify program")
-            self._spec_accepted = self._reg.counter(
-                "speculative_accepted_total",
-                "draft tokens accepted by the greedy verify rule")
-            self._spec_ratio = self._reg.gauge(
-                "speculative_accepted_ratio",
-                "cumulative accepted/proposed draft-token ratio (the "
-                "speculative speedup is roughly 1 + ratio × (k-1))")
         if self.sharded:
             # fleet-view honesty: the mesh shape plus what ONE chip
             # actually holds — heartbeat_summary's serving_kv block and
@@ -1037,55 +878,6 @@ class ServingEngine(_EngineBase):
                 "serve_kv_global_bytes",
                 "logical (unsharded) KV state bytes across the mesh"
             ).set(self._part.global_bytes(self._cache))
-
-        # -- host-RAM spill tier (paged, single-device) -------------------
-        self.spill_bytes = int(spill_bytes or 0)
-        self._spill_tier = None
-        self._spill_declined = None
-        if self.spill_bytes > 0:
-            if self.kv_layout != "paged":
-                warnings.warn(
-                    "spill_bytes declined: the host-RAM spill tier "
-                    "parks evicted cached-prefix BLOCKS, which only "
-                    "the paged layout has", stacklevel=3)
-                self._spill_declined = "requires_paged_layout"
-            elif self.sharded:
-                warnings.warn(
-                    "spill_bytes declined: a sharded pool's blocks "
-                    "are sliced over the mesh ('model' axis) — a "
-                    "host spill/restore would need per-device "
-                    "gathers; serve single-device to spill",
-                    stacklevel=3)
-                self._spill_declined = "sharded"
-            else:
-                from . import kv_cache as _kvc_spill
-                tier = _kvc_spill.HostSpillTier(self.spill_bytes)
-                self._spill_tier = tier
-                spill_c = self._reg.counter(
-                    "serve_kv_spill_total",
-                    "cached-prefix blocks spilled to the host-RAM "
-                    "tier on pool eviction")
-                restore_c = self._reg.counter(
-                    "serve_kv_restore_total",
-                    "prefix blocks restored from the host-RAM tier "
-                    "instead of being re-prefilled")
-                spill_g = self._reg.gauge(
-                    "serve_kv_spill_bytes",
-                    "bytes the host-RAM spill tier currently holds "
-                    f"(budget {self.spill_bytes})")
-
-                def _on_spill():
-                    spill_c.inc()
-                    spill_g.set(tier.bytes_used)
-
-                def _on_restore():
-                    restore_c.inc()
-                    spill_g.set(tier.bytes_used)
-
-                self._mgr.attach_spill(
-                    tier, self._spill_block_read,
-                    self._spill_block_write,
-                    on_spill=_on_spill, on_restore=_on_restore)
 
     # -- AOT export / warm restart -----------------------------------------
     def _load_aot(self, store):
@@ -1161,25 +953,11 @@ class ServingEngine(_EngineBase):
                 "vocab logits on the host, which the sharded decode "
                 "program never materialises — submit with "
                 "temperature=0, or serve this model unsharded")
-        if self.kv_layout == "paged":
-            total = int(prompt.size) + int(max_new_tokens)
-            if total > self.max_len:
-                self.queue.finish("rejected")
-                raise ServingError(
-                    f"prompt ({prompt.size}) + max_new_tokens "
-                    f"({int(max_new_tokens)}) = {total} exceeds "
-                    f"max_len {self.max_len}: the paged layout is "
-                    "exact full attention within max_len (no logical "
-                    "slot exists past it) — raise max_len, or use the "
-                    "ring layout for sliding-window generation")
-            if self._mgr.n_for(total) > self._mgr.n_blocks:
-                self.queue.finish("rejected")
-                raise BlockPoolExhausted(
-                    f"request needs {self._mgr.n_for(total)} KV blocks "
-                    f"but the whole pool is {self._mgr.n_blocks} "
-                    f"(× {self.kv_block_size} tokens): it can NEVER "
-                    "be admitted — raise kv_blocks or lower "
-                    "max_new_tokens")
+        err = self._layout.never_fits(int(prompt.size),
+                                      int(max_new_tokens))
+        if err is not None:
+            self.queue.finish("rejected")
+            raise err
         req = Request(prompt, max_new_tokens=max_new_tokens,
                       temperature=temperature, top_k=top_k,
                       eos_id=eos_id, seed=seed, timeout=timeout,
@@ -1205,10 +983,7 @@ class ServingEngine(_EngineBase):
                 # store. The chaos warm-restart gate reads this off
                 # /healthz.
                 "aot": self._aot_source}
-        if self._kv_declined:
-            info["kv_layout_declined"] = self._kv_declined
-        if self._spec_declined:
-            info["speculative_declined"] = self._spec_declined
+        info.update(self._layout.declined)
         if self.sharded:
             # /healthz honesty under sharding: the mesh shape and what
             # ONE device holds (not the global logical pool)
@@ -1218,25 +993,7 @@ class ServingEngine(_EngineBase):
                 self._part.per_device_bytes(self._cache)
             info["kv_global_bytes"] = \
                 self._part.global_bytes(self._cache)
-            if self.kv_layout == "ring":
-                info["slots_per_device"] = \
-                    self.slots // self._part.batch_shards
-        if self.kv_layout == "paged":
-            info.update(
-                kv_block_size=self.kv_block_size,
-                kv_blocks=self.kv_blocks,
-                kv_blocks_in_use=self._mgr.blocks_live(),
-                kv_blocks_cached=self._mgr.blocks_cached(),
-                prefix_cache_entries=len(self._mgr._cache))
-            if self._spill_tier is not None:
-                info["spill"] = {
-                    "budget_bytes": self._spill_tier.budget_bytes,
-                    "bytes_used": self._spill_tier.bytes_used,
-                    "entries": len(self._spill_tier),
-                    "spilled_total": self._mgr.spilled_total,
-                    "restored_total": self._mgr.restored_total}
-        if self._spill_declined:
-            info["spill_declined"] = self._spill_declined
+        info.update(self._layout.info(self._part))
         if self.snapshot_every:
             info["snapshot_every"] = self.snapshot_every
         return info
@@ -1344,20 +1101,14 @@ class ServingEngine(_EngineBase):
         quantization policy. Rides every frame's CRC-covered meta."""
         level = self._cache[0]
         shape = tuple(int(d) for d in level["k"].shape)
-        g = {"layout": self.kv_layout,
-             "n_layers": len(self._cache),
+        g = {"n_layers": len(self._cache),
              "dtype": str(level["k"].dtype),
              "quantized": "k_scale" in level,
              "heads": shape[1], "head_dim": shape[3],
              "max_len": int(self.max_len),
              "policy": self.policy.describe()
              if self.policy is not None else None}
-        if self.kv_layout == "paged":
-            g["block_size"] = int(self.kv_block_size)
-        elif self._ring_lengths is not None and \
-                any(n != self.max_len for n in self._ring_lengths):
-            # layers with rings of their own length (window layers)
-            g["ring_lengths"] = [int(n) for n in self._ring_lengths]
+        g.update(self._layout.geometry())
         return g
 
     @staticmethod
@@ -1377,19 +1128,8 @@ class ServingEngine(_EngineBase):
         keeps running."""
         slot = self._slots[i]
         req = slot["req"]
-        arrays = []
-        if self.kv_layout == "paged":
-            bids = np.asarray(slot["alloc"].blocks, np.int32)
-            for level in self._cache:
-                for name in _LEVEL_KEYS:
-                    if name in level:
-                        arrays.append(np.asarray(level[name][bids]))
-        else:
-            for level in self._cache:
-                for name in _LEVEL_KEYS:
-                    if name in level:
-                        arrays.append(np.asarray(level[name][i]))
-        specs, payload = _pack_arrays(arrays)
+        specs, payload = _pack_arrays(
+            self._layout.read_slot(self._cache, i, slot["alloc"]))
         doc = {"v": 1, "kind": "kv_snapshot",
                "geometry": self._handoff_geometry(),
                "prompt": [int(t) for t in req.prompt],
@@ -1474,16 +1214,11 @@ class ServingEngine(_EngineBase):
                 ValueError) as e:
             self._handoff_refused.inc()
             raise HandoffRefused(f"snapshot refused: {e}")
-        if self.kv_layout == "paged":
-            total = int(prompt.size) + max_new
-            if total > self.max_len or \
-                    self._mgr.n_for(total) > self._mgr.n_blocks:
-                self._handoff_refused.inc()
-                raise HandoffRefused(
-                    f"snapshot needs {total} token positions "
-                    f"({self._mgr.n_for(total)} blocks) but this "
-                    f"engine caps at max_len {self.max_len} / "
-                    f"{self._mgr.n_blocks} blocks")
+        err = self._layout.never_fits(int(prompt.size), max_new)
+        if err is not None:
+            self._handoff_refused.inc()
+            raise HandoffRefused(
+                f"snapshot can never be placed here: {err}")
         # the request keeps ITS deadline (snapshot-carried remainder);
         # `timeout` bounds only how long the snapshot may wait for a
         # slot — a handoff budget must not shorten the request's life
@@ -1512,12 +1247,9 @@ class ServingEngine(_EngineBase):
         return req.future
 
     def _place_injects(self, now):
-        """Move validated snapshots into free slots (paged: once their
-        block reservation fits — BlockPoolExhausted is backpressure,
-        the snapshot stays pending). The write path is host-side
-        ``.at[].set`` on the cache arrays OUTSIDE the two compiled
-        serve programs: no retrace, and the fresh buffers are donated
-        on the next tick exactly like any other."""
+        """Move validated snapshots into free slots, once what the
+        layout reserves for them fits (BlockPoolExhausted is
+        backpressure: the snapshot stays pending)."""
         while self._injects:
             free = [i for i, s in enumerate(self._slots) if s is None]
             if not free:
@@ -1532,27 +1264,19 @@ class ServingEngine(_EngineBase):
                         "be placed"))
                     self.queue.finish("timed_out")
                 continue
-            alloc = None
-            if self.kv_layout == "paged":
-                try:
-                    alloc = self._mgr.admit(
-                        req.prompt,
-                        int(req.prompt.size) + req.max_new_tokens)
-                except BlockPoolExhausted:
-                    return          # backpressure: retry next tick
+            try:
+                alloc = self._layout.reserve(req.prompt,
+                                             req.max_new_tokens)
+            except BlockPoolExhausted:
+                return              # backpressure: retry next tick
             self._injects.popleft()
             try:
-                self._write_snapshot(arrays, free[0], alloc)
+                self._cache = self._layout.write_slot(
+                    self._cache, arrays, free[0], alloc)
             except Exception as e:  # noqa: BLE001 — typed refusal below
                 if alloc is not None:
-                    from . import kv_cache as _kvc_r
-                    # never cache the partially-written blocks: a
-                    # zero-prompt_blocks release frees them uncached
-                    self._mgr.release(
-                        _kvc_r.SlotAlloc(alloc.blocks,
-                                         alloc.shared_tokens, 0),
-                        req.prompt)
-                    self._update_pool_gauges()
+                    # never cache the partially-written blocks
+                    self._layout.release(alloc, req.prompt, cache=False)
                 self._handoff_refused.inc()
                 if not req.future.done():
                     req.future.set_error(HandoffRefused(
@@ -1566,49 +1290,6 @@ class ServingEngine(_EngineBase):
                 _spans.event("request.injected",
                              request=req.trace_id, slot=free[0],
                              tokens=len(req.tokens))
-            self._update_pool_gauges()
-
-    def _write_snapshot(self, arrays, slot_idx, alloc):
-        """Write a validated snapshot's rows into the pool. Paged
-        allocations skip their already-correct leading blocks (prefix
-        cache hits / spill restores cover the same positions with
-        bitwise-identical content under greedy determinism)."""
-        import jax.numpy as jnp
-        if self.kv_layout == "paged":
-            skip = alloc.shared_tokens // self.kv_block_size
-            bids = jnp.asarray(alloc.blocks[skip:], jnp.int32)
-        it = iter(arrays)
-        new_cache = []
-        for level in self._cache:
-            upd = dict(level)
-            for name in _LEVEL_KEYS:
-                if name not in level:
-                    continue
-                arr = next(it)
-                if self.kv_layout == "paged":
-                    if arr.shape[0] != len(alloc.blocks) or \
-                            tuple(arr.shape[1:]) != \
-                            tuple(level[name].shape[1:]):
-                        raise HandoffRefused(
-                            f"snapshot array {name} shape "
-                            f"{arr.shape} does not cover this "
-                            f"allocation ({len(alloc.blocks)} blocks "
-                            f"of {tuple(level[name].shape[1:])})")
-                    sub = arr[skip:]
-                    if len(sub):
-                        upd[name] = level[name].at[bids].set(
-                            jnp.asarray(sub))
-                else:
-                    if tuple(arr.shape) != \
-                            tuple(level[name].shape[1:]):
-                        raise HandoffRefused(
-                            f"snapshot array {name} shape "
-                            f"{arr.shape} does not match this ring's "
-                            f"slot rows {level[name].shape[1:]}")
-                    upd[name] = level[name].at[slot_idx].set(
-                        jnp.asarray(arr))
-            new_cache.append(upd)
-        self._cache = new_cache
 
     def _checkpoint_inflight(self):
         """Cadence crash armor: snapshot every active slot to host
@@ -1637,12 +1318,8 @@ class ServingEngine(_EngineBase):
     def _spill_block_read(self, bid):
         """Pull ONE pool block's rows (every layer, payloads and
         scales) to host for the spill tier."""
-        arrays = []
-        for level in self._cache:
-            for name in _LEVEL_KEYS:
-                if name in level:
-                    arrays.append(np.asarray(level[name][int(bid)]))
-        specs, payload = _pack_arrays(arrays)
+        specs, payload = _pack_arrays(
+            self._layout.read_block(self._cache, bid))
         doc = {"v": 1, "kind": "kv_block",
                "geometry": self._handoff_geometry(), "arrays": specs}
         return _integrity.frame_meta(doc), payload
@@ -1651,30 +1328,15 @@ class ServingEngine(_EngineBase):
         """Restore one spilled block's rows into pool block ``bid``.
         Raises on any mismatch — the BlockManager catches and degrades
         to re-prefilling the span, never writes a wrong block."""
-        import jax.numpy as jnp
         doc = _integrity.parse_frame_meta(meta)
         if doc.get("kind") != "kv_block" or self._geometry_mismatch(
                 doc.get("geometry"), self._handoff_geometry()):
             raise HandoffRefused(
                 "spilled block does not match this engine's pool "
                 "geometry")
-        arrays = _unpack_arrays(doc.get("arrays", ()), payload)
-        it = iter(arrays)
-        new_cache = []
-        for level in self._cache:
-            upd = dict(level)
-            for name in _LEVEL_KEYS:
-                if name in level:
-                    arr = next(it)
-                    if tuple(arr.shape) != \
-                            tuple(level[name].shape[1:]):
-                        raise HandoffRefused(
-                            f"spilled block array {name} shape "
-                            f"{arr.shape} != {level[name].shape[1:]}")
-                    upd[name] = level[name].at[int(bid)].set(
-                        jnp.asarray(arr))
-            new_cache.append(upd)
-        self._cache = new_cache
+        self._cache = self._layout.write_block(
+            self._cache, bid, _unpack_arrays(doc.get("arrays", ()),
+                                             payload))
 
     # -- deadline drain (handoff pass) -------------------------------------
     def _drain_handoff_pass(self, now):
@@ -1739,18 +1401,12 @@ class ServingEngine(_EngineBase):
             s is not None for s in self._slots)
 
     def _release_blocks(self, slot):
-        """Return a finished/failed paged sequence's block references
-        to the manager (its full prompt blocks enter the prefix
-        cache); no-op for ring slots."""
+        """Return what a finished/failed sequence had reserved to its
+        layout (paged: its full prompt blocks enter the prefix
+        cache)."""
         alloc = slot.get("alloc")
-        if alloc is not None and self._mgr is not None:
-            self._mgr.release(alloc, slot["req"].prompt)
-            self._update_pool_gauges()
-
-    def _update_pool_gauges(self):
-        if self._mgr is not None:
-            self._blocks_in_use.set(self._mgr.blocks_live())
-            self._blocks_cached.set(self._mgr.blocks_cached())
+        if alloc is not None:
+            self._layout.release(alloc, slot["req"].prompt)
 
     def _count_inflight(self):
         return self.active_slots()
@@ -1773,15 +1429,12 @@ class ServingEngine(_EngineBase):
         self._occupancy.set(0)
 
     def _fail_batch(self, batch, exc):
-        # popped-but-never-slotted paged requests carry their block
-        # reservation on the request: give it back before failing them
+        # popped-but-never-slotted requests carry what the admit
+        # predicate reserved for them: give it back before failing them
         for req in batch:
-            alloc = getattr(req, "_alloc", None)
-            if alloc is not None and self._mgr is not None:
-                self._mgr.release(alloc, req.prompt)
+            if req._alloc is not None:
+                self._layout.release(req._alloc, req.prompt)
                 req._alloc = None
-        if self._mgr is not None:
-            self._update_pool_gauges()
         super()._fail_batch(batch, exc)
 
     def _finish_slot(self, i, status="completed"):
@@ -1812,28 +1465,22 @@ class ServingEngine(_EngineBase):
             req.future.set_error(ServingError(status))
         self.queue.finish(status)
 
-    def _sample_and_place(self, req, out_row, slot_idx, pos, at,
-                          alloc=None):
-        """Shared first-token/next-token bookkeeping: resolve the
-        program output row into a token, record, finish or keep the
-        slot hot. ``out_row`` is a logits vector on the single-device
-        engines and an in-graph-argmax'd token id on the sharded ones
-        — the ONE place that split is decided. ``at`` is the token's
-        stamp (``ServeFuture.token_times``): the clock as the program's
-        output reached the host, one reading for the whole batch.
-        ``alloc`` is the paged block reservation riding the slot."""
+    def _sample_and_place(self, req, out_row, at):
+        """One program output row resolved into the request's next
+        token and placed on its stream; returns ``(token, done)``.
+        ``out_row`` is a logits vector on the single-device engines and
+        an in-graph-argmax'd token id on the sharded ones — the ONE
+        place that split is decided. ``at`` is the token's stamp
+        (``ServeFuture.token_times``): the clock as the program's
+        output reached the host, one reading for the whole batch."""
         tok = int(out_row) if self.sharded else _decode.sample_logits(
             out_row, temperature=req.temperature, top_k=req.top_k,
             rng=req.rng)
         req.tokens.append(tok)
         req.future.token_times.append(at)
         self._tokens_total.inc()
-        done = (len(req.tokens) >= req.max_new_tokens or
-                (req.eos_id is not None and tok == req.eos_id))
-        self._slots[slot_idx] = {"req": req, "pos": pos, "tok": tok,
-                                 "alloc": alloc}
-        if done:
-            self._finish_slot(slot_idx)
+        return tok, (len(req.tokens) >= req.max_new_tokens or
+                     (req.eos_id is not None and tok == req.eos_id))
 
     def _tick(self):
         """One continuous-batching tick, as ONE ``serve.tick`` span
@@ -1862,29 +1509,16 @@ class ServingEngine(_EngineBase):
                 if slot is not None and slot["req"].expired(now):
                     self._finish_slot(i, status="timed_out")
 
-        # 2) admit: fill free slots, a fixed-width prefill batch per tick.
-        #    A paged engine additionally gates each pop on the block
-        #    pool: the admit predicate RESERVES the request's blocks
-        #    (prefix-shared ones re-referenced) so a batch can never
-        #    over-commit the pool; a request that doesn't fit right now
-        #    stays at the head of the queue (backpressure, FIFO-fair —
-        #    live sequences are never evicted to make room).
+        # 2) admit: fill free slots, a fixed-width prefill batch per
+        #    tick; where the layout reserves (its ``admit``), a request
+        #    that does not fit right now stays at the head of the queue
         batch = []
         with tick.phase("admit"):
             free = [i for i, s in enumerate(self._slots) if s is None]
             if free and len(self.queue) > 0:
-                admit = None
-                if self.kv_layout == "paged":
-                    def admit(req):
-                        try:
-                            req._alloc = self._mgr.admit(
-                                req.prompt,
-                                int(req.prompt.size) + req.max_new_tokens)
-                            return True
-                        except BlockPoolExhausted:
-                            return False
                 batch = self.queue.pop_batch(
-                    min(len(free), self.prefill_batch), now, admit=admit)
+                    min(len(free), self.prefill_batch), now,
+                    admit=self._layout.admit)
             tick.attrs["admitted"] = len(batch)
             tick.attrs["queue_depth"] = len(self.queue)
         if batch:
@@ -1930,19 +1564,28 @@ class ServingEngine(_EngineBase):
             self._tick_ewma = dt if not self._tick_ewma \
                 else 0.8 * self._tick_ewma + 0.2 * dt
 
-    def _run_prefill(self, batch, free, sp):
-        """``sp`` is the open ``serve.prefill`` span; both layouts
-        split it into ``pack`` (the numpy inputs), ``dispatch`` (the
-        program call until it returns), ``readback`` (its output to
-        the host) and ``place`` (first tokens sampled, slots filled)."""
-        if self.kv_layout == "paged":
-            return self._run_prefill_paged(batch, free, sp)
-        return self._run_prefill_ring(batch, free, sp)
+    def _dispatch(self, fn, rec, program, names, host):
+        """One call of a serve program on the DONATED state, which comes
+        back threaded into ``self._cache``; returns the program's other
+        output. A call that traced (the ``n_traces`` delta) is
+        attributed: a first compile, or the retrace that breaks the
+        one-trace contract, with what changed."""
+        n0 = rec["n_traces"]
+        t0 = time.perf_counter()
+        cc0 = _cache_counts()
+        self._cache, out = _quiet_donation(fn, self._P, self._cache,
+                                           *host)
+        if rec["n_traces"] > n0:
+            _attribute_trace(rec, self._reg, program, list(host), names,
+                             t0, cc0)
+        return out
 
     def _read_out(self, out, sp, program):
-        """A ring program's output to the host. Where the adapter records
-        its programs' counts (``stats_recorder``), they come over with
-        the logits, and what it returns goes on the span."""
+        """A program's output to the host: ``(rows, V)`` logits, or the
+        in-graph argmax tokens when sharded (the full-vocab array never
+        reaches the host). Where the adapter records its programs'
+        counts (``stats_recorder``), they come over with it, and what
+        the recorder returns goes on the span."""
         if self._record_stats is None:
             return np.asarray(out)
         out, stats = out
@@ -1950,279 +1593,102 @@ class ServingEngine(_EngineBase):
         sp.attrs.update(self._record_stats(program, np.asarray(stats)))
         return out
 
-    def _run_prefill_ring(self, batch, free, sp):
+    def _run_prefill(self, batch, free, sp):
+        """``sp`` is the open ``serve.prefill`` span, split into
+        ``pack`` (the layout's numpy inputs), ``dispatch`` (the program
+        call until it returns), ``readback`` (its output to the host)
+        and ``place`` (first tokens sampled, slots filled)."""
+        layout = self._layout
         with sp.phase("pack"):
-            B, S = self.prefill_batch, self.prefill_len
-            tokens = np.zeros((B, S), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            slot_ids = np.zeros((B,), np.int32)
-            valid = np.zeros((B,), bool)
-            placed = []
-            for b, req in enumerate(batch):
-                n = req.prompt.size
-                tokens[b, :n] = req.prompt
-                lengths[b] = n
-                slot_ids[b] = free[b]
-                valid[b] = True
-                placed.append((req, free[b]))
-                self._prefill_tok.inc(int(n))
+            host, placed, n_tokens = layout.pack_prefill(batch, free)
+            self._prefill_tok.inc(n_tokens)
         with sp.phase("dispatch"):
-            n0 = self._prefill_rec["n_traces"]
-            t0c = time.perf_counter()
-            cc0 = _cache_counts()
-            self._cache, out = _quiet_donation(
-                self._prefill, self._P, self._cache, tokens, lengths,
-                slot_ids, valid)
-            if self._prefill_rec["n_traces"] > n0:
-                _attribute_trace(self._prefill_rec, self._reg,
-                                 "serve_prefill",
-                                 [tokens, lengths, slot_ids, valid],
-                                 ("tokens", "lengths", "slot_ids",
-                                  "valid"), t0c, cc0)
+            out = self._dispatch(self._prefill, self._prefill_rec,
+                                 "serve_prefill", layout.prefill_names,
+                                 host)
         with sp.phase("readback"):
-            # (B, V) logits single-device; (B,) in-graph argmax tokens
-            # when sharded (the full-vocab array never reaches the host)
             out = self._read_out(out, sp, "prefill")
         with sp.phase("place"):
             at = time.monotonic()
-            for b, (req, slot_idx) in enumerate(placed):
+            for b, (req, slot_idx, alloc) in enumerate(placed):
+                req._alloc = None   # the slot owns the reservation now
                 req.first_token_at = at
                 self._ttft.observe(at - req.submitted_at)
                 self._prefills.inc()
                 if self._trace_requests:
+                    hit = {} if alloc is None else \
+                        {"prefix_hit_tokens": int(alloc.shared_tokens)}
                     _spans.event("request.prefill",
                                  request=req.trace_id, slot=slot_idx,
-                                 prompt_len=int(req.prompt.size))
+                                 prompt_len=int(req.prompt.size), **hit)
                 # the first generated token sits at position prompt_len;
                 # its k/v are written by the NEXT decode tick
-                self._sample_and_place(req, out[b], slot_idx,
-                                       pos=int(req.prompt.size), at=at)
-
-    def _run_prefill_paged(self, batch, free, sp):
-        """Paged admission: each popped request arrives with its block
-        reservation already taken (the pop predicate); a prefix-cache
-        hit enters the compiled program with ``start > 0`` and only
-        its SUFFIX tokens — the shared span's prefill is skipped
-        entirely, its K/V served from the refcounted cached blocks."""
-        with sp.phase("pack"):
-            B, S = self.prefill_batch, self.prefill_len
-            tokens = np.zeros((B, S), np.int32)
-            starts = np.zeros((B,), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            tables = np.zeros((B, self._max_blocks), np.int32)
-            valid = np.zeros((B,), bool)
-            placed = []
-            for b, req in enumerate(batch):
-                alloc = req._alloc
-                suffix = req.prompt[alloc.shared_tokens:]
-                tokens[b, :suffix.size] = suffix
-                starts[b] = alloc.shared_tokens
-                lengths[b] = suffix.size
-                tables[b, :len(alloc.blocks)] = alloc.blocks
-                valid[b] = True
-                placed.append((req, free[b], alloc))
-                self._prefill_tok.inc(int(suffix.size))
-                if alloc.shared_tokens:
-                    self._prefix_hits.inc()
-                    self._prefix_tokens.inc(alloc.shared_tokens)
-        with sp.phase("dispatch"):
-            n0 = self._prefill_rec["n_traces"]
-            t0c = time.perf_counter()
-            cc0 = _cache_counts()
-            self._cache, out = _quiet_donation(
-                self._prefill, self._P, self._cache, tables, tokens,
-                starts, lengths, valid)
-            if self._prefill_rec["n_traces"] > n0:
-                _attribute_trace(self._prefill_rec, self._reg,
-                                 "serve_prefill",
-                                 [tables, tokens, starts, lengths,
-                                  valid],
-                                 ("tables", "tokens", "starts",
-                                  "lengths", "valid"), t0c, cc0)
-        with sp.phase("readback"):
-            out = np.asarray(out)  # (B, V) logits, or (B,) sharded toks
-        with sp.phase("place"):
-            at = time.monotonic()
-            self._update_pool_gauges()
-            for b, (req, slot_idx, alloc) in enumerate(placed):
-                req._alloc = None  # the slot owns the reservation now
-                req.first_token_at = at
-                self._ttft.observe(at - req.submitted_at)
-                self._prefills.inc()
-                if self._trace_requests:
-                    _spans.event(
-                        "request.prefill", request=req.trace_id,
-                        slot=slot_idx, prompt_len=int(req.prompt.size),
-                        prefix_hit_tokens=int(alloc.shared_tokens))
-                # the first generated token sits at position prompt_len;
-                # its k/v are written by the NEXT decode tick
-                self._sample_and_place(req, out[b], slot_idx,
-                                       pos=int(req.prompt.size), at=at,
-                                       alloc=alloc)
+                tok, done = self._sample_and_place(req, out[b], at)
+                self._slots[slot_idx] = {
+                    "req": req, "pos": int(req.prompt.size), "tok": tok,
+                    "alloc": alloc}
+                if done:
+                    self._finish_slot(slot_idx)
 
     def _run_decode(self, sp):
-        """``sp`` is the open ``serve.decode`` span; both layouts split
-        it into ``pack`` (the numpy inputs, n-gram drafting),
-        ``dispatch`` (the program call until it returns), ``readback``
-        (wait for the device, logits to the host) and ``sample`` (the
-        per-slot loop: sampling, accept walk, events, finishing)."""
-        if self.kv_layout == "paged":
-            return self._run_decode_paged(sp)
-        return self._run_decode_ring(sp)
+        """``sp`` is the open ``serve.decode`` span, split into ``pack``
+        (the layout's numpy inputs, n-gram drafting), ``dispatch`` (the
+        program call until it returns), ``readback`` (wait for the
+        device, its output to the host) and ``sample`` (the per-slot
+        accept walk, events, finishing).
 
-    def _run_decode_paged(self, sp):
-        """One verify tick: every active slot's row is its pending
-        token plus up to ``speculative_k - 1`` n-gram drafts; the ONE
-        compiled program writes all rows' k/v and scores every
-        position, and the host accept/reject walk emits the longest
-        prefix of drafts matching greedy — each emitted token is
-        EXACTLY what sequential greedy would have produced (the CI
-        parity invariant). Rejected drafts leave stale rows at
-        positions past the new ``pos``; the position-exact paged mask
-        keeps them unreachable until overwritten."""
+        Every live slot has a row of candidates the ONE program scored:
+        its pending token and, under speculation, drafts behind it. The
+        walk emits the longest prefix of drafts matching what was
+        sampled — each emitted token EXACTLY what sequential greedy
+        decoding would have produced (the CI parity invariant) — and at
+        one candidate it is one token a slot."""
+        layout = self._layout
         with sp.phase("pack"):
-            W, K = self.slots, self._spec_width
-            tokens = np.zeros((W, K), np.int32)
-            positions = np.zeros((W,), np.int32)
-            counts = np.zeros((W,), np.int32)
-            tables = np.zeros((W, self._max_blocks), np.int32)
-            rows = {}
-            for i, slot in enumerate(self._slots):
+            host, rows = layout.pack_decode(
+                self._slots, sp.attrs, not self._spec_throttled)
+        with sp.phase("dispatch"):
+            out = self._dispatch(self._decode, self._decode_rec,
+                                 "serve_decode", layout.decode_names,
+                                 host)
+        with sp.phase("readback"):
+            out = self._read_out(out, sp, "decode")
+            if not layout.candidate_axis:
+                out = out[:, None]
+        with sp.phase("sample"):
+            at = time.monotonic()
+            trace = self._trace_requests
+            for i, slot in enumerate(list(self._slots)):
                 if slot is None:
                     continue
                 req = slot["req"]
-                n = 1
-                if K > 1 and req.temperature == 0 \
-                        and not self._spec_throttled:
-                    # greedy-only: the accept rule below is exact for
-                    # argmax; a sampled request decodes one token per tick
-                    # (its per-request rng draw order must not change)
-                    remaining = req.max_new_tokens - len(req.tokens)
-                    room = self.max_len - slot["pos"]
-                    n = max(1, min(K, remaining, room))
-                row = [slot["tok"]]
-                if n > 1:
-                    row += _decode.ngram_propose(
-                        list(req.prompt) + req.tokens, n - 1)
-                    self._spec_proposed.inc(n - 1)
-                tokens[i, :len(row)] = row
-                positions[i] = slot["pos"]
-                counts[i] = len(row)
-                tables[i, :len(slot["alloc"].blocks)] = \
-                    slot["alloc"].blocks
-                rows[i] = row
-        with sp.phase("dispatch"):
-            n0 = self._decode_rec["n_traces"]
-            t0c = time.perf_counter()
-            cc0 = _cache_counts()
-            self._cache, out = _quiet_donation(
-                self._decode, self._P, self._cache, tables, tokens,
-                positions, counts)
-            if self._decode_rec["n_traces"] > n0:
-                _attribute_trace(self._decode_rec, self._reg,
-                                 "serve_decode",
-                                 [tables, tokens, positions, counts],
-                                 ("tables", "tokens", "positions",
-                                  "counts"), t0c, cc0)
-        with sp.phase("readback"):
-            # (W, K, V) logits single-device; (W, K) in-graph argmax tokens
-            # when sharded — the accept walk below only ever needs argmax
-            out = np.asarray(out)
-        with sp.phase("sample"):
-            at = time.monotonic()
-            for i, slot in enumerate(list(self._slots)):
-                if slot is None:
-                    continue
-                req, row, cnt = slot["req"], rows[i], int(counts[i])
+                row = rows.get(i) if rows is not None else None
+                cnt = 1 if row is None else len(row)
                 emitted = 0
-                done = False
-                for j in range(cnt):
-                    tok = int(out[i, j]) if self.sharded else \
-                        _decode.sample_logits(
-                            out[i, j], temperature=req.temperature,
-                            top_k=req.top_k, rng=req.rng)
-                    req.tokens.append(tok)
-                    req.future.token_times.append(at)
-                    self._tokens_total.inc()
+                while True:
+                    tok, done = self._sample_and_place(
+                        req, out[i, emitted], at)
                     emitted += 1
-                    done = (len(req.tokens) >= req.max_new_tokens or
-                            (req.eos_id is not None and tok == req.eos_id))
-                    if done:
+                    # a draft equal to what was sampled is accepted: its
+                    # k/v row is already right, so its score counts too
+                    if done or emitted == cnt or row[emitted] != tok:
                         break
-                    if j + 1 < cnt and row[j + 1] == tok:
-                        continue        # draft accepted: its k/v row is
-                    break               # already correct; score the next
                 if cnt > 1:
-                    self._spec_accepted.inc(emitted - 1)
-                    proposed = self._spec_proposed.total()
-                    if proposed:
-                        self._spec_ratio.set(
-                            self._spec_accepted.total() / proposed)
+                    layout.note_accepted(emitted - 1)
+                slot["pos"] += emitted
+                slot["tok"] = tok
+                # decimated past the first 16 tokens: a 4-slot engine
+                # generating hundreds of tokens per request would
+                # otherwise evict the whole flight-recorder ring
+                # (capacity 1024) with ticks, beheading every request
+                # lane and crash blackbox
                 n_tok = len(req.tokens)
-                if self._trace_requests and \
-                        (n_tok < 16 or n_tok % 16 < emitted):
+                if trace and (n_tok < 16 or n_tok % 16 < emitted):
                     _spans.event("request.decode_tick",
                                  request=req.trace_id, slot=i,
-                                 pos=slot["pos"] + emitted,
-                                 emitted=emitted)
-                self._slots[i] = {"req": req, "pos": slot["pos"] + emitted,
-                                  "tok": req.tokens[-1],
-                                  "alloc": slot["alloc"]}
+                                 pos=slot["pos"], emitted=emitted)
                 if done:
                     self._finish_slot(i)
-
-    def _run_decode_ring(self, sp):
-        with sp.phase("pack"):
-            W = self.slots
-            tokens = np.zeros((W,), np.int32)
-            positions = np.zeros((W,), np.int32)
-            active = np.zeros((W,), bool)
-            for i, slot in enumerate(self._slots):
-                if slot is not None:
-                    tokens[i] = slot["tok"]
-                    positions[i] = slot["pos"]
-                    active[i] = True
-            if self._ring_lengths is not None:
-                rows = np.minimum(positions[active, None] + 1,
-                                  self._ring_lengths)
-                sp.attrs["kv_rows"] = int(rows.sum())
-                sp.attrs["kv_blocks"] = int(
-                    (-(-rows // self._ring_blocks)).sum())
-                self._kv_rows.inc(sp.attrs["kv_rows"])
-                self._kv_blocks.inc(sp.attrs["kv_blocks"])
-        with sp.phase("dispatch"):
-            n0 = self._decode_rec["n_traces"]
-            t0c = time.perf_counter()
-            cc0 = _cache_counts()
-            self._cache, out = _quiet_donation(
-                self._decode, self._P, self._cache, tokens, positions,
-                active)
-            if self._decode_rec["n_traces"] > n0:
-                _attribute_trace(self._decode_rec, self._reg,
-                                 "serve_decode",
-                                 [tokens, positions, active],
-                                 ("tokens", "positions", "active"), t0c,
-                                 cc0)
-        with sp.phase("readback"):
-            # (W, V) logits, or (W,) sharded toks
-            out = self._read_out(out, sp, "decode")
-        with sp.phase("sample"):
-            at = time.monotonic()
-            for i, slot in enumerate(list(self._slots)):
-                if slot is None:
-                    continue
-                # decimated past the first 16 tokens: a 4-slot engine
-                # generating hundreds of tokens per request would otherwise
-                # evict the whole flight-recorder ring (capacity 1024) with
-                # ticks, beheading every request lane and crash blackbox
-                n_tok = len(slot["req"].tokens)
-                if self._trace_requests and \
-                        (n_tok < 16 or n_tok % 16 == 0):
-                    _spans.event("request.decode_tick",
-                                 request=slot["req"].trace_id, slot=i,
-                                 pos=slot["pos"] + 1)
-                self._sample_and_place(slot["req"], out[i], i,
-                                       pos=slot["pos"] + 1, at=at)
 
 
 class BatchServingEngine(_EngineBase):

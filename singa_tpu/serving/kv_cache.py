@@ -49,7 +49,10 @@ unreferenced cached prefixes are reclaimed (LRU).
 
 Everything device-side here is a pure function over arrays,
 shape-stable by construction, ready to be closed over by a jitted
-prefill/decode body. ``dtype=int8`` rides both layouts: per-row fp32
+prefill/decode body. The HOST half of each format is a layout class
+at the end of this file (:class:`RingLayout`, :class:`PagedLayout`,
+picked by :func:`pick_layout`): what ``ServingEngine`` asks of a KV
+format, so that its tick is written once. ``dtype=int8`` rides both layouts: per-row fp32
 scales beside the ring, per-(block, offset) scale pools beside the
 paged blocks.
 
@@ -68,10 +71,15 @@ from __future__ import annotations
 import contextlib
 import hashlib
 import threading
+import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from ..models import decode as _decode
+from .scheduler import BlockPoolExhausted, HandoffRefused, ServingError
 
 
 # ---------------------------------------------------------------------------
@@ -621,14 +629,6 @@ class BlockManager:
                      and i not in keep)
         return len(self._free) + cached
 
-    def can_admit(self, prompt, total_tokens):
-        """Whether :meth:`admit` would succeed right now (the queue's
-        backpressure gate — a request that cannot be placed THIS tick
-        stays queued, it is not failed)."""
-        shared, _ = self.match_prefix(prompt)
-        need = self.n_for(total_tokens) - len(shared)
-        return need <= self._reclaimable(shared)
-
     def admit(self, prompt, total_tokens):
         """Reserve every block the sequence can ever touch (positions
         ``[0, total_tokens)`` — decode can then never stall or corrupt
@@ -638,7 +638,6 @@ class BlockManager:
         when it runs dry. Raises
         :class:`~singa_tpu.serving.scheduler.BlockPoolExhausted` when
         the pool cannot cover it without touching a live block."""
-        from .scheduler import BlockPoolExhausted
         shared, shared_tokens = self.match_prefix(prompt)
         need = self.n_for(total_tokens) - len(shared)
         if need > self._reclaimable(shared):
@@ -666,7 +665,7 @@ class BlockManager:
         its chained key) and extends the shared span — the tokens it
         covers skip prefill. Returns extra shared tokens. Restored
         blocks come out of the SAME ``fresh`` reservation, so admission
-        accounting (``can_admit``/``_reclaimable``) is unchanged."""
+        accounting (``_reclaimable``) is unchanged."""
         if self._spill is None or self._spill_write is None or not fresh:
             return 0
         keys = self._chain_keys(prompt)
@@ -703,7 +702,7 @@ class BlockManager:
 
     def _evict_lru(self):
         """Reclaim the least-recently-used CACHED block (refcount 0).
-        Callers guarantee one exists (can_admit/admit checked). With a
+        Callers guarantee one exists (``admit`` checked). With a
         spill tier attached the victim's rows move to host RAM first —
         only cached-prefix blocks ever reach this point, so a LIVE
         block can never be spilled."""
@@ -743,9 +742,537 @@ class BlockManager:
                 self._free.append(bid)
 
 
+# ---------------------------------------------------------------------------
+# the host half of each layout: everything ServingEngine asks of a KV format
+# ---------------------------------------------------------------------------
+#
+# The engine owns the slot table, the queue, sampling, spans and the device
+# state itself (``engine._cache``); a layout owns how that state is built,
+# which of the adapter's programs run on it, what a request reserves, how a
+# tick's host arrays are packed, and how one slot's rows leave and enter it.
+# Both classes below answer the same calls; :func:`pick_layout` is the one
+# place that reads the ``kv_layout`` string.
+
+# KV level arrays in their ONE canonical serialization order: every
+# snapshot/spill frame packs present keys in this order, so the bytes
+# on both sides of a handoff agree by construction.
+LEVEL_KEYS = ("k", "v", "k_scale", "v_scale")
+
+
+def _rows_to_host(state, index):
+    """Every level's arrays at ``index`` of their first axis (a ring's
+    slot, a pool's block or blocks), on the host, in ``LEVEL_KEYS``
+    order."""
+    return [np.asarray(level[name][index]) for level in state
+            for name in LEVEL_KEYS if name in level]
+
+
+def _rows_from_host(state, arrays, index, what, lead=None, skip=0):
+    """The inverse: a new state with ``arrays`` written at ``index``
+    (host-side ``.at[].set`` OUTSIDE the two compiled programs: no
+    retrace, and the fresh buffers are donated on the next tick like
+    any other). ``lead=None``: one array a level key, shaped like one
+    entry of the level (a slot, a block). ``lead=n``: each array holds
+    ``n`` such entries (a slot's blocks), the first ``skip`` of which
+    stay as they are and the rest go to ``index``. A shape that is not
+    the level's is refused, never written."""
+    it = iter(arrays)
+    new_state = []
+    for level in state:
+        upd = dict(level)
+        for name in LEVEL_KEYS:
+            if name not in level:
+                continue
+            arr = next(it)
+            want = tuple(level[name].shape[1:])
+            if lead is not None:
+                want = (lead,) + want
+            if tuple(arr.shape) != want:
+                raise HandoffRefused(
+                    f"{what} array {name} shape {tuple(arr.shape)} does "
+                    f"not match this engine's {want}")
+            if lead is not None:
+                arr = arr[skip:]
+                if not len(arr):
+                    continue
+            upd[name] = level[name].at[index].set(jnp.asarray(arr))
+        new_state.append(upd)
+    return new_state
+
+
+class KVLayout:
+    """What both layouts share, and what a format that reserves nothing
+    answers: no pool, no reservation, nothing that can fail to fit. A
+    layout fills in ``name``, the programs' argument names (for the
+    compile/retrace events), ``init_state``, ``pack_prefill``,
+    ``pack_decode``, ``geometry``, ``info``, ``read_slot`` and
+    ``write_slot``."""
+
+    # the attributes ServingEngine republishes (None: no pool here)
+    mgr = block_size = n_blocks = max_blocks = spill_tier = None
+    spec_width = 1
+    admit = None            # pop_batch predicate: nothing to reserve
+    candidate_axis = False  # decode returns (W, ...), one row a slot
+
+    def __init__(self, adapter, registry, *, slots, max_len, prefill_len,
+                 prefill_batch):
+        self.adapter = adapter
+        self._reg = registry
+        self.slots, self.max_len = slots, max_len
+        self.prefill_len, self.prefill_batch = prefill_len, prefill_batch
+
+    def programs(self, sharded):
+        """``(prefill, decode)`` of the adapter; sharded, the ``greedy_``
+        twins that argmax IN GRAPH over the vocab-sharded logits (the
+        full ``(rows, V)`` array is never gathered or output)."""
+        return tuple(
+            getattr(self.adapter, ("greedy_" if sharded else "") + fn)()
+            for fn in self._programs)
+
+    def never_fits(self, n_prompt, max_new):
+        """The typed error for a request no state of the layout could
+        take, or None."""
+        return None
+
+    def reserve(self, prompt, max_new):
+        """What a request holds beside its slot while it runs (the
+        ``alloc`` of its slot; a layout that hands one out takes it
+        back through ``release(alloc, prompt, cache=True)``)."""
+        return None
+
+
+class RingLayout(KVLayout):
+    """One ring a slot a level (``adapter.init_cache``): a free slot is
+    a free ring and generation past ``max_len`` slides its window, so
+    the defaults above hold; a decode tick carries one token a slot.
+    Also what a recurrent adapter's per-slot state rides (no rings:
+    ``lengths`` stays None and the ring gauges are not made)."""
+
+    name = "ring"
+    prefill_names = ("tokens", "lengths", "slot_ids", "valid")
+    decode_names = ("tokens", "positions", "active")
+    _programs = ("prefill_fn", "decode_fn")
+    lengths = None
+
+    def init_state(self):
+        state = self.adapter.init_cache(self.slots, self.max_len)
+        if not (isinstance(state, list) and all(
+                isinstance(lv, dict) and "k" in lv for lv in state)):
+            return state
+        # what the rings hold, by kind of layer: an adapter whose layers
+        # keep rings of different lengths names each level's kind
+        # (``cache_kinds``); one geometry reads as "full"
+        kinds = getattr(self.adapter, "cache_kinds", None)
+        kinds = kinds() if kinds is not None else ["full"] * len(state)
+        kv_bytes = self._reg.gauge(
+            "serve_kv_bytes", "bytes of ring KV state, by kind of "
+            "layer (window: min(window, max_len) positions a slot; "
+            "full: max_len)", labels=("kind",))
+        for kind in sorted(set(kinds)):
+            kv_bytes.set(sum(
+                int(a.size) * a.dtype.itemsize
+                for k, level in zip(kinds, state) if k == kind
+                for a in level.values()), kind=kind)
+        self.lengths = np.asarray(
+            [int(level["k"].shape[2]) for level in state])
+        # a ring that no block divides is walked whole
+        from ..ops.ring_decode import block_rows
+        self._blocks = np.asarray(
+            [block_rows(n) or n for n in self.lengths])
+        self._kv_rows = self._reg.counter(
+            "serve_kv_rows_attended_total", "ring rows holding a token "
+            "that decode ticks attended to, summed over layers and "
+            "active slots (what a tick has to read of the cache)")
+        self._kv_blocks = self._reg.counter(
+            "serve_kv_blocks_walked_total", "blocks of the rings "
+            "holding a token, as the ring decode kernel cuts them, "
+            "summed over layers and active slots (against slots x "
+            "blocks a ring: the share of the whole walk)")
+        return state
+
+    def pack_prefill(self, batch, free):
+        """``(program arrays, [(request, slot, alloc)], prompt tokens
+        the program runs)`` for one admitted batch."""
+        B, S = self.prefill_batch, self.prefill_len
+        tokens = np.zeros((B, S), np.int32)
+        lengths = np.zeros((B,), np.int32)
+        slot_ids = np.zeros((B,), np.int32)
+        valid = np.zeros((B,), bool)
+        placed = []
+        for b, req in enumerate(batch):
+            n = req.prompt.size
+            tokens[b, :n] = req.prompt
+            lengths[b] = n
+            slot_ids[b] = free[b]
+            valid[b] = True
+            placed.append((req, free[b], None))
+        return (tokens, lengths, slot_ids, valid), placed, \
+            int(lengths.sum())
+
+    def pack_decode(self, slots, attrs, draft):
+        """``(program arrays, None)``: one pending token a live slot,
+        no candidate rows. What the tick has to read of the rings goes
+        on the span (``attrs``) and the two counters."""
+        W = self.slots
+        tokens = np.zeros((W,), np.int32)
+        positions = np.zeros((W,), np.int32)
+        active = np.zeros((W,), bool)
+        for i, slot in enumerate(slots):
+            if slot is not None:
+                tokens[i] = slot["tok"]
+                positions[i] = slot["pos"]
+                active[i] = True
+        if self.lengths is not None:
+            rows = np.minimum(positions[active, None] + 1, self.lengths)
+            attrs["kv_rows"] = int(rows.sum())
+            attrs["kv_blocks"] = int((-(-rows // self._blocks)).sum())
+            self._kv_rows.inc(attrs["kv_rows"])
+            self._kv_blocks.inc(attrs["kv_blocks"])
+        return (tokens, positions, active), None
+
+    def geometry(self):
+        g = {"layout": self.name}
+        if self.lengths is not None and \
+                any(n != self.max_len for n in self.lengths):
+            # layers with rings of their own length (window layers)
+            g["ring_lengths"] = [int(n) for n in self.lengths]
+        return g
+
+    def info(self, part):
+        return {} if part is None else \
+            {"slots_per_device": self.slots // part.batch_shards}
+
+    def read_slot(self, state, slot_idx, alloc):
+        return _rows_to_host(state, slot_idx)
+
+    def write_slot(self, state, arrays, slot_idx, alloc):
+        return _rows_from_host(state, arrays, slot_idx, "snapshot")
+
+
+class PagedLayout(KVLayout):
+    """One block pool a level (``adapter.init_pool``) under a
+    :class:`BlockManager`: a request reserves the blocks of its whole
+    ``prompt + max_new_tokens`` span before it is popped, a prefix-cache
+    hit enters prefill with ``start > 0`` and only its SUFFIX tokens,
+    and a decode tick carries a row of up to ``spec_width`` candidates a
+    slot (the pending token plus n-gram drafts) for the verify program.
+    Rejected drafts leave stale rows past the new position; the
+    position-exact mask keeps them unreachable until overwritten."""
+
+    name = "paged"
+    candidate_axis = True   # decode returns (W, K, ...)
+    prefill_names = ("tables", "tokens", "starts", "lengths", "valid")
+    decode_names = ("tables", "tokens", "positions", "counts")
+    _programs = ("paged_prefill_fn", "paged_decode_fn")
+
+    def __init__(self, adapter, registry, *, block_size, n_blocks,
+                 spec_width, spill_bytes, **size):
+        super().__init__(adapter, registry, **size)
+        self.block_size = int(block_size)
+        if self.block_size < 1:
+            raise ValueError(
+                f"kv_block_size must be >= 1, got {block_size}")
+        self.max_blocks = -(-self.max_len // self.block_size)
+        # default pool covers slots × max_len (no saving, full safety);
+        # a smaller kv_blocks is where paged memory elasticity lives —
+        # admission backpressure keeps it safe
+        self.n_blocks = int(n_blocks) if n_blocks \
+            else self.slots * self.max_blocks
+        if self.n_blocks < 1:
+            raise ValueError(f"kv_blocks must be >= 1, got {n_blocks}")
+        self.spec_width = spec_width
+        self.mgr = BlockManager(self.n_blocks, self.block_size)
+        # pool-pressure gauges: what /metrics.json and the heartbeat
+        # fleet view read to see a replica running out of KV blocks
+        # before requests start backing up
+        reg = self._reg
+        reg.gauge("kv_blocks_total",
+                  "paged KV pool size in blocks").set(self.n_blocks)
+        self._in_use = reg.gauge(
+            "kv_blocks_in_use",
+            "pool blocks referenced by live sequences (never evicted)")
+        self._cached = reg.gauge(
+            "kv_blocks_cached",
+            "unreferenced blocks held by the prefix cache "
+            "(reclaimable, LRU)")
+        self._prefix_hits = reg.counter(
+            "prefix_cache_hits_total",
+            "admitted prompts whose prefix matched cached blocks "
+            "(prefill skipped for the shared span)")
+        self._prefix_tokens = reg.counter(
+            "prefix_cache_tokens_total",
+            "prompt tokens served from cached prefix blocks "
+            "instead of prefill compute")
+        self._proposed = reg.counter(
+            "speculative_proposed_total",
+            "draft tokens proposed to the verify program")
+        self._accepted = reg.counter(
+            "speculative_accepted_total",
+            "draft tokens accepted by the greedy verify rule")
+        self._ratio = reg.gauge(
+            "speculative_accepted_ratio",
+            "cumulative accepted/proposed draft-token ratio (the "
+            "speculative speedup is roughly 1 + ratio × (k-1))")
+        self.spill_tier = HostSpillTier(spill_bytes) \
+            if spill_bytes else None
+
+    def init_state(self):
+        return self.adapter.init_pool(self.n_blocks, self.block_size)
+
+    def attach_spill(self, reader, writer):
+        """Arm the spill tier with the engine's block reader and writer
+        (the manager and this layout hold no device state)."""
+        tier, reg = self.spill_tier, self._reg
+        spilled = reg.counter(
+            "serve_kv_spill_total",
+            "cached-prefix blocks spilled to the host-RAM "
+            "tier on pool eviction")
+        restored = reg.counter(
+            "serve_kv_restore_total",
+            "prefix blocks restored from the host-RAM tier "
+            "instead of being re-prefilled")
+        held = reg.gauge(
+            "serve_kv_spill_bytes",
+            "bytes the host-RAM spill tier currently holds "
+            f"(budget {tier.budget_bytes})")
+
+        def moved(counter):
+            counter.inc()
+            held.set(tier.bytes_used)
+
+        self.mgr.attach_spill(
+            tier, reader, writer, on_spill=lambda: moved(spilled),
+            on_restore=lambda: moved(restored))
+
+    # -- what a request needs ----------------------------------------------
+    def never_fits(self, n_prompt, max_new):
+        total = n_prompt + max_new
+        if total > self.max_len:
+            return ServingError(
+                f"prompt ({n_prompt}) + max_new_tokens ({max_new}) = "
+                f"{total} exceeds max_len {self.max_len}: the paged "
+                "layout is exact full attention within max_len (no "
+                "logical slot exists past it) — raise max_len, or use "
+                "the ring layout for sliding-window generation")
+        if self.mgr.n_for(total) > self.n_blocks:
+            return BlockPoolExhausted(
+                f"request needs {self.mgr.n_for(total)} KV blocks but "
+                f"the whole pool is {self.n_blocks} (× {self.block_size} "
+                "tokens): it can NEVER be admitted — raise kv_blocks or "
+                "lower max_new_tokens")
+        return None
+
+    def reserve(self, prompt, max_new):
+        """The request's :class:`SlotAlloc`, or ``BlockPoolExhausted``
+        (backpressure: the caller tries again next tick)."""
+        alloc = self.mgr.admit(prompt, int(prompt.size) + max_new)
+        self._update_gauges()
+        return alloc
+
+    def admit(self, req):
+        """The ``pop_batch`` predicate: RESERVES the request's blocks
+        (prefix-shared ones re-referenced) so a batch can never
+        over-commit the pool; a request that does not fit right now
+        stays at the head of the queue (FIFO-fair — live sequences are
+        never evicted to make room)."""
+        try:
+            req._alloc = self.mgr.admit(
+                req.prompt, int(req.prompt.size) + req.max_new_tokens)
+            return True
+        except BlockPoolExhausted:
+            return False
+
+    def release(self, alloc, prompt, cache=True):
+        """Return a sequence's block references (its full prompt blocks
+        enter the prefix cache unless ``cache`` is False: blocks a
+        failed write left half-filled must never be shared)."""
+        if not cache:
+            alloc = SlotAlloc(alloc.blocks, alloc.shared_tokens, 0)
+        self.mgr.release(alloc, prompt)
+        self._update_gauges()
+
+    def _update_gauges(self):
+        self._in_use.set(self.mgr.blocks_live())
+        self._cached.set(self.mgr.blocks_cached())
+
+    # -- packing -------------------------------------------------------------
+    def pack_prefill(self, batch, free):
+        """As :meth:`RingLayout.pack_prefill`; each popped request
+        arrives with its reservation taken (``req._alloc``), and the
+        pool gauges follow the batch's, once."""
+        B, S = self.prefill_batch, self.prefill_len
+        tokens = np.zeros((B, S), np.int32)
+        starts = np.zeros((B,), np.int32)
+        lengths = np.zeros((B,), np.int32)
+        tables = np.zeros((B, self.max_blocks), np.int32)
+        valid = np.zeros((B,), bool)
+        placed = []
+        for b, req in enumerate(batch):
+            alloc = req._alloc
+            suffix = req.prompt[alloc.shared_tokens:]
+            tokens[b, :suffix.size] = suffix
+            starts[b] = alloc.shared_tokens
+            lengths[b] = suffix.size
+            tables[b, :len(alloc.blocks)] = alloc.blocks
+            valid[b] = True
+            placed.append((req, free[b], alloc))
+            if alloc.shared_tokens:
+                self._prefix_hits.inc()
+                self._prefix_tokens.inc(alloc.shared_tokens)
+        self._update_gauges()
+        return (tables, tokens, starts, lengths, valid), placed, \
+            int(lengths.sum())
+
+    def pack_decode(self, slots, attrs, draft):
+        """``(program arrays, rows)``: ``rows`` is None at width 1;
+        under speculation it maps a slot to its candidate row, the
+        pending token plus up to ``spec_width - 1`` n-gram drafts
+        (none while ``draft`` is off)."""
+        W, K = self.slots, self.spec_width
+        tokens = np.zeros((W, K), np.int32)
+        positions = np.zeros((W,), np.int32)
+        counts = np.zeros((W,), np.int32)
+        tables = np.zeros((W, self.max_blocks), np.int32)
+        rows = {} if K > 1 and draft else None
+        for i, slot in enumerate(slots):
+            if slot is None:
+                continue
+            tokens[i, 0] = slot["tok"]
+            positions[i] = slot["pos"]
+            counts[i] = 1
+            blocks = slot["alloc"].blocks
+            tables[i, :len(blocks)] = blocks
+            req = slot["req"]
+            if rows is None or req.temperature != 0:
+                # greedy-only: the accept rule is exact for argmax; a
+                # sampled request decodes one token per tick (its
+                # per-request rng draw order must not change)
+                continue
+            n = min(K, req.max_new_tokens - len(req.tokens),
+                    self.max_len - slot["pos"])
+            if n > 1:
+                row = [slot["tok"]] + _decode.ngram_propose(
+                    list(req.prompt) + req.tokens, n - 1)
+                self._proposed.inc(n - 1)
+                tokens[i, :len(row)] = row
+                counts[i] = len(row)
+                rows[i] = row
+        return (tables, tokens, positions, counts), rows
+
+    def note_accepted(self, n):
+        """``n`` drafts of one slot's row passed the verify rule."""
+        self._accepted.inc(n)
+        proposed = self._proposed.total()
+        if proposed:
+            self._ratio.set(self._accepted.total() / proposed)
+
+    # -- hand-off ------------------------------------------------------------
+    def geometry(self):
+        return {"layout": self.name, "block_size": self.block_size}
+
+    def info(self, part):
+        info = {"kv_block_size": self.block_size,
+                "kv_blocks": self.n_blocks,
+                "kv_blocks_in_use": self.mgr.blocks_live(),
+                "kv_blocks_cached": self.mgr.blocks_cached(),
+                "prefix_cache_entries": len(self.mgr._cache)}
+        if self.spill_tier is not None:
+            info["spill"] = {
+                "budget_bytes": self.spill_tier.budget_bytes,
+                "bytes_used": self.spill_tier.bytes_used,
+                "entries": len(self.spill_tier),
+                "spilled_total": self.mgr.spilled_total,
+                "restored_total": self.mgr.restored_total}
+        return info
+
+    def read_slot(self, state, slot_idx, alloc):
+        """The slot's blocks, in block-table order."""
+        return _rows_to_host(state, np.asarray(alloc.blocks, np.int32))
+
+    def write_slot(self, state, arrays, slot_idx, alloc):
+        """A snapshot's blocks into ``alloc``'s; the leading blocks a
+        prefix-cache hit or a spill restore already covers are skipped
+        (same positions, bitwise-identical content under greedy
+        determinism)."""
+        skip = alloc.shared_tokens // self.block_size
+        return _rows_from_host(
+            state, arrays, jnp.asarray(alloc.blocks[skip:], jnp.int32),
+            "snapshot", lead=len(alloc.blocks), skip=skip)
+
+    def read_block(self, state, bid):
+        return _rows_to_host(state, int(bid))
+
+    def write_block(self, state, bid, arrays):
+        return _rows_from_host(state, arrays, int(bid), "spilled block")
+
+
+def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
+                prefill_len, prefill_batch, kv_block_size, kv_blocks,
+                speculative_k, spill_bytes, sharded):
+    """The layout an engine runs on, from what was asked for and what
+    the adapter and the mesh can honour. What cannot be honoured
+    declines LOUDLY (a warning, and the reason under ``declined`` for
+    ``compiled_step_info``), never silently."""
+    kv_layout = str(kv_layout)
+    if kv_layout not in ("ring", "paged"):
+        raise ValueError(
+            f"kv_layout must be 'ring' or 'paged', got {kv_layout!r}")
+    declined = {}
+    paged = kv_layout == "paged"
+    if paged and not getattr(adapter, "supports_paged", False):
+        warnings.warn(
+            f"kv_layout='paged' declined: {type(adapter).__name__} has "
+            "no paged block-pool programs (its decode state is not "
+            "per-position KV rows); serving on the ring layout instead",
+            stacklevel=4)
+        declined["kv_layout_declined"] = "adapter_unsupported"
+        paged = False
+    # speculative_k = verify-program width: up to speculative_k tokens
+    # emitted per tick (speculative_k - 1 of them drafted). It needs the
+    # paged mask's position-exactness — a wrapped ring re-attributes a
+    # rejected draft's stale row INTO the sliding window (pos+1 wraps to
+    # pos-L+1), so the ring declines rather than risking silent
+    # corruption.
+    spec = int(speculative_k or 0)
+    if spec > 1 and not paged:
+        warnings.warn(
+            "speculative_k declined: speculative decoding needs "
+            "kv_layout='paged' (the ring's wraparound would "
+            "re-attribute rejected-draft rows into the attention "
+            "window); decoding one token per tick", stacklevel=4)
+        declined["speculative_declined"] = "requires_paged_layout"
+    spill = int(spill_bytes or 0)
+    if spill > 0 and not paged:
+        warnings.warn(
+            "spill_bytes declined: the host-RAM spill tier parks "
+            "evicted cached-prefix BLOCKS, which only the paged layout "
+            "has", stacklevel=4)
+        declined["spill_declined"] = "requires_paged_layout"
+    elif spill > 0 and sharded:
+        warnings.warn(
+            "spill_bytes declined: a sharded pool's blocks are sliced "
+            "over the mesh ('model' axis) — a host spill/restore would "
+            "need per-device gathers; serve single-device to spill",
+            stacklevel=4)
+        declined["spill_declined"] = "sharded"
+        spill = 0
+    size = dict(slots=slots, max_len=max_len, prefill_len=prefill_len,
+                prefill_batch=prefill_batch)
+    if paged:
+        layout = PagedLayout(
+            adapter, registry, block_size=kv_block_size,
+            n_blocks=kv_blocks, spec_width=max(1, spec),
+            spill_bytes=spill, **size)
+    else:
+        layout = RingLayout(adapter, registry, **size)
+    layout.declined = declined
+    return layout
+
+
 __all__ = ["init_cache", "ring_positions", "ring_mask", "write_token",
            "write_prompt", "attend", "decode_token", "ring_block",
            "xla_rings", "init_pool", "write_rows",
            "gather_pages", "attend_pages", "SlotAlloc", "BlockManager",
            "HostSpillTier", "chain_keys", "prefix_chain_key",
-           "affinity_hash"]
+           "affinity_hash", "LEVEL_KEYS", "KVLayout", "RingLayout",
+           "PagedLayout", "pick_layout"]

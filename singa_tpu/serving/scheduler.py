@@ -216,6 +216,9 @@ class Request:
         self.first_token_at = None      # set by the engine at prefill
         self.future = ServeFuture()
         self.tokens: list = []          # generated ids (engine-owned)
+        # what the engine's KV layout reserved for it when it was
+        # popped, until a slot owns it (paged: a SlotAlloc)
+        self._alloc = None
 
     def expired(self, now=None):
         return self.deadline is not None and \

@@ -3,7 +3,8 @@ serving throughput push's CI invariants.
 
 - **paged == ring, token for token AND KV-row for KV-row** on greedy
   workloads (the two layouts store position ``p`` at the same logical
-  index while sequences fit, so the pin is BITWISE);
+  index while sequences fit: the first level's rows are bitwise equal,
+  deeper ones to the last bits of the two prefill programs' sums);
 - the paged decode program NEVER retraces: ≥3 mid-batch slot refills
   with mixed lengths PLUS prefix-cache hits PLUS speculative ticks,
   ``compiled_step_info()["n_traces"] == 1``;
@@ -95,10 +96,17 @@ class TestPagedParity:
         assert _greedy(eng, prompt) == seq[len(prompt):]
 
     def test_written_kv_rows_bitwise_equal_ring(self):
-        """The written prompt+decode KV rows are BITWISE identical
-        between layouts: both store position p at logical index p
-        while the sequence fits, and the chunked-prefill softmax only
-        adds exact-zero masked terms."""
+        """Both layouts store position p at logical index p while the
+        sequence fits. The FIRST level's written rows are bitwise
+        identical (embeddings through one matmul); a later level's are
+        held to 8 ulps of its largest entry: its input passed through
+        the level below's prefill attention, which the ring program
+        sums over the prompt's S keys and the paged program over the
+        row's whole gathered table (the masked terms are exact zeros,
+        the order of the sum is not the same), so prompt rows differ in
+        the last bits on this backend — measured 2.5 ulps at most over
+        six seeds. A row at the wrong index would differ by its whole
+        size."""
         m = tiny_lm(seed=0)
         prompt = np.random.RandomState(1).randint(0, 19, (6,))
         ring = m.compile_serving(slots=2, max_len=32, prefill_len=8,
@@ -109,7 +117,7 @@ class TestPagedParity:
         assert _greedy(ring, prompt, 4) == _greedy(paged, prompt, 4)
         n_written = 6 + 4 - 1      # the last token is never written
         bs = 4
-        for rl, pl in zip(ring._cache, paged._cache):
+        for depth, (rl, pl) in enumerate(zip(ring._cache, paged._cache)):
             for part in ("k", "v"):
                 ring_rows = np.asarray(rl[part])[0, :, :n_written]
                 pool = np.asarray(pl[part])
@@ -118,7 +126,36 @@ class TestPagedParity:
                 nb = -(-n_written // bs)
                 logical = np.concatenate(
                     [pool[b] for b in range(nb)], axis=1)[:, :n_written]
-                assert np.array_equal(ring_rows, logical), part
+                ulp = np.finfo(np.float32).eps * np.abs(ring_rows).max()
+                gap = np.abs(ring_rows - logical).max()
+                assert gap <= (0 if depth == 0 else 8 * ulp), \
+                    (depth, part, gap / ulp)
+
+    def test_paged_at_one_candidate_ticks_like_the_ring(self):
+        """The engine's one decode walk: without speculation a paged
+        slot's candidate row is its pending token alone, so the same
+        requests take the same number of decode ticks (and of prefill
+        batches) on both layouts, token for token."""
+        m = tiny_lm(seed=3)
+        rng = np.random.RandomState(11)
+        work = [(rng.randint(0, 19, (int(rng.randint(1, 8)),)),
+                 int(rng.randint(2, 9))) for _ in range(7)]
+        seen = {}
+        for layout, kw in (("ring", {}), ("paged", dict(
+                kv_layout="paged", kv_block_size=4))):
+            reg = _reg()
+            eng = m.compile_serving(slots=3, max_len=32, prefill_len=8,
+                                    prefill_batch=2, registry=reg, **kw)
+            futs = [eng.submit(p, max_new_tokens=n, temperature=0.0)
+                    for p, n in work]
+            ticks = eng.run_until_idle()
+            seen[layout] = (
+                [f.result(timeout=5)["tokens"] for f in futs], ticks,
+                reg.get("serve_decode_steps_total").value(),
+                reg.get("serve_prefill_total").value(),
+                reg.get("serve_tokens_total").value())
+        assert seen["ring"] == seen["paged"]
+        assert seen["ring"][2] > 0
 
     def test_int8_kv_paged_matches_int8_ring(self):
         """int8 KV scales ride the block pool: per-(block, offset)

@@ -271,6 +271,44 @@ class TestServeTick:
             assert req.submitted_at <= req.admitted_at \
                 <= req.first_token_at
 
+    def test_both_layouts_drive_the_two_programs_by_one_path(self, ring,
+                                                             layout):
+        """The seam of `serving/kv_cache.py`: whichever layout packs the
+        tick, the `serve.prefill` / `serve.decode` spans carry the same
+        phase names in the same order, and over 20 ticks with admissions
+        all along neither program is traced a second time."""
+        reg = obs_metrics.MetricsRegistry()
+        eng = _engine(layout, reg)
+        rng = np.random.RandomState(3)
+        futs, ticks = [], 0
+        while ticks < 20:
+            if ticks % 2 == 0:      # a new request every other tick
+                futs.append(eng.submit(
+                    rng.randint(0, 19, (int(rng.randint(1, 8)),)),
+                    max_new_tokens=int(rng.randint(3, 9)),
+                    temperature=0.0, seed=ticks))
+            assert eng.step()
+            ticks += 1
+        eng.run_until_idle()
+        assert all(f.result(timeout=5)["tokens"] for f in futs)
+        records = ring.records()
+        prefills = _named(records, "serve.prefill")
+        decodes = _named(records, "serve.decode")
+        assert len(prefills) >= 5 and len(decodes) >= 20
+        assert {tuple(r["phases"]) for r in prefills} == \
+            {("pack", "dispatch", "readback", "place")}
+        assert {tuple(r["phases"]) for r in decodes} == \
+            {("pack", "dispatch", "readback", "sample")}
+        info = eng.compiled_step_info()
+        assert info["kv_layout"] == layout
+        assert (info["prefill_n_traces"], info["n_traces"]) == (1, 1)
+        # one compile event a program, no retrace
+        compiles = [r for r in records if r.get("name") == "compile"
+                    and r.get("kind") == "event"]
+        assert sorted(r["program"] for r in compiles) == \
+            ["serve_decode", "serve_prefill"]
+        assert not _named(records, "retrace", kind="event")
+
 
 def test_spans_of_a_running_engine_appear_in_a_profiler_trace(tmp_path):
     """The tick's names on the host plane of a trace taken around it."""
