@@ -202,6 +202,18 @@ def _ring_kernel_in_decode(ctx, eng):
     return calls
 
 
+def _logits_readbacks(reg):
+    """Program calls of an engine that brought their ``(rows, V)`` logits
+    to the host: none for greedy requests, whose tokens the programs
+    choose, and every call counted (``serve_readback_total``)."""
+    counter = reg.get("serve_readback_total")
+    n = sum(int(counter.value(program=p, what="logits"))
+            for p in ("prefill", "decode"))
+    assert n == 0 and counter.total() > 0, \
+        f"{n} of {counter.total()} greedy program calls read their logits"
+    return n
+
+
 # ---------------------------------------------------------------------------
 # device
 # ---------------------------------------------------------------------------
@@ -642,17 +654,19 @@ def phase_serve(ctx):
     prompts = [rng.randint(1, lm_cfg["vocab"], (n,)) for n in lengths]
     out = {}
     served = {}
+    logits_readbacks = 0
     for kv_layout in ("ring", "paged"):
         kw = {"kv_layout": "paged"} if kv_layout == "paged" else {}
+        reg = obs_metrics.MetricsRegistry()
         eng = m.compile_serving(
             slots=cfg["slots"], max_len=cfg["max_len"],
-            prefill_len=cfg["prefill_len"],
-            registry=obs_metrics.MetricsRegistry(), **kw)
+            prefill_len=cfg["prefill_len"], registry=reg, **kw)
         futs = [eng.submit(p, max_new_tokens=cfg["new_tokens"])
                 for p in prompts]
         eng.run_until_idle()
         results = [f.result(timeout=5) for f in futs]
         info = eng.compiled_step_info()
+        logits_readbacks += _logits_readbacks(reg)
         if kv_layout == "ring":
             out["ring_decode_calls"] = _ring_kernel_in_decode(ctx, eng)
         eng.stop()
@@ -692,6 +706,11 @@ def phase_serve(ctx):
     assert worst < 0.25, f"engine tokens trail the eager argmax by " \
         f"{worst} in logit"
     out["max_logit_gap_vs_eager"] = round(worst, 4)
+    out["logits_readbacks"] = logits_readbacks
+    # information: requests whose tokens are the same on both layouts
+    # (the two sum attention in another order; see the gap above)
+    out["ring_paged_equal_requests"] = sum(
+        a == b for a, b in zip(served["ring"], served["paged"]))
     return out
 
 
@@ -780,6 +799,7 @@ def phase_moe_serve(ctx):
     served = [f.result(timeout=5)["tokens"] for f in futs]
     info = eng.compiled_step_info()
     out["ring_decode_calls"] = _ring_kernel_in_decode(ctx, eng)
+    out["logits_readbacks"] = _logits_readbacks(reg)
     eng.stop()
     assert info["n_traces"] == 1 and info["kv_layout"] == "ring", info
     seqs = np.zeros((len(prompts), c["max_len"]), np.float32)

@@ -575,14 +575,20 @@ def serving_geometry(engine):
     artifact exported at different slots/lengths — or a different KV
     LAYOUT (a ring executable honored by a paged engine would be a
     silently wrong program) — must refuse with reason ``signature``
-    even before the aval diff names it. Paged manifests additionally
-    carry the pool geometry (``kv_block_size``/``kv_blocks``) and the
-    verify width."""
+    even before the aval diff names it, and so must one whose programs
+    return another form of output (``outputs``: the avals are of the
+    inputs and cannot tell). Paged manifests additionally carry the
+    pool geometry (``kv_block_size``/``kv_blocks``) and the verify
+    width."""
     geo = {"slots": engine.slots,
            "max_len": engine.max_len,
            "prefill_len": engine.prefill_len,
            "prefill_batch": engine.prefill_batch,
-           "kv_layout": getattr(engine, "kv_layout", "ring")}
+           "kv_layout": getattr(engine, "kv_layout", "ring"),
+           # what the programs return beside the state: an artifact of
+           # a build that returned the bare logits carries no such key
+           # and is refused, never loaded and unpacked wrongly
+           "outputs": engine._layout.outputs(engine.sharded)}
     if geo["kv_layout"] == "paged":
         geo.update(kv_block_size=engine.kv_block_size,
                    kv_blocks=engine.kv_blocks,
@@ -645,18 +651,12 @@ def export_serving(engine, store):
             "persistent compile cache is their warm-start path")
     prefill_avals, decode_avals = serving_program_avals(engine)
     geometry = serving_geometry(engine)
-    if engine.kv_layout == "paged":
-        raws = ((SERVE_PREFILL, engine.adapter.paged_prefill_fn(),
-                 prefill_avals),
-                (SERVE_DECODE, engine.adapter.paged_decode_fn(),
-                 decode_avals))
-    else:
-        raws = ((SERVE_PREFILL, engine.adapter.prefill_fn(),
-                 prefill_avals),
-                (SERVE_DECODE, engine.adapter.decode_fn(),
-                 decode_avals))
+    # the programs the engine runs, from the one place it picks them
+    prefill_raw, decode_raw = engine._layout.programs(engine.sharded)
     out = {}
-    for program, raw, avals in raws:
+    for program, raw, avals in (
+            (SERVE_PREFILL, prefill_raw, prefill_avals),
+            (SERVE_DECODE, decode_raw, decode_avals)):
         compiled = jax.jit(raw, donate_argnums=(1,)).lower(
             *avals).compile()
         out[program] = store.save_program(

@@ -536,9 +536,9 @@ class _LMServeAdapter:
     # kv_layout="paged" loudly back to the ring
     supports_paged = True
     # GSPMD sharded serving (parallel/gspmd.py): the adapter can map
-    # its param/cache trees to NamedSharding specs and emit
-    # argmax-in-graph program variants; the char-rnn's (h,c) adapter
-    # cannot, so compile_serving(model_shards=) on it is a typed decline
+    # its param/cache trees to NamedSharding specs; the char-rnn's
+    # (h,c) adapter cannot, so compile_serving(model_shards=) on it is
+    # a typed decline
     supports_sharded = True
 
     def __init__(self, m, policy=None):
@@ -874,36 +874,6 @@ class _LMServeAdapter:
             if kv_layout == "paged" else \
             gspmd.ring_cache_specs(part, cache)
         return param_specs, cache_specs
-
-    def _argmax_wrap(self, base):
-        """Token-returning twin of a logits-returning serve program:
-        ``argmax`` runs IN GRAPH over the vocab-sharded logits (XLA
-        combines per-shard partial argmaxes — ties break to the lowest
-        id, the exact semantics of the host sampler's np.argmax), so
-        the full (rows, V) logits never leave the program and no
-        full-vocab gather exists anywhere in it."""
-        import jax.numpy as jnp
-
-        def fn(*args):
-            state, logits = base(*args)
-            return state, jnp.argmax(logits, -1).astype(jnp.int32)
-
-        return fn
-
-    def greedy_prefill_fn(self):
-        return self._argmax_wrap(self.prefill_fn())
-
-    def greedy_decode_fn(self):
-        return self._argmax_wrap(self.decode_fn())
-
-    def greedy_paged_prefill_fn(self):
-        return self._argmax_wrap(self.paged_prefill_fn())
-
-    def greedy_paged_decode_fn(self):
-        # (W, K, V) logits -> (W, K) tokens: the speculative accept
-        # walk only ever compares draft tokens against argmax, so the
-        # verify program loses nothing by returning tokens
-        return self._argmax_wrap(self.paged_decode_fn())
 
 
 def _decode_adapter(self, policy=None):

@@ -8,7 +8,10 @@ blackbox):
 - :class:`ServingEngine` — autoregressive models (transformer LM,
   char-rnn, the sparse-expert LM). TWO fixed-shape compiled programs a
   model, each taking ``(P, state, *host arrays)`` and returning the
-  DONATED KV state and one output row a request:
+  DONATED KV state and, a row, the token its logits put first beside
+  the logits themselves (``kv_cache.with_tokens``; a tick reads back
+  the tokens, and the ``(rows, V)`` logits only while a request it
+  serves samples):
 
   * **prefill**: a fixed-width batch of padded prompts writes its
     assigned slots' KV rows and returns last-token logits; a ``valid``
@@ -21,7 +24,8 @@ blackbox):
     like the train step's retrace guard.
 
   **The tick is written once; the KV format is not its business.** The
-  engine owns the slot table, the queue, sampling (host-side per slot
+  engine owns the slot table, the queue, sampling (a greedy row's
+  token is the program's argmax; a sampling row draws host-side
   through :mod:`singa_tpu.models.decode`, which is what lets
   per-request temperature/top_k/seed vary without touching a compiled
   program), the spans, fault points, the counters every format has,
@@ -41,9 +45,9 @@ blackbox):
   a named (batch × model) mesh (``parallel/gspmd.py``): params and KV
   state are annotated with NamedSharding, the SAME pure bodies are
   jitted once, and XLA inserts every collective. The sharded programs
-  compute the greedy argmax IN GRAPH over the vocab-sharded logits
-  (the full (rows, V) array never exists on any device or the host),
-  so sampled requests are a typed submit-time rejection.
+  return the tokens alone — the argmax runs over the vocab-sharded
+  logits and the full (rows, V) array never exists on any device or
+  the host — so sampled requests are a typed submit-time rejection.
 
 - :class:`BatchServingEngine` — stateless models (the CNN/MLP zoo and
   ONNX imports through ``sonnx.SONNXModel``): each tick gathers up to
@@ -230,8 +234,9 @@ class _EngineBase:
         self._tok_lat = self._reg.histogram(
             "serve_token_seconds",
             "the decode part of one continuous-batching tick: input "
-            "packing, the program, the logits' read-back and host "
-            "sampling for every active slot (the serve.decode span)")
+            "packing, the program, the read-back of its tokens (of its "
+            "logits too in a tick that serves a sampling request) and "
+            "the per-slot walk that places them (the serve.decode span)")
 
     # -- admission ---------------------------------------------------------
     def _admit(self, req):
@@ -773,7 +778,7 @@ class ServingEngine(_EngineBase):
                 kw.update(
                     in_shardings=(p_sh, c_sh, *(self._part.sharding(s)
                                                 for s in io[program])),
-                    out_shardings=(c_sh, tok_sh))
+                    out_shardings=(c_sh, (tok_sh,)))
         self._hbm_dev = _perf.first_jax_device(self._cache)
         # the KV state (ring cache or block pool) is DONATED: the one
         # large serving buffer is updated in place by XLA instead of
@@ -818,7 +823,7 @@ class ServingEngine(_EngineBase):
                         "slot array width (max in-flight sequences)"
                         ).set(self.slots)
         # an adapter whose programs return ``(logits, stats)`` (a small
-        # array of per-call counts that rides the logits' read-back, no
+        # array of per-call counts that rides the tokens' read-back, no
         # sync of its own) publishes them itself: ``stats_recorder(
         # registry)`` gives ``record(program, stats) -> span attrs``;
         # the engine knows neither their names nor their meaning
@@ -826,6 +831,12 @@ class ServingEngine(_EngineBase):
         self._record_stats = None if make is None else make(self._reg)
         self._tokens_total = self._reg.counter(
             "serve_tokens_total", "tokens generated")
+        self._readbacks = self._reg.counter(
+            "serve_readback_total", "serve program calls, by what of "
+            "their output came to the host (tokens: every row's "
+            "in-graph argmax, 4 bytes a row; logits: the (rows, V) "
+            "float32 array as well, because a request the call served "
+            "samples)", labels=("program", "what"))
         self._decode_steps = self._reg.counter(
             "serve_decode_steps_total", "continuous-batching decode "
             "ticks executed")
@@ -1465,17 +1476,20 @@ class ServingEngine(_EngineBase):
             req.future.set_error(ServingError(status))
         self.queue.finish(status)
 
-    def _sample_and_place(self, req, out_row, at):
-        """One program output row resolved into the request's next
-        token and placed on its stream; returns ``(token, done)``.
-        ``out_row`` is a logits vector on the single-device engines and
-        an in-graph-argmax'd token id on the sharded ones — the ONE
-        place that split is decided. ``at`` is the token's stamp
-        (``ServeFuture.token_times``): the clock as the program's
-        output reached the host, one reading for the whole batch."""
-        tok = int(out_row) if self.sharded else _decode.sample_logits(
-            out_row, temperature=req.temperature, top_k=req.top_k,
-            rng=req.rng)
+    def _sample_and_place(self, req, tokens, logits, row, at):
+        """Row ``row`` of a program's output resolved into the request's
+        next token and placed on its stream; returns ``(token, done)``.
+        ``tokens`` holds every row's argmax as the program took it,
+        which IS the token of a greedy request; a sampling request
+        draws from its row of ``logits`` (read back only in a tick that
+        serves such a request) with its own ``rng``. ``at`` is the
+        token's stamp (``ServeFuture.token_times``): the clock as the
+        program's output reached the host, one reading for the whole
+        batch."""
+        tok = int(tokens[row]) if req.temperature == 0 else \
+            _decode.sample_logits(logits[row],
+                                  temperature=req.temperature,
+                                  top_k=req.top_k, rng=req.rng)
         req.tokens.append(tok)
         req.future.token_times.append(at)
         self._tokens_total.inc()
@@ -1580,18 +1594,27 @@ class ServingEngine(_EngineBase):
                              t0, cc0)
         return out
 
-    def _read_out(self, out, sp, program):
-        """A program's output to the host: ``(rows, V)`` logits, or the
-        in-graph argmax tokens when sharded (the full-vocab array never
-        reaches the host). Where the adapter records its programs'
-        counts (``stats_recorder``), they come over with it, and what
-        the recorder returns goes on the span."""
+    def _read_out(self, out, sp, program, requests):
+        """What the tick needs of a program's ``out`` (``(tokens,
+        logits[, stats])``, ``kv_cache.with_tokens``), to the host;
+        returns ``(tokens, logits or None)``. The tokens always (4
+        bytes a row) and with them the adapter's counts
+        (``stats_recorder``; what the recorder returns goes on the
+        span). The ``(rows, V)`` logits only where one of the
+        ``requests`` this call served samples — with ``temperature ==
+        0`` a row's token is its argmax whatever ``top_k`` says — and
+        otherwise they stay on the device and go with their
+        reference."""
+        import jax
+        sampling = any(r.temperature != 0 for r in requests)
+        what = sp.attrs["readback"] = "logits" if sampling else "tokens"
+        self._readbacks.inc(program=program, what=what)
         if self._record_stats is None:
-            return np.asarray(out)
-        out, stats = out
-        out = np.asarray(out)
-        sp.attrs.update(self._record_stats(program, np.asarray(stats)))
-        return out
+            tokens = np.asarray(out[0])
+        else:
+            tokens, stats = jax.device_get((out[0], out[-1]))
+            sp.attrs.update(self._record_stats(program, stats))
+        return tokens, (np.asarray(out[1]) if sampling else None)
 
     def _run_prefill(self, batch, free, sp):
         """``sp`` is the open ``serve.prefill`` span, split into
@@ -1607,7 +1630,7 @@ class ServingEngine(_EngineBase):
                                  "serve_prefill", layout.prefill_names,
                                  host)
         with sp.phase("readback"):
-            out = self._read_out(out, sp, "prefill")
+            tokens, logits = self._read_out(out, sp, "prefill", batch)
         with sp.phase("place"):
             at = time.monotonic()
             for b, (req, slot_idx, alloc) in enumerate(placed):
@@ -1623,7 +1646,8 @@ class ServingEngine(_EngineBase):
                                  prompt_len=int(req.prompt.size), **hit)
                 # the first generated token sits at position prompt_len;
                 # its k/v are written by the NEXT decode tick
-                tok, done = self._sample_and_place(req, out[b], at)
+                tok, done = self._sample_and_place(
+                    req, tokens, logits, b, at)
                 self._slots[slot_idx] = {
                     "req": req, "pos": int(req.prompt.size), "tok": tok,
                     "alloc": alloc}
@@ -1652,9 +1676,13 @@ class ServingEngine(_EngineBase):
                                  "serve_decode", layout.decode_names,
                                  host)
         with sp.phase("readback"):
-            out = self._read_out(out, sp, "decode")
+            tokens, logits = self._read_out(
+                out, sp, "decode",
+                (s["req"] for s in self._slots if s is not None))
             if not layout.candidate_axis:
-                out = out[:, None]
+                tokens = tokens[:, None]
+                if logits is not None:
+                    logits = logits[:, None]
         with sp.phase("sample"):
             at = time.monotonic()
             trace = self._trace_requests
@@ -1667,7 +1695,7 @@ class ServingEngine(_EngineBase):
                 emitted = 0
                 while True:
                     tok, done = self._sample_and_place(
-                        req, out[i, emitted], at)
+                        req, tokens, logits, (i, emitted), at)
                     emitted += 1
                     # a draft equal to what was sampled is accepted: its
                     # k/v row is already right, so its score counts too

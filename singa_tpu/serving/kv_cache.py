@@ -800,6 +800,29 @@ def _rows_from_host(state, arrays, index, what, lead=None, skip=0):
     return new_state
 
 
+def with_tokens(program, keep_logits=True):
+    """``program`` with the token chosen where the logits are. An
+    adapter's serve program returns ``(state, logits)``, or ``(state,
+    (logits, stats))`` where it counts (``stats_recorder``); this
+    returns ``(state, (tokens, logits[, stats]))`` with ``tokens =
+    argmax(logits, -1)`` as int32 — ``(rows,)``, or ``(W, K)`` for the
+    paged verify program. Ties go to the lowest id, as ``np.argmax``
+    on the host sends them, so a greedy row needs nothing but its
+    token and the ``(rows, V)`` logits stay on the device unless a
+    request samples. ``keep_logits=False`` drops them from the
+    outputs (the sharded engine: XLA combines the shards' partial
+    argmaxes and the full-vocab array is never gathered)."""
+
+    def fn(*args):
+        state, out = program(*args)
+        logits, *stats = out if isinstance(out, tuple) else (out,)
+        tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+        kept = (logits,) if keep_logits else ()
+        return state, (tokens, *kept, *stats)
+
+    return fn
+
+
 class KVLayout:
     """What both layouts share, and what a format that reserves nothing
     answers: no pool, no reservation, nothing that can fail to fit. A
@@ -822,12 +845,22 @@ class KVLayout:
         self.prefill_len, self.prefill_batch = prefill_len, prefill_batch
 
     def programs(self, sharded):
-        """``(prefill, decode)`` of the adapter; sharded, the ``greedy_``
-        twins that argmax IN GRAPH over the vocab-sharded logits (the
-        full ``(rows, V)`` array is never gathered or output)."""
-        return tuple(
-            getattr(self.adapter, ("greedy_" if sharded else "") + fn)()
-            for fn in self._programs)
+        """``(prefill, decode)``: the adapter's two programs, each
+        returning its rows' tokens beside its logits
+        (:func:`with_tokens`). Sharded, the logits are dropped: they
+        are vocab-sharded, and an output would gather them."""
+        return tuple(with_tokens(getattr(self.adapter, fn)(), not sharded)
+                     for fn in self._programs)
+
+    def outputs(self, sharded):
+        """The form of the programs' ``out``, as AOT export stamps it
+        on an artifact: ``tokens[+logits][+stats]``."""
+        parts = ["tokens"]
+        if not sharded:
+            parts.append("logits")
+        if hasattr(self.adapter, "stats_recorder"):
+            parts.append("stats")
+        return "+".join(parts)
 
     def never_fits(self, n_prompt, max_new):
         """The typed error for a request no state of the layout could
@@ -1275,4 +1308,4 @@ __all__ = ["init_cache", "ring_positions", "ring_mask", "write_token",
            "gather_pages", "attend_pages", "SlotAlloc", "BlockManager",
            "HostSpillTier", "chain_keys", "prefix_chain_key",
            "affinity_hash", "LEVEL_KEYS", "KVLayout", "RingLayout",
-           "PagedLayout", "pick_layout"]
+           "PagedLayout", "pick_layout", "with_tokens"]

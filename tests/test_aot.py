@@ -633,6 +633,37 @@ class TestServingAot:
         e3.run_until_idle()
         assert len(f.result()["tokens"]) == 4
 
+    def test_artifact_without_the_output_form_is_refused(self, dev,
+                                                         tmp_path):
+        """The programs return ``(tokens, logits)`` beside the state,
+        and the geometry stamp says so. An artifact of a build whose
+        programs returned the bare logits carries no such stamp: it is
+        refused with reason ``signature`` and compiled fresh, never
+        loaded and unpacked wrongly — and the refused engine serves the
+        tokens the exporting one served."""
+        store = AotStore(str(tmp_path))
+        e1 = self._model(dev).compile_serving(
+            slots=2, max_len=48, prefill_len=8)
+        e1.export_aot(store)
+        f1 = e1.submit([1, 2, 3], max_new_tokens=6)
+        e1.run_until_idle()
+        for program in ("serve_prefill", "serve_decode"):
+            path = os.path.join(str(tmp_path), program + ".json")
+            with open(path) as f:
+                doc = json.load(f)
+            assert doc["engine"].pop("outputs") == "tokens+logits"
+            with open(path, "w") as f:
+                json.dump(doc, f)
+        with pytest.warns(UserWarning, match="REFUSED.*outputs"):
+            e2 = self._model(dev).compile_serving(
+                slots=2, max_len=48, prefill_len=8, aot_store=store)
+        assert e2.compiled_step_info()["aot"] == {
+            "serve_prefill": "refused:signature",
+            "serve_decode": "refused:signature"}
+        f2 = e2.submit([1, 2, 3], max_new_tokens=6)
+        e2.run_until_idle()
+        assert f2.result()["tokens"] == f1.result()["tokens"]
+
 
 # ---------------------------------------------------------------------------
 # checkpoint scrub covers the aot sidecar

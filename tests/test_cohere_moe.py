@@ -237,6 +237,36 @@ def test_engine_counts_pairs_touched_experts_and_ring_rows(toy):
     # no block of the kernel divides these toy rings: each is one block
     assert [r["kv_blocks"] for r in decode[-5:]] == [4] * 5
     assert reg.get("serve_kv_blocks_walked_total").value() == 4 * 5
+    # the counts came over with the tokens: no call read its logits
+    assert {r["readback"] for r in decode[-5:]} == {"tokens"}
+    readback = reg.get("serve_readback_total")
+    assert readback.value(program="decode", what="tokens") == 5
+    assert readback.value(program="prefill", what="tokens") == 1
+    assert readback.total() == 6
+
+
+def test_the_counts_reach_the_spans_of_a_sampling_tick_too(toy):
+    """``(tokens, logits, stats)``: in a tick that serves a sampling
+    request the logits are read as well, and the counts still are the
+    last output, on the span and in the counters."""
+    cfg, m, _ = toy
+    eng = _engine(m)
+    f = eng.submit(np.arange(1, 11), max_new_tokens=4, temperature=0.7,
+                   seed=5)
+    eng.run_until_idle()
+    assert len(f.result(timeout=0)["tokens"]) == 4
+    reg = eng._reg
+    assert reg.get("moe_pairs_total").value(held="here") == 13 * 16
+    assert reg.get("moe_calls_total").value(program="decode") == 3
+    readback = reg.get("serve_readback_total")
+    assert readback.value(program="decode", what="logits") == 3
+    assert readback.value(program="prefill", what="logits") == 1
+    assert readback.total() == 4
+    from singa_tpu.observability import spans
+    last = [r for r in spans.recorder().records()
+            if r.get("name") == "serve.decode"][-1]
+    assert last["pairs_here"] == 16 and last["readback"] == "logits"
+    assert eng.compiled_step_info()["n_traces"] == 1
 
 
 def test_the_engine_takes_whatever_counts_an_adapter_publishes(toy,

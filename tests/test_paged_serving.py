@@ -450,6 +450,42 @@ class TestSpeculative:
                 - reg.get("serve_prefill_total").total()
             assert ticks < toks, (ticks, toks)
 
+    def test_the_accept_walk_compares_drafts_with_the_programs_tokens(
+            self):
+        """The verify program's ``(W, K, V)`` logits stay on the device:
+        its ``(W, K)`` tokens are what the accept walk compares the
+        drafts with, accepted prefixes equal sequential greedy decoding,
+        and a sampling request in the same ticks (one token a tick, its
+        logits read back while it lives) changes nothing for the greedy
+        one beside it. One trace a program throughout."""
+        m = tiny_lm(seed=5)
+        kw = dict(slots=2, max_len=64, prefill_len=8, kv_layout="paged",
+                  kv_block_size=4)
+        plain = m.compile_serving(**kw, registry=_reg())
+        reg = _reg()
+        spec = m.compile_serving(**kw, speculative_k=4, registry=reg)
+        draftable = [3, 3, 3, 3, 3, 3]
+        want = _greedy(plain, draftable, 16)
+        assert _greedy(spec, draftable, 16) == want
+        readback = reg.get("serve_readback_total")
+        assert readback.value(program="decode", what="logits") == 0
+        assert readback.value(program="prefill", what="logits") == 0
+        assert readback.value(program="decode", what="tokens") \
+            == reg.get("serve_decode_steps_total").total()
+        assert reg.get("speculative_proposed_total").total() > 0
+
+        greedy = spec.submit(draftable, max_new_tokens=16)
+        sampler = spec.submit([4, 1, 2], max_new_tokens=3,
+                              temperature=0.8, seed=3)
+        spec.run_until_idle()
+        assert greedy.result(timeout=5)["tokens"] == want
+        assert len(sampler.result(timeout=5)["tokens"]) == 3
+        # the sampler: one prefill call, then two decode ticks
+        assert readback.value(program="prefill", what="logits") == 1
+        assert readback.value(program="decode", what="logits") == 2
+        info = spec.compiled_step_info()
+        assert info["n_traces"] == 1 and info["prefill_n_traces"] == 1
+
     def test_sampled_request_declines_speculation_per_request(self):
         """temperature > 0 requests decode one token per tick (the rng
         draw order is part of their contract) and still match the ring

@@ -97,12 +97,7 @@ class TestContinuousBatching:
         token."""
         m = tiny_lm(seed=1)
         prompt = np.random.RandomState(1).randint(0, 19, (6,))
-        seq = list(prompt)
-        for _ in range(6):
-            logits = m(Tensor(data=np.asarray(seq, np.float32)[None],
-                              device=DEV, requires_grad=False))
-            seq.append(int(np.argmax(np.asarray(logits.data)[0, -1])))
-        ref = seq[len(prompt):]
+        ref = _reference_tokens(m, prompt, 6)
 
         eng = m.compile_serving(slots=2, max_len=32, prefill_len=8,
                                 registry=_reg())
@@ -569,3 +564,137 @@ class TestSharedDecodeHelper:
         assert a == b
         top3 = set(np.argsort(logits)[-3:].tolist())
         assert set(a) <= top3
+
+
+# ---------------------------------------------------------------------------
+# the tick reads back tokens, not logits
+# ---------------------------------------------------------------------------
+
+LAYOUTS = {"ring": {}, "paged": dict(kv_layout="paged", kv_block_size=4)}
+
+
+def _readbacks(reg):
+    """``{(program, what): calls}`` of ``serve_readback_total``."""
+    return {(s["labels"]["program"], s["labels"]["what"]): int(s["value"])
+            for m in reg.snapshot()["metrics"]
+            if m["name"] == "serve_readback_total" for s in m["series"]}
+
+
+def _reference_tokens(m, prompt, n_new, temperature=0.0, top_k=None,
+                      rng=None):
+    """What the host sampler makes of the uncached eager forward's
+    logits, one grown sequence at a time: the tokens a request is owed
+    whatever the engine reads back."""
+    seq = list(prompt)
+    for _ in range(n_new):
+        logits = m(Tensor(data=np.asarray(seq, np.float32)[None],
+                          device=DEV, requires_grad=False))
+        seq.append(decode_mod.sample_logits(
+            np.asarray(logits.data)[0, -1], temperature=temperature,
+            top_k=top_k, rng=rng))
+    return seq[len(prompt):]
+
+
+def _program_spans(rec):
+    return [r for r in rec.records() if r.get("kind") == "span"
+            and r.get("name") in ("serve.prefill", "serve.decode")]
+
+
+@pytest.fixture
+def recorder():
+    """The process-wide flight recorder, emptied for one test."""
+    from singa_tpu.observability import spans
+    rec = spans.recorder()
+    rec.clear()
+    yield rec
+    rec.clear()
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+class TestTokenReadback:
+    def test_all_greedy_run_reads_no_logits(self, layout, recorder):
+        """Greedy requests (``top_k`` set on one: with temperature 0 it
+        changes nothing) are served the argmax of the reference's
+        logits, and no program call brings the logits to the host."""
+        m = tiny_lm(seed=2)
+        reg = _reg()
+        eng = m.compile_serving(slots=2, max_len=32, prefill_len=8,
+                                registry=reg, **LAYOUTS[layout])
+        rng = np.random.RandomState(4)
+        prompts = [rng.randint(0, 19, (int(rng.randint(1, 8)),))
+                   for _ in range(5)]
+        futs = [eng.submit(p, max_new_tokens=5, temperature=0.0,
+                           top_k=3 if i == 1 else None)
+                for i, p in enumerate(prompts)]
+        eng.run_until_idle()
+        for p, f in zip(prompts, futs):
+            assert f.result(timeout=5)["tokens"] == \
+                _reference_tokens(m, p, 5)
+        calls = _readbacks(reg)
+        assert {what for _, what in calls} == {"tokens"}, calls
+        assert calls["prefill", "tokens"] >= 3
+        assert calls["decode", "tokens"] >= 4 * 3
+        recs = _program_spans(recorder)
+        assert len(recs) == sum(calls.values())
+        assert {r["readback"] for r in recs} == {"tokens"}
+
+    def test_a_tie_goes_to_the_lowest_id(self, layout):
+        """The program's argmax breaks a tie as ``np.argmax`` on the
+        host did: every logit row here IS the head's bias, with its
+        largest value at ids 3, 7 and 12."""
+        import jax.numpy as jnp
+        m = tiny_lm(seed=2)
+        bias = np.zeros(19, np.float32)
+        bias[[12, 3, 7]] = 5.0
+        m.head.W.data = jnp.zeros_like(m.head.W.data)
+        m.head.b.data = jnp.asarray(bias)
+        reg = _reg()
+        eng = m.compile_serving(slots=2, max_len=32, prefill_len=8,
+                                registry=reg, **LAYOUTS[layout])
+        futs = [eng.submit(p, max_new_tokens=4) for p in ([1, 2], [5])]
+        eng.run_until_idle()
+        assert int(np.argmax(bias)) == 3
+        for f in futs:
+            assert f.result(timeout=5)["tokens"] == [3, 3, 3, 3]
+        assert {what for _, what in _readbacks(reg)} == {"tokens"}
+
+    def test_a_sampling_request_among_greedy_ones(self, layout, recorder):
+        """One ``temperature=0.8`` request between greedy ones: every
+        request gets the tokens the host sampler draws from the
+        reference's logits (its own seeded ``rng``; the greedy rows of
+        the same ticks take the program's token), the logits come to
+        the host only in calls that serve the sampling request, and
+        neither program traces again when a tick changes kind."""
+        from singa_tpu.serving import scheduler
+        m = tiny_lm(seed=5)
+        reg = _reg()
+        eng = m.compile_serving(slots=2, max_len=32, prefill_len=8,
+                                prefill_batch=1, registry=reg,
+                                **LAYOUTS[layout])
+        first_id = next(scheduler.Request._ids) + 1
+        # (prompt, new tokens, temperature, top_k): the sampler is
+        # admitted second, lives 4 tokens, and greedy requests run
+        # before, beside and after it
+        plan = [([3, 1, 4], 9, 0.0, None), ([1, 5, 9, 2], 4, 0.8, 5),
+                ([6, 5], 6, 0.0, None), ([3, 5, 8], 5, 0.0, None)]
+        futs = [eng.submit(p, max_new_tokens=n, temperature=t, top_k=k,
+                           seed=11)
+                for p, n, t, k in plan]
+        eng.run_until_idle()
+        for i, ((p, n, t, k), f) in enumerate(zip(plan, futs)):
+            want = _reference_tokens(
+                m, p, n, temperature=t, top_k=k,
+                rng=np.random.RandomState(11 + first_id + i))
+            assert f.result(timeout=5)["tokens"] == want, i
+        # the sampler's own calls: its prefill (batches of one) and the
+        # decode ticks it was live in, one token each after the first
+        calls = _readbacks(reg)
+        assert calls["prefill", "logits"] == 1
+        assert calls["prefill", "tokens"] == 3
+        assert calls["decode", "logits"] == 3
+        assert calls["decode", "tokens"] >= 5
+        recs = _program_spans(recorder)
+        assert sorted(r["readback"] for r in recs) == sorted(
+            what for (_, what), n in calls.items() for _ in range(n))
+        info = eng.compiled_step_info()
+        assert info["n_traces"] == 1 and info["prefill_n_traces"] == 1

@@ -168,7 +168,7 @@ class TestNoVocabGather:
         eng = m.compile_serving(slots=2, max_len=48, prefill_len=8,
                                 model_shards=2, registry=_reg())
         _, decode_avals = aot_export.serving_program_avals(eng)
-        raw = eng.adapter.greedy_decode_fn()
+        raw = eng._layout.programs(eng.sharded)[1]
         jaxpr = jax.make_jaxpr(raw)(*decode_avals)
         text = str(jaxpr)
         for prim in ("all_gather", "psum", "all_to_all",
@@ -190,12 +190,43 @@ class TestNoVocabGather:
                                 model_shards=2, speculative_k=3,
                                 registry=_reg())
         _, decode_avals = aot_export.serving_program_avals(eng)
-        jaxpr = jax.make_jaxpr(eng.adapter.greedy_paged_decode_fn())(
+        jaxpr = jax.make_jaxpr(eng._layout.programs(eng.sharded)[1])(
             *decode_avals)
         assert "all_gather" not in str(jaxpr)
         assert jaxpr.out_avals[-1].shape == (eng.slots, 3)
         assert all(m.vocab_size not in a.shape
                    for a in jaxpr.out_avals)
+
+    def test_one_wrap_keeps_or_drops_the_logits(self):
+        """The sharded engine's programs are the unsharded engine's with
+        the logits dropped — one wrap (``kv_cache.with_tokens``) at the
+        one place programs are picked: unsharded, decode returns the
+        ``(W,)`` tokens beside the ``(W, V)`` float32 logits; sharded,
+        the tokens alone, and every call counts a ``tokens``
+        read-back."""
+        from singa_tpu.aot import export as aot_export
+        m = tiny_lm(seed=7)
+        reg = _reg()
+        kw = dict(slots=2, max_len=48, prefill_len=8)
+        one = m.compile_serving(**kw, registry=_reg())
+        eng = m.compile_serving(**kw, model_shards=2, registry=reg)
+        _, decode_avals = aot_export.serving_program_avals(one)
+        n_state = len(jax.tree_util.tree_leaves(decode_avals[1]))
+        for e, want in ((one, [((2,), "int32"),
+                               ((2, m.vocab_size), "float32")]),
+                        (eng, [((2,), "int32")])):
+            jaxpr = jax.make_jaxpr(e._layout.programs(e.sharded)[1])(
+                *decode_avals)
+            assert [(a.shape, str(a.dtype))
+                    for a in jaxpr.out_avals[n_state:]] == want
+        assert one._layout.outputs(False) == "tokens+logits"
+        assert eng._layout.outputs(True) == "tokens"
+        _run(eng, _prompts(3))
+        readback = reg.get("serve_readback_total")
+        assert readback.value(program="decode", what="tokens") \
+            == reg.get("serve_decode_steps_total").total() > 0
+        assert readback.value(program="decode", what="logits") == 0
+        assert readback.value(program="prefill", what="logits") == 0
 
 
 class TestTypedDeclines:
