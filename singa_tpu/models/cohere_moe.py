@@ -223,21 +223,22 @@ def forward_logits(cfg, P, tokens):
 # the model.Model
 # ---------------------------------------------------------------------------
 
-class _Forward(Operator):
-    """The whole eval forward as one tape node over the model's leaves
-    (the pure functions above)."""
+class EvalForward(Operator):
+    """A whole eval forward as one tape node over a model's leaves:
+    ``logits_fn(cfg, P, tokens)`` with ``P`` the params tree the leaves
+    unflatten to."""
 
     differentiable = False
 
-    def __init__(self, cfg, treedef):
+    def __init__(self, cfg, treedef, logits_fn):
         super().__init__()
-        self.cfg, self.treedef = cfg, treedef
+        self.cfg, self.treedef, self.logits_fn = cfg, treedef, logits_fn
 
     def forward(self, ids, *leaves):
         import jax
         import jax.numpy as jnp
         P = jax.tree_util.tree_unflatten(self.treedef, leaves)
-        return forward_logits(self.cfg, P, ids.astype(jnp.int32))
+        return self.logits_fn(self.cfg, P, ids.astype(jnp.int32))
 
 
 class CohereMoEBlock(layer.Layer):
@@ -273,7 +274,51 @@ class CohereMoEBlock(layer.Layer):
         return {**self._own_params(), "ffn": self.ffn._own_params()}
 
 
-class CohereMoELM(model.Model):
+class DrawnBeforeCompile:
+    """For a ``model.Model`` of billions of parameters (mixed in before
+    it): ``compile`` draws the parameters BEFORE the dry run — inside
+    its trace a random draw is folded into the executable (a 1 GB
+    constant a 1 GB leaf, minutes of compiling); out here it is one
+    small program a leaf on the device. The model writes
+    ``_draw_params(dev, dtype)`` and starts with ``_ready = False``."""
+
+    def compile(self, inputs, is_train=True, use_graph=False,
+                sequential=False, policy=None, **kw):
+        from .. import mixed_precision as mp
+        pol = mp.resolve(policy)
+        self._ensure_params(inputs[0].device, pol.param_dtype
+                            if pol is not None else None)
+        return super().compile(inputs, is_train=is_train,
+                               use_graph=use_graph, sequential=sequential,
+                               policy=policy, **kw)
+
+    def _ensure_params(self, dev, dtype=None, traced=False):
+        if self._ready:
+            return
+        import contextlib
+        import jax
+        import jax.numpy as jnp
+        # under a trace (eager use without compile) the draws have to be
+        # evaluated at compile time; outside one that scope is what turns
+        # a draw into a constant of its executable, so it is left out
+        scope = jax.ensure_compile_time_eval() if traced \
+            else contextlib.nullcontext()
+        with scope:
+            self._draw_params(dev, jnp.dtype(dtype or jnp.float32))
+        self._ready = True
+
+    def _leaves(self, ids):
+        """``(leaves, treedef)`` of ``param_tensors()`` for the forward's
+        one tape node, drawn first if this is eager use without
+        compile."""
+        import jax
+        self._ensure_params(ids.device,
+                            traced=isinstance(ids.data, jax.core.Tracer))
+        return jax.tree_util.tree_flatten(
+            self.param_tensors(), is_leaf=lambda t: hasattr(t, "data"))
+
+
+class CohereMoELM(DrawnBeforeCompile, model.Model):
     """One chip's share of a ``cohere2_moe`` language model.
 
     ``forward(ids)`` takes a float tensor of token ids (B, S) and gives
@@ -310,46 +355,19 @@ class CohereMoELM(model.Model):
                        for _ in self.cfg.layer_types]
         self._ready = False
 
-    def compile(self, inputs, is_train=True, use_graph=False,
-                sequential=False, policy=None, **kw):
-        """``Model.compile``, with the parameters drawn BEFORE the dry
-        run: inside its trace a random draw is folded into the
-        executable (a 1 GB constant a 1 GB leaf, minutes of compiling);
-        out here it is one small program a leaf on the device."""
-        from .. import mixed_precision as mp
-        pol = mp.resolve(policy)
-        self._ensure_params(inputs[0].device, pol.param_dtype
-                            if pol is not None else None)
-        return super().compile(inputs, is_train=is_train,
-                               use_graph=use_graph, sequential=sequential,
-                               policy=policy, **kw)
-
-    def _ensure_params(self, dev, dtype=None, traced=False):
-        if self._ready:
-            return
-        import contextlib
-        import jax
-        import jax.numpy as jnp
+    def _draw_params(self, dev, dtype):
         from ..tensor import Tensor
-        dtype = jnp.dtype(dtype or jnp.float32)
-        # under a trace (eager use without compile) the draws have to be
-        # evaluated at compile time; outside one that scope is what turns
-        # a draw into a constant of its executable, so it is left out
-        scope = jax.ensure_compile_time_eval() if traced \
-            else contextlib.nullcontext()
-        with scope:
-            self.emb = _param((self.vocab_size, self.cfg.hidden_size), dev,
-                              dtype=dtype)
-            self.emb.gaussian(0.0, self._emb_std)
-            self.ln_f = _param((self.cfg.hidden_size,), dev, init="ones",
-                               dtype=dtype)
-            probe = Tensor(shape=(1, 1, self.cfg.hidden_size), device=dev,
-                           dtype=dtype, requires_grad=False)
-            for blk in self.layers:
-                for lyr in (blk.ffn, blk):
-                    lyr.initialize(probe)
-                    lyr._initialized = True
-        self._ready = True
+        self.emb = _param((self.vocab_size, self.cfg.hidden_size), dev,
+                          dtype=dtype)
+        self.emb.gaussian(0.0, self._emb_std)
+        self.ln_f = _param((self.cfg.hidden_size,), dev, init="ones",
+                           dtype=dtype)
+        probe = Tensor(shape=(1, 1, self.cfg.hidden_size), device=dev,
+                       dtype=dtype, requires_grad=False)
+        for blk in self.layers:
+            for lyr in (blk.ffn, blk):
+                lyr.initialize(probe)
+                lyr._initialized = True
 
     def _own_params(self):
         return {"emb": self.emb, "ln_f": self.ln_f}
@@ -360,13 +378,8 @@ class CohereMoELM(model.Model):
                 "layers": [blk.leaves() for blk in self.layers]}
 
     def forward(self, ids):
-        import jax
-        # eager use without compile (compile() has drawn them otherwise)
-        self._ensure_params(ids.device,
-                            traced=isinstance(ids.data, jax.core.Tracer))
-        leaves, treedef = jax.tree_util.tree_flatten(
-            self.param_tensors(), is_leaf=lambda t: hasattr(t, "data"))
-        return _Forward(self.cfg, treedef)(ids, *leaves)
+        leaves, treedef = self._leaves(ids)
+        return EvalForward(self.cfg, treedef, forward_logits)(ids, *leaves)
 
     def train_one_batch(self, *a, **kw):
         raise NotImplementedError(
@@ -386,14 +399,10 @@ def create_model(vocab_size=256, **kwargs):
 # the serve adapter
 # ---------------------------------------------------------------------------
 
-class _ServeAdapter:
-    """What ``ServingEngine`` needs of the model (docs/serving.md, "The
-    adapter contract"): the model's own arrays by reference, per-layer
-    rings (``min(window, max_len)`` positions for a window layer,
-    ``max_len`` for a full one, one row of KV heads each), a prefill and
-    a decode program built on :func:`block_apply`, and the expert
-    layer's counts riding each program's read-back
-    (:meth:`stats_recorder`)."""
+class ByReferenceAdapter:
+    """The part of a serve adapter that hands the engine the model's own
+    device arrays (``m.param_tensors()``), for a model too large to hold
+    twice: ring layout only, one device, no quantized second copy."""
 
     supports_paged = False
     supports_sharded = False
@@ -417,8 +426,8 @@ class _ServeAdapter:
         if getattr(self.policy, "weight_quant", None) or \
                 getattr(self.policy, "cache_quant", None):
             raise ValueError(
-                "CohereMoELM serves its own arrays: quantized serving "
-                "policies are not supported")
+                f"{type(self.m).__name__} serves its own arrays: "
+                "quantized serving policies are not supported")
 
     def params(self):
         """The model's own device arrays: no host round trip, no cast, no
@@ -427,6 +436,17 @@ class _ServeAdapter:
         return jax.tree_util.tree_map(
             lambda t: t.data, self.m.param_tensors(),
             is_leaf=lambda t: hasattr(t, "data"))
+
+
+
+class _ServeAdapter(ByReferenceAdapter):
+    """What ``ServingEngine`` needs of the model (docs/serving.md, "The
+    adapter contract"): the model's own arrays by reference, per-layer
+    rings (``min(window, max_len)`` positions for a window layer,
+    ``max_len`` for a full one, one row of KV heads each), a prefill and
+    a decode program built on :func:`block_apply`, and the expert
+    layer's counts riding each program's read-back
+    (:meth:`stats_recorder`)."""
 
     def stats_recorder(self, registry):
         """The expert layer's counters in the engine's registry, and the
@@ -492,25 +512,13 @@ class _ServeAdapter:
             new_cache, stats = [], []
             for kind, p, level in zip(cfg.layer_types, P["layers"], cache):
                 window = cfg.sliding_window if kind == SLIDING else None
-                L = level["k"].shape[2]
 
-                def attend(q, k, v, level=level, window=window, L=L):
+                def attend(q, k, v, level=level, window=window):
                     o = masked_attention(
                         q, k, v, cfg.scale, window,
                         n_blocks=None if blocks is None else blocks[0])
-                    kh, vh = k.swapaxes(1, 2), v.swapaxes(1, 2)  # B,H,S,D
-                    for b in range(B):
-                        kb, vb = kh[b], vh[b]
-                        if S > L:
-                            # a prompt longer than the ring: index r
-                            # gets the last prompt row t with t % L == r
-                            r = jnp.arange(L, dtype=jnp.int32)
-                            last = lengths[b] - 1
-                            t = jnp.clip(last - ((last - r) % L), 0, S - 1)
-                            kb, vb = kb[:, t], vb[:, t]
-                        level = kv_cache.write_prompt(
-                            level, slot_ids[b], kb, vb, valid[b])
-                    return o, level
+                    return o, kv_cache.write_prompts(
+                        level, slot_ids, k, v, lengths, valid)
 
                 x, level, st = block_apply(cfg, kind, p, x, positions,
                                            attend, rows, blocks)
@@ -552,4 +560,5 @@ class _ServeAdapter:
 
 
 __all__ = ["CohereMoELM", "CohereMoEBlock", "Config", "block_apply",
-           "forward_logits", "create_model"]
+           "forward_logits", "create_model", "DrawnBeforeCompile",
+           "ByReferenceAdapter", "EvalForward"]

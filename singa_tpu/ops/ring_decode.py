@@ -27,6 +27,11 @@ the ordinary pipeline and nothing else is fetched. Of the step:
   block that took it is copied back by a DMA into the level, which is
   aliased to the output — no other byte of the cache is written.
 
+A layer that attends to ANOTHER layer's keys and values takes the same
+walk read-only (:func:`ring_attend`, ``kv_cache.attend_token``): no new
+row, nothing written, the level no output of the call, so every such
+reader shares the owner's buffer.
+
 The kernel's text does not depend on ``L`` (blocks are a grid axis) nor
 on ``G`` (one matrix product), and the call sits in a jitted wrapper with
 static arguments, so a model lowers it once a distinct
@@ -106,10 +111,11 @@ def kernel_block(n_kv, length, D):
 
 
 def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
-                        q_ref, kn_ref, vn_ref, k_ref, v_ref,
-                        o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref, sem,
-                        *, scale, block, length, tile, cols):
+                        q_ref, *refs, scale, block, length, tile, cols,
+                        write):
     """One (slot, block) pair of the walk, one group of KV heads.
+    ``write`` false is the read-only pass (:func:`ring_attend`): no new
+    row, no output but the attention's, the level is only read.
 
     Row-major level (``cols`` false): the block is ``(B, D)`` of one KV
     head and ``q`` its ``(G, D)`` query group. ``cols``: the level is
@@ -119,6 +125,11 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
     product scores every head against its own keys; the value product
     then holds every head's values in every row, and the wrapper reads
     each row's own head out of it."""
+    if write:
+        (kn_ref, vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
+         acc_ref, m_ref, l_ref, sem) = refs
+    else:
+        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     hg = pl.program_id(0)
     s = pl.program_id(1)
     w = slot_ref[s]
@@ -140,13 +151,12 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
         lead = (0, 0)
         here = (pl.ds(base, tile), slice(None))
         there = (w, hg, pl.ds(j * block + base, tile), slice(None))
-    copies = (
+    copies = () if not write else (
         pltpu.make_async_copy(k_ref.at[lead + here], ko_ref.at[there],
                               sem.at[0]),
         pltpu.make_async_copy(v_ref.at[lead + here], vo_ref.at[there],
                               sem.at[1]))
 
-    @pl.when(jnp.logical_and(live, writes))
     def _write():
         # merged into the fetched block, which the walk below then reads
         # like any other, and copied from there into the level
@@ -182,6 +192,9 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
         for c in copies:
             c.start()
 
+    if write:
+        pl.when(jnp.logical_and(live, writes))(_write)
+
     @pl.when(jnp.logical_and(live, j == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -209,10 +222,11 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
         o_ref[0, 0] = (acc_ref[...] / _across(l_ref[...], K)).astype(
             o_ref.dtype)
 
-    @pl.when(jnp.logical_and(live, writes))
-    def _written():
-        for c in copies:
-            c.wait()
+    if write:
+        @pl.when(jnp.logical_and(live, writes))
+        def _written():
+            for c in copies:
+                c.wait()
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
@@ -254,51 +268,51 @@ def _ring_decode_call(q, k_new, v_new, k, v, pos, active, *, scale, block,
         qg = qg * jnp.eye(hb, dtype=dtype)[:, None, :, None]
     qg = jnp.pad(qg.reshape(W, groups, hb * G, K),
                  ((0, 0), (0, 0), (0, M - hb * G), (0, 0)))
-    # the rows as the cache will hold them, widened for the VPU
-    kn = k_new.astype(dtype).astype(jnp.float32)
-    vn = v_new.astype(dtype).astype(jnp.float32)
+    write = k_new is not None
     if cols:
         k, v = (a.swapaxes(2, 3).reshape(W, n_kv * D, L) for a in (k, v))
-        # a group's new row, 128 values to a row of the operand
-        kn, vn = (a.reshape(W, groups, K // _LANES, _LANES)
-                  for a in (kn, vn))
+        new_shape = (W, groups, K // _LANES, _LANES)
         new_row = spec((1, 1, K // _LANES, _LANES),
                        lambda w, g, b: (w, g, 0, 0))
         kv_block = spec((1, K, block), lambda w, g, b: (w, g, b))
     else:
-        kn, vn = kn[:, :, None], vn[:, :, None]
+        new_shape = (W, n_kv, 1, D)
         new_row = spec((1, 1, 1, D), lambda w, g, b: (w, g, 0, 0))
         kv_block = spec((1, 1, block, D), lambda w, g, b: (w, g, b, 0))
     by_slot = spec((1, 1, M, K), lambda w, g, b: (w, g, 0, 0))
     kernel = functools.partial(
         _ring_decode_kernel, scale=scale, block=block, length=L,
-        tile=_LANES if cols else rows, cols=cols)
-    out, k, v = pl.pallas_call(
+        tile=_LANES if cols else rows, cols=cols, write=write)
+    # the new rows as the cache will hold them, widened for the VPU (a
+    # group's, 128 values to a row of the operand, where the ring lies
+    # on the lanes); the read-only pass has none, writes nothing and
+    # leaves the level to its other readers
+    new_rows = [a.astype(dtype).astype(jnp.float32).reshape(new_shape)
+                for a in (k_new, v_new)] if write else []
+    level_out = [pl.BlockSpec(memory_space=pl.ANY)] * 2 if write else []
+    out, *kv = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(groups, jnp.maximum(total[0], 1)),
-            in_specs=[by_slot, new_row, new_row, kv_block, kv_block],
-            out_specs=[by_slot, pl.BlockSpec(memory_space=pl.ANY),
-                       pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[by_slot] + [new_row] * len(new_rows)
+            + [kv_block, kv_block],
+            out_specs=[by_slot] + level_out,
             scratch_shapes=[
                 pltpu.VMEM((M, K), jnp.float32),
                 pltpu.VMEM((M, _LANES), jnp.float32),
                 pltpu.VMEM((M, _LANES), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
-            ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((W, groups, M, K), q.dtype),
-            jax.ShapeDtypeStruct(k.shape, dtype),
-            jax.ShapeDtypeStruct(v.shape, dtype),
-        ],
+            ] + ([pltpu.SemaphoreType.DMA((2,))] if write else [])),
+        out_shape=[jax.ShapeDtypeStruct((W, groups, M, K), q.dtype)]
+        + ([jax.ShapeDtypeStruct(a.shape, dtype) for a in (k, v)]
+           if write else []),
         # operands count the five prefetched arrays: k is 8, v is 9
-        input_output_aliases={8: 1, 9: 2},
+        input_output_aliases={8: 1, 9: 2} if write else {},
         interpret=interpret,
-        name="ring_decode",
-    )(total, slot, blk, pos, walk, qg, kn, vn, k, v)
+        name="ring_decode" if write else "ring_attend",
+    )(total, slot, blk, pos, walk, qg, *new_rows, k, v)
     if cols:
-        k, v = (a.reshape(W, n_kv, D, L).swapaxes(2, 3) for a in (k, v))
+        kv = [a.reshape(W, n_kv, D, L).swapaxes(2, 3) for a in kv]
     # each row's own head of the value product; a dead slot's rows of
     # the output were never written
     out = out[:, :, :hb * G]
@@ -307,7 +321,7 @@ def _ring_decode_call(q, k_new, v_new, k, v, pos, active, *, scale, block,
                          out.reshape(W, groups, hb, G, hb, D),
                          jnp.eye(hb, dtype=out.dtype))
     out = jnp.where(active[:, None, None, None], out.reshape(q.shape), 0)
-    return out, k, v
+    return (out, *kv)
 
 
 def ring_decode(q, k_new, v_new, k, v, pos, active, scale, block):
@@ -319,3 +333,16 @@ def ring_decode(q, k_new, v_new, k, v, pos, active, scale, block):
     return _ring_decode_call(q, k_new, v_new, k, v, pos, active,
                              scale=float(scale), block=int(block),
                              interpret=_interpret())
+
+
+def ring_attend(q, k, v, pos, active, scale, block):
+    """The read-only pass: attend ``q`` ``(W, H, 1, D)`` over each live
+    slot's ring as it stands, ``pos`` the position of its newest row
+    (written already, by the level's own :func:`ring_decode` of this
+    tick). The same walk over the blocks that hold a token; no tile is
+    written and the level is not an output, so as many layers as read one
+    ring share one buffer. Returns ``out (W, H, 1, D)``, zero for a dead
+    slot."""
+    return _ring_decode_call(q, None, None, k, v, pos, active,
+                             scale=float(scale), block=int(block),
+                             interpret=_interpret())[0]

@@ -1110,7 +1110,8 @@ class ServingEngine(_EngineBase):
         receiver's pool: layout, layer count, cache dtype +
         quantization, head geometry, position space, and the
         quantization policy. Rides every frame's CRC-covered meta."""
-        level = self._cache[0]
+        # the first ring level (a cache may hold state levels too)
+        level = next(lv for lv in self._cache if "k" in lv)
         shape = tuple(int(d) for d in level["k"].shape)
         g = {"n_layers": len(self._cache),
              "dtype": str(level["k"].dtype),
@@ -1623,7 +1624,8 @@ class ServingEngine(_EngineBase):
         and ``place`` (first tokens sampled, slots filled)."""
         layout = self._layout
         with sp.phase("pack"):
-            host, placed, n_tokens = layout.pack_prefill(batch, free)
+            host, placed, n_tokens = layout.pack_prefill(
+                batch, free, sp.attrs)
             self._prefill_tok.inc(n_tokens)
         with sp.phase("dispatch"):
             out = self._dispatch(self._prefill, self._prefill_rec,

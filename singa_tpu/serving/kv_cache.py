@@ -22,7 +22,13 @@ ring a block divides takes the Pallas kernel of ``ops/ring_decode.py``
 one tile of the level is written, a dead slot is neither read nor
 written); a quantized level, the GSPMD-sharded engine, an odd ring
 length and every other backend keep the XLA twins :func:`write_token`
-+ :func:`attend`, which write every slot and score the whole level.
++ :func:`attend`, which write every slot and score the whole level. A
+layer that reads a ring it does not own (cross attention to another
+layer's keys and values) calls :func:`attend_token`, the read-only half
+by the same rule. A cache may also hold STATE levels beside its rings —
+what a recurrent layer keeps of a sequence, a fixed size a slot — which
+the ring layout sizes, counts, snapshots and hands off with the rings
+(:class:`RingLayout`).
 
 **Paged** (``compile_serving(kv_layout="paged")``): one fixed POOL of
 ``(n_blocks, n_heads, block_size, head_dim)`` KV blocks per layer plus
@@ -273,6 +279,24 @@ def decode_token(level, q, k_new, v_new, pos, active, scale):
     return out, {"k": k, "v": v}
 
 
+def attend_token(level, q, pos, active, scale):
+    """The read-only half of a decode tick: attend ``q`` ``(W, H, 1, D)``
+    over a ring level as it stands — ``pos`` the position of its newest
+    row, which the level's own :func:`decode_token` has written in this
+    tick — and write nothing. What a layer calls that reads another
+    layer's keys and values. By the same rule as :func:`decode_token`: a
+    level the kernel can take goes through its read-only pass over the
+    blocks that hold a token (``ops/ring_decode.ring_attend``), any other
+    through :func:`attend`, which scores the whole level. Returns
+    ``(W, H, 1, D)``."""
+    block = ring_block(level)
+    if block is None:
+        return attend(q, level, pos, scale)
+    from ..ops import ring_decode as _rd
+    return _rd.ring_attend(q, level["k"], level["v"], pos, active, scale,
+                           block)
+
+
 def write_prompt(level, slot, k_rows, v_rows, valid):
     """Write one prompt's rows into one slot, starting at ring index 0.
 
@@ -303,6 +327,29 @@ def write_prompt(level, slot, k_rows, v_rows, valid):
         out["k_scale"] = jnp.where(valid, ks_up, level["k_scale"])
         out["v_scale"] = jnp.where(valid, vs_up, level["v_scale"])
     return out
+
+
+def write_prompts(level, slot_ids, k, v, lengths, valid):
+    """A prefill batch's keys and values into their slots' rings.
+
+    ``k``/``v``: ``(B, S, H, D)`` of whole (padded) prompts; ``slot_ids``,
+    ``lengths``, ``valid``: ``(B,)``. A prompt no longer than the ring
+    lies from index 0 (:func:`write_prompt`); of one that is longer
+    (``S > L``: a window layer's ring under a longer prefill) ring index
+    ``r`` gets the last prompt row ``t`` with ``t % L == r``, which is
+    what token-by-token writing would have left."""
+    L = level["k"].shape[2]
+    B, S = k.shape[:2]
+    kh, vh = k.swapaxes(1, 2), v.swapaxes(1, 2)          # B, H, S, D
+    for b in range(B):
+        kb, vb = kh[b], vh[b]
+        if S > L:
+            r = jnp.arange(L, dtype=jnp.int32)
+            last = lengths[b].astype(jnp.int32) - 1
+            t = jnp.clip(last - ((last - r) % L), 0, S - 1)
+            kb, vb = kb[:, t], vb[:, t]
+        level = write_prompt(level, slot_ids[b], kb, vb, valid[b])
+    return level
 
 
 def attend(q, level, pos, scale):
@@ -759,12 +806,20 @@ class BlockManager:
 LEVEL_KEYS = ("k", "v", "k_scale", "v_scale")
 
 
+def _level_names(level):
+    """A level's arrays in serialization order: the ``LEVEL_KEYS`` it
+    has, then whatever else it holds (a state level's arrays, whose
+    names are the adapter's) by name."""
+    return [n for n in LEVEL_KEYS if n in level] \
+        + sorted(n for n in level if n not in LEVEL_KEYS)
+
+
 def _rows_to_host(state, index):
     """Every level's arrays at ``index`` of their first axis (a ring's
-    slot, a pool's block or blocks), on the host, in ``LEVEL_KEYS``
-    order."""
+    or a state's slot, a pool's block or blocks), on the host, in
+    :func:`_level_names` order."""
     return [np.asarray(level[name][index]) for level in state
-            for name in LEVEL_KEYS if name in level]
+            for name in _level_names(level)]
 
 
 def _rows_from_host(state, arrays, index, what, lead=None, skip=0):
@@ -780,9 +835,7 @@ def _rows_from_host(state, arrays, index, what, lead=None, skip=0):
     new_state = []
     for level in state:
         upd = dict(level)
-        for name in LEVEL_KEYS:
-            if name not in level:
-                continue
+        for name in _level_names(level):
             arr = next(it)
             want = tuple(level[name].shape[1:])
             if lead is not None:
@@ -875,57 +928,97 @@ class KVLayout:
 
 
 class RingLayout(KVLayout):
-    """One ring a slot a level (``adapter.init_cache``): a free slot is
-    a free ring and generation past ``max_len`` slides its window, so
-    the defaults above hold; a decode tick carries one token a slot.
-    Also what a recurrent adapter's per-slot state rides (no rings:
-    ``lengths`` stays None and the ring gauges are not made)."""
+    """One slot's share of every level (``adapter.init_cache``): a free
+    slot is a free row of each and generation past ``max_len`` slides a
+    ring's window, so the defaults above hold; a decode tick carries one
+    token a slot. A cache is a list of levels, each a ring ``{"k","v"}``
+    or a state — any other dict of arrays with the slot first: what a
+    recurrent layer keeps of a sequence, of a fixed size whatever its
+    length. Everything here derives from that list, not from the model:
+    levels may be fewer than layers (layers that read another layer's
+    ring own none), and the adapter may say what each level is
+    (``cache_kinds()``: ``"window"`` | ``"full"`` | ``"state"``) and how
+    many layers read it each tick (``cache_readers()``). A recurrent
+    adapter whose state is not such a list rides the layout opaquely
+    (``lengths`` stays None, no gauges, no snapshots of it)."""
 
     name = "ring"
     prefill_names = ("tokens", "lengths", "slot_ids", "valid")
     decode_names = ("tokens", "positions", "active")
     _programs = ("prefill_fn", "decode_fn")
     lengths = None
+    n_state = 0
+    _prefill_rows = None
 
     def init_state(self):
         state = self.adapter.init_cache(self.slots, self.max_len)
+        if hasattr(self.adapter, "prefill_rows"):
+            self._prefill_rows = self._reg.counter(
+                "serve_prefill_rows_total", "rows of prefill batches "
+                "each of the adapter's decoders ran (an adapter whose "
+                "later layers run a prompt's last token alone)",
+                labels=("decoder",))
         if not (isinstance(state, list) and all(
-                isinstance(lv, dict) and "k" in lv for lv in state)):
+                isinstance(lv, dict) for lv in state) and any(
+                "k" in lv for lv in state)):
             return state
-        # what the rings hold, by kind of layer: an adapter whose layers
-        # keep rings of different lengths names each level's kind
-        # (``cache_kinds``); one geometry reads as "full"
+        rings = [lv for lv in state if "k" in lv]
+        # what the levels hold, by kind: an adapter whose layers keep
+        # rings of different lengths, or states beside them, names each
+        # level's kind (``cache_kinds``); one geometry reads as "full"
         kinds = getattr(self.adapter, "cache_kinds", None)
-        kinds = kinds() if kinds is not None else ["full"] * len(state)
+        kinds = kinds() if kinds is not None else \
+            ["full" if "k" in lv else "state" for lv in state]
         kv_bytes = self._reg.gauge(
-            "serve_kv_bytes", "bytes of ring KV state, by kind of "
-            "layer (window: min(window, max_len) positions a slot; "
-            "full: max_len)", labels=("kind",))
+            "serve_kv_bytes", "bytes of per-slot serving state, by kind "
+            "of level (window: rings of min(window, max_len) positions "
+            "a slot; full: of max_len; state: what recurrent layers "
+            "keep, of a fixed size a slot)", labels=("kind",))
         for kind in sorted(set(kinds)):
             kv_bytes.set(sum(
                 int(a.size) * a.dtype.itemsize
                 for k, level in zip(kinds, state) if k == kind
                 for a in level.values()), kind=kind)
-        self.lengths = np.asarray(
-            [int(level["k"].shape[2]) for level in state])
+        self.lengths = np.asarray([int(lv["k"].shape[2]) for lv in rings])
         # a ring that no block divides is walked whole
         from ..ops.ring_decode import block_rows
         self._blocks = np.asarray(
             [block_rows(n) or n for n in self.lengths])
+        # layers that read each ring a tick (a layer that attends to
+        # another layer's keys and values reads that layer's ring again)
+        readers = getattr(self.adapter, "cache_readers", None)
+        readers = readers() if readers is not None else [1] * len(state)
+        self._readers = np.asarray(
+            [int(n) for n, lv in zip(readers, state) if "k" in lv])
+        self._state_shapes = [
+            [name, [int(d) for d in lv[name].shape[1:]],
+             str(lv[name].dtype)]
+            for lv in state if "k" not in lv for name in _level_names(lv)]
+        self.n_state = len(state) - len(rings)
         self._kv_rows = self._reg.counter(
             "serve_kv_rows_attended_total", "ring rows holding a token "
-            "that decode ticks attended to, summed over layers and "
-            "active slots (what a tick has to read of the cache)")
+            "that decode ticks attended to, summed over the layers that "
+            "read them and active slots (what a tick has to read of "
+            "the rings)")
         self._kv_blocks = self._reg.counter(
             "serve_kv_blocks_walked_total", "blocks of the rings "
             "holding a token, as the ring decode kernel cuts them, "
-            "summed over layers and active slots (against slots x "
-            "blocks a ring: the share of the whole walk)")
+            "summed over the layers that read them and active slots "
+            "(against slots x blocks a ring: the share of the whole "
+            "walk)")
+        if self.n_state:
+            self._state_steps = self._reg.counter(
+                "serve_state_steps_total", "recurrent states a decode "
+                "tick stepped: active slots x state levels")
         return state
 
-    def pack_prefill(self, batch, free):
+    def pack_prefill(self, batch, free, attrs):
         """``(program arrays, [(request, slot, alloc)], prompt tokens
-        the program runs)`` for one admitted batch."""
+        the program runs)`` for one admitted batch. An adapter whose
+        layers do not all run every row of a prompt says what each of
+        its decoders runs (``prefill_rows(lengths) -> {decoder:
+        rows}``): that goes on the span (``attrs``, as
+        ``<decoder>_rows``) and into ``serve_prefill_rows_total``."""
         B, S = self.prefill_batch, self.prefill_len
         tokens = np.zeros((B, S), np.int32)
         lengths = np.zeros((B,), np.int32)
@@ -939,6 +1032,11 @@ class RingLayout(KVLayout):
             slot_ids[b] = free[b]
             valid[b] = True
             placed.append((req, free[b], None))
+        if self._prefill_rows is not None:
+            for decoder, n in self.adapter.prefill_rows(
+                    lengths[valid]).items():
+                attrs[f"{decoder}_rows"] = n
+                self._prefill_rows.inc(n, decoder=decoder)
         return (tokens, lengths, slot_ids, valid), placed, \
             int(lengths.sum())
 
@@ -957,10 +1055,14 @@ class RingLayout(KVLayout):
                 active[i] = True
         if self.lengths is not None:
             rows = np.minimum(positions[active, None] + 1, self.lengths)
-            attrs["kv_rows"] = int(rows.sum())
-            attrs["kv_blocks"] = int((-(-rows // self._blocks)).sum())
+            attrs["kv_rows"] = int((rows * self._readers).sum())
+            attrs["kv_blocks"] = int(
+                (-(-rows // self._blocks) * self._readers).sum())
             self._kv_rows.inc(attrs["kv_rows"])
             self._kv_blocks.inc(attrs["kv_blocks"])
+            if self.n_state:
+                attrs["state_slots"] = int(active.sum()) * self.n_state
+                self._state_steps.inc(attrs["state_slots"])
         return (tokens, positions, active), None
 
     def geometry(self):
@@ -969,6 +1071,9 @@ class RingLayout(KVLayout):
                 any(n != self.max_len for n in self.lengths):
             # layers with rings of their own length (window layers)
             g["ring_lengths"] = [int(n) for n in self.lengths]
+        if self.n_state:
+            # what each state level keeps of a slot: name, shape, dtype
+            g["state"] = self._state_shapes
         return g
 
     def info(self, part):
@@ -1129,7 +1234,7 @@ class PagedLayout(KVLayout):
         self._cached.set(self.mgr.blocks_cached())
 
     # -- packing -------------------------------------------------------------
-    def pack_prefill(self, batch, free):
+    def pack_prefill(self, batch, free, attrs):
         """As :meth:`RingLayout.pack_prefill`; each popped request
         arrives with its reservation taken (``req._alloc``), and the
         pool gauges follow the batch's, once."""
@@ -1252,13 +1357,21 @@ def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
             f"kv_layout must be 'ring' or 'paged', got {kv_layout!r}")
     declined = {}
     paged = kv_layout == "paged"
-    if paged and not getattr(adapter, "supports_paged", False):
+    # a level that is no ring (``cache_kinds``: "state") is what a
+    # recurrent layer keeps of a sequence: no row a position for a pool
+    # to page or share, and nothing a rejected draft could be rolled
+    # back to
+    kinds = getattr(adapter, "cache_kinds", None)
+    recurrent = kinds is not None and "state" in kinds()
+    if paged and (recurrent
+                  or not getattr(adapter, "supports_paged", False)):
         warnings.warn(
             f"kv_layout='paged' declined: {type(adapter).__name__} has "
             "no paged block-pool programs (its decode state is not "
             "per-position KV rows); serving on the ring layout instead",
             stacklevel=4)
-        declined["kv_layout_declined"] = "adapter_unsupported"
+        declined["kv_layout_declined"] = "recurrent_state" if recurrent \
+            else "adapter_unsupported"
         paged = False
     # speculative_k = verify-program width: up to speculative_k tokens
     # emitted per tick (speculative_k - 1 of them drafted). It needs the
@@ -1272,8 +1385,11 @@ def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
             "speculative_k declined: speculative decoding needs "
             "kv_layout='paged' (the ring's wraparound would "
             "re-attribute rejected-draft rows into the attention "
-            "window); decoding one token per tick", stacklevel=4)
-        declined["speculative_declined"] = "requires_paged_layout"
+            "window" + ("; a recurrent state stepped over a rejected "
+                        "draft cannot be rolled back" if recurrent else "")
+            + "); decoding one token per tick", stacklevel=4)
+        declined["speculative_declined"] = "recurrent_state" if recurrent \
+            else "requires_paged_layout"
     spill = int(spill_bytes or 0)
     if spill > 0 and not paged:
         warnings.warn(
@@ -1303,7 +1419,9 @@ def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
 
 
 __all__ = ["init_cache", "ring_positions", "ring_mask", "write_token",
-           "write_prompt", "attend", "decode_token", "ring_block",
+           "write_prompt", "write_prompts", "attend", "decode_token",
+           "attend_token",
+           "ring_block",
            "xla_rings", "init_pool", "write_rows",
            "gather_pages", "attend_pages", "SlotAlloc", "BlockManager",
            "HostSpillTier", "chain_keys", "prefix_chain_key",

@@ -108,12 +108,16 @@ def test_ring_form_compiles(one_chip, for_the_chip):
 
 # (slots, KV heads, query heads a KV head, ring, head size), dtype: the two
 # serve cells' levels first (GPT-2's lies with the ring on the lanes,
-# Command A+'s two are row-major), then float32 levels, grouped KV heads at
-# head size 128, and chip_smoke's toy rings
+# Command A+'s two are row-major, as are the two of the differential
+# attention of phi4flash: 10 paired KV heads of 128 read by 4 queries each),
+# then float32 levels, grouped KV heads at head size 128, and chip_smoke's
+# toy rings
 RING_LEVELS = [
     ((64, 16, 1, 1024, 64), jnp.bfloat16),
     ((64, 1, 16, 4096, 128), jnp.bfloat16),
     ((64, 1, 16, 5120, 128), jnp.bfloat16),
+    ((64, 10, 4, 512, 128), jnp.bfloat16),
+    ((64, 10, 4, 3072, 128), jnp.bfloat16),
     ((8, 16, 1, 1024, 64), jnp.float32),
     ((8, 2, 1, 1024, 128), jnp.float32),
     ((8, 8, 4, 2048, 128), jnp.bfloat16),
@@ -156,3 +160,36 @@ def test_ring_decode_compiles_and_writes_the_level_in_place(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * level
     assert mem.temp_size_in_bytes < level // 4
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize(
+    "shape,dtype", [RING_LEVELS[4], RING_LEVELS[0], RING_LEVELS[-1]],
+    ids=["full-ring-10x4x128", "ring-on-the-lanes-16x64", "toy-4x2x32"])
+def test_ring_attend_compiles_and_leaves_the_level_alone(
+        one_chip, for_the_chip, monkeypatch, shape, dtype):
+    """The read-only pass (a layer that reads another layer's ring): one
+    Mosaic call named apart from the writing one, the level neither an
+    output nor aliased, and no temporary of a level's size."""
+    from singa_tpu.ops import ring_decode
+    monkeypatch.setattr(ring_decode, "_interpret", lambda: False)
+    W, n_kv, G, L, D = shape
+    block = ring_decode.kernel_block(n_kv, L, D)
+
+    def sds(*s, dt=dtype):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def f(q, k, v, pos, active):
+        return ring_decode.ring_attend(q, k, v, pos, active, D ** -0.5,
+                                       block)
+
+    compiled = jax.jit(f).lower(
+        sds(W, n_kv * G, 1, D), sds(W, n_kv, L, D), sds(W, n_kv, L, D),
+        sds(W, dt=jnp.int32), sds(W, dt=jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "ring_attend" in hlo
+    level = W * n_kv * L * D * jnp.dtype(dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < level // 4
+    assert mem.output_size_in_bytes < level // 4
