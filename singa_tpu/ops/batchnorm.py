@@ -7,9 +7,17 @@ the running blocks on device; here the update rebinds the state Tensors'
 values, which the Model layer threads through jit as donated state), and
 inference mode normalises by the running statistics.
 
-Backward (dx, dscale, dbias) is the vjp of the batch-stat normalisation —
-the same math as cudnnBatchNormalizationBackward, emitted by XLA as a fused
-reduction + elementwise kernel.
+Training mode is written in closed form in both directions, so a step
+passes over the activation as rarely as BN's mathematics allows. The
+batch statistics are Σx and Σx² in one read (float32 accumulation,
+var = max(Σx²/N − mean², 0)); the backward is dβ = Σdy, dγ = Σdy·x̂ in
+one read of (x, dy), then dx = γ·inv·(dy − dβ/N − x̂·dγ/N) elementwise —
+the same math as cudnnBatchNormalizationBackward. Each pair of sums is
+two siblings of one fusion; on the TPU XLA hangs them on the neighbouring
+convolution fusion as its epilogue, so no reduction kernel of BN's own is
+left in a conv net's step (PERF.md §5).
+Inside a data-parallel shard_map step the statistics are the global
+batch's: one stacked pmean forward, one stacked psum backward per BN.
 """
 
 from __future__ import annotations
@@ -50,38 +58,60 @@ class BatchNormHandle:
             else (1, self.channels, 1, 1)
 
 
+def _batch_axes():
+    """Mesh axes the step's batch is sharded over (empty outside a mesh
+    context); imported here because ``parallel`` imports ``layer``."""
+    from ..parallel.communicator import active_batch_axes
+    return active_batch_axes()
+
+
 def _global_moments(xb, axes):
-    """Batch mean/var, pmean-synchronised across every mesh axis the
-    batch is sharded over (identity outside a mesh context). Inside a
-    shard_map'd step each replica sees only its local batch shard;
-    sync-BN pmeans the moments so both normalisation and the
-    running-stat update use GLOBAL batch statistics — making the sharded
-    step numerically identical to a single-device full-batch step (the
-    SPMD-correct form of the reference's in-place running stats,
+    """Batch mean and biased variance in ONE read of the activation: Σx
+    and Σx² are sibling float32 reductions of the same operand (XLA
+    emits them as one multi-output fusion), mean = Σx/N and
+    var = max(Σx²/N − mean², 0) — Flax BatchNorm's default
+    (``use_fast_variance``). A bf16 input is accumulated in f32 (the
+    cast fuses into the reduction: the "stats stay f32" contract at
+    zero cost; a bf16 sum over N·H·W ≈ 1.6M elements at the bench
+    shapes would lose most of its mantissa), and a bf16 value's square
+    is exact in f32, so what the raw moments lose is f32 summation
+    error × (1 + mean²/var): on the TPU's reductions 2e-4 of the
+    variance at a mean of 30 standard deviations and 3e-3 at 100
+    (PERF.md §6, PR 32; XLA:CPU sums in one accumulator and loses about
+    ten times that).
+
+    Inside a shard_map'd step each replica sees only its local batch
+    shard; the two raw moments go through ONE stacked pmean over every
+    mesh axis the batch is sharded over (identity outside a mesh
+    context), so normalisation and the running-stat update use GLOBAL
+    batch statistics and, with equal-sized shards, the sharded step is
+    numerically a single-device full-batch step (the SPMD-correct form
+    of the reference's in-place running stats,
     src/model/operation/batchnorm.h:103-115). The axes come from the
     Model step's declared input batch sharding, NOT a hardcoded 'data'
-    (the batch may shard over ('data','expert') or a renamed axis).
-    Two-pass: variance is the mean squared deviation around the GLOBAL
-    mean — numerically stable (never negative) and, with equal-sized
-    shards, exactly the full-batch biased variance."""
-    from ..parallel.communicator import active_batch_axes
-    paxes = active_batch_axes()
-    # accumulate moments in f32 regardless of activation dtype: a bf16
-    # sum over N*H*W elements (~1.6M at the bench shapes) loses most of
-    # its mantissa; the cast fuses into the reduction, so this is the
-    # "stats stay f32" contract at zero cost
-    xb = xb.astype(jnp.float32)
-    mean = jnp.mean(xb, axis=axes)
+    (the batch may shard over ('data','expert') or a renamed axis)."""
+    x32 = xb.astype(jnp.float32)
+    moments = jnp.stack([jnp.mean(x32, axis=axes),
+                         jnp.mean(jnp.square(x32), axis=axes)])
+    paxes = _batch_axes()
     if paxes:
-        mean = jax.lax.pmean(mean, paxes)
-    var = jnp.mean(jnp.square(xb - jnp.expand_dims(mean, axes)), axis=axes)
-    if paxes:
-        var = jax.lax.pmean(var, paxes)
-    return mean, var
+        moments = jax.lax.pmean(moments, paxes)
+    mean, mean_sq = moments
+    return mean, jnp.maximum(mean_sq - jnp.square(mean), 0.0)
 
 
 class _BatchNorm2d(Operator):
-    """Training-mode BN over batch stats; grads for (x, scale, bias)."""
+    """Training-mode BN over batch stats; grads for (x, scale, bias).
+
+    Forward and backward are both written in closed form, so a training
+    step passes over the activation as rarely as BN's mathematics
+    allows: one read for the statistics, one elementwise pass for y,
+    one read of (x, dy) for the backward's two sums, one elementwise
+    pass for dx. (The vjp of a mean-then-deviation forward costs two
+    dependent reductions each way: autodiff does not know Σ(x − μ) = 0.)
+    After ``forward`` the op holds ``batch_mean`` / ``batch_var`` for
+    the wrapper's running-stat update.
+    """
 
     def __init__(self, handle: BatchNormHandle):
         super().__init__()
@@ -89,15 +119,47 @@ class _BatchNorm2d(Operator):
 
     def forward(self, x, scale, bias):
         h = self.handle
-        axes = h._axes(x.ndim)
-        mean, var = _global_moments(x, axes)
         bshape = h._bshape(x.ndim)
-        inv = jax.lax.rsqrt(var + h.eps).reshape(bshape)
-        y = (x - mean.reshape(bshape)) * inv * scale.reshape(bshape) \
-            + bias.reshape(bshape)
+        mean, var = _global_moments(x, h._axes(x.ndim))
+        inv = jax.lax.rsqrt(var + h.eps)
+        self.batch_mean, self.batch_var = mean, var
+        # residuals: x in its own dtype and per-channel f32 vectors — no
+        # activation-sized f32 copy is kept for backward
+        self._saved = (x, scale, bias, inv)
         # stats/params stay f32 for stability; activations keep the
         # input's precision class (bf16 nets must not upcast here)
+        a = scale.astype(jnp.float32) * inv
+        b = bias.astype(jnp.float32) - mean * a
+        y = x.astype(jnp.float32) * a.reshape(bshape) + b.reshape(bshape)
         return y.astype(x.dtype)
+
+    def backward(self, dy):
+        """dβ = Σdy, dγ = Σdy·x̂, dx = γ·inv·(dy − dβ/N − x̂·dγ/N): the
+        math of cudnnBatchNormalizationBackward, one level of
+        reductions. Under sync-BN the sums inside dx are the GLOBAL
+        batch's (one stacked psum) while the returned dγ, dβ stay this
+        shard's — what the vjp of the pmean'd forward gave; the
+        optimizer's all-reduce sums them afterwards."""
+        h = self.handle
+        x, scale, bias, inv = self._saved
+        mean = self.batch_mean
+        axes, bshape = h._axes(x.ndim), h._bshape(x.ndim)
+        dy32 = dy.astype(jnp.float32)
+        xhat = (x.astype(jnp.float32) - mean.reshape(bshape)) \
+            * inv.reshape(bshape)
+        sums = jnp.stack([jnp.sum(dy32, axis=axes),
+                          jnp.sum(dy32 * xhat, axis=axes)])
+        dbias, dscale = sums
+        count = x.size // h.channels
+        paxes = _batch_axes()
+        if paxes:
+            sums = jax.lax.psum(sums, paxes)
+            count *= jax.lax.psum(1, paxes)
+        m1, m2 = (sums / count).reshape((2,) + bshape)
+        k = (scale.astype(jnp.float32) * inv).reshape(bshape)
+        dx = k * (dy32 - m1 - xhat * m2)
+        return (dx.astype(x.dtype), dscale.astype(scale.dtype),
+                dbias.astype(bias.dtype))
 
 
 class _BatchNorm2dInference(Operator):
@@ -129,23 +191,8 @@ def batchnorm_2d(handle: BatchNormHandle, x, scale, bias,
     mutation semantics. ``freeze_stats`` forces the frozen-stats inference
     path even in training (caffe's use_global_stats).
     """
-    if is_training() and not freeze_stats:
-        h = handle
-        axes = h._axes(x.ndim)
-        xb = x.data if isinstance(x, Tensor) else x
-        batch_mean, batch_var = _global_moments(xb, axes)
-        m = h.factor
-        # running stats keep their own (f32) dtype under EVERY precision
-        # mode — _global_moments already accumulates f32, and the astype
-        # pins the threaded state's dtype so a precision policy (or a
-        # stat tensor restored from an older checkpoint) can never flip
-        # it mid-training and break step donation
-        running_mean.data = (m * running_mean.data.astype(jnp.float32)
-                             + (1 - m) * batch_mean
-                             ).astype(running_mean.data.dtype)
-        running_var.data = (m * running_var.data.astype(jnp.float32)
-                            + (1 - m) * batch_var
-                            ).astype(running_var.data.dtype)
+    training = is_training() and not freeze_stats
+    if training:
         op, args = _BatchNorm2d(handle), (x, scale, bias)
     else:
         op, args = _BatchNorm2dInference(handle), \
@@ -153,7 +200,19 @@ def batchnorm_2d(handle: BatchNormHandle, x, scale, bias,
     # keep references for ONNX export (BatchNormalization's mean/var inputs)
     op.running_mean, op.running_var = running_mean, running_var
     out = op(*args)
-    if isinstance(op, _BatchNorm2dInference) and not handle.is_2d:
+    if training:
+        # the moments the op normalised by, computed once: running stats
+        # keep their own (f32) dtype under EVERY precision mode — the
+        # moments are accumulated f32, and the astype pins the threaded
+        # state's dtype so a precision policy (or a stat tensor restored
+        # from an older checkpoint) can never flip it mid-training and
+        # break step donation
+        m = handle.factor
+        for running, batch in ((running_mean, op.batch_mean),
+                               (running_var, op.batch_var)):
+            running.data = (m * running.data.astype(jnp.float32)
+                            + (1 - m) * batch).astype(running.data.dtype)
+    if not training and not handle.is_2d:
         # tag the frozen-stats output with its folding ingredients: a
         # ReLU consuming it may fuse the whole scale/shift+relu epilogue
         # into one pass over the conv output (ops/fused_epilogue.py —

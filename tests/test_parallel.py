@@ -506,65 +506,41 @@ class TestSyncBatchNorm:
     numerically identical to a single-device full-batch run (the sound SPMD
     form of reference batchnorm.h:103-115 in-place running stats)."""
 
-    def _train(self, distributed, steps=4):
+    def _train(self, distributed, batch_axes=1, steps=4):
+        """Four steps of BNModel; sharded over 'data', or with
+        ``batch_axes=2`` over ('data','expert'). Returns the model."""
         dev = device.create_cpu_device()
         dev.SetRandSeed(9)
         rng = np.random.RandomState(3)
         x = rng.randn(16, 3, 8, 8).astype(np.float32)
         y = np.eye(4, dtype=np.float32)[rng.randint(0, 4, 16)]
         m = BNModel()
-        if distributed:
+        if not distributed:
+            m.set_optimizer(opt.SGD(lr=0.1))
+        elif batch_axes == 1:
             d = opt.DistOpt(opt.SGD(lr=0.1))
             d.communicator.mesh = mesh_mod.make_mesh(
                 jax.devices("cpu"), mesh_mod.MeshConfig())
             m.set_optimizer(d)
         else:
-            m.set_optimizer(opt.SGD(lr=0.1))
+            d = opt.DistOpt(opt.SGD(lr=0.1),
+                            reduce_axes=("data", "expert"))
+            d.communicator.mesh = mesh_mod.make_mesh(
+                jax.devices("cpu"), mesh_mod.MeshConfig(expert=2))
+            m.set_optimizer(d)
+            m.input_specs = [P(("data", "expert")),
+                             P(("data", "expert"))]
         tx = Tensor(data=x, device=dev, requires_grad=False)
         ty = Tensor(data=y, device=dev, requires_grad=False)
         m.compile([tx], is_train=True, use_graph=True)
-        losses = [float(np.asarray(m(tx, ty)[1].data))
-                  for _ in range(steps)]
-        rmean = np.asarray(jax.device_get(m.bn.running_mean.data))
-        rvar = np.asarray(jax.device_get(m.bn.running_var.data))
-        return losses, rmean, rvar
+        m.losses = [float(np.asarray(m(tx, ty)[1].data))
+                    for _ in range(steps)]
+        return m
 
-    def test_dp_bn_matches_single_device(self):
-        dl, dmean, dvar = self._train(True)
-        sl, smean, svar = self._train(False)
-        np.testing.assert_allclose(dl, sl, rtol=1e-4)
-        np.testing.assert_allclose(dmean, smean, rtol=1e-4, atol=1e-6)
-        np.testing.assert_allclose(dvar, svar, rtol=1e-4, atol=1e-6)
-
-    def test_bn_batch_sharded_over_two_axes(self):
-        """VERDICT r2 weak #4: the batch sharded over ('data','expert')
-        must still produce GLOBAL statistics — the reduce axes come from
-        the step's input specs, not a hardcoded 'data'."""
-        dev = device.create_cpu_device()
-        dev.SetRandSeed(9)
-        rng = np.random.RandomState(3)
-        x = rng.randn(16, 3, 8, 8).astype(np.float32)
-        y = np.eye(4, dtype=np.float32)[rng.randint(0, 4, 16)]
-
+    def _assert_stats_match(self, batch_axes):
         def run(distributed):
-            dev.SetRandSeed(9)
-            m = BNModel()
-            if distributed:
-                d = opt.DistOpt(opt.SGD(lr=0.1),
-                                reduce_axes=("data", "expert"))
-                d.communicator.mesh = mesh_mod.make_mesh(
-                    jax.devices("cpu"), mesh_mod.MeshConfig(expert=2))
-                m.set_optimizer(d)
-                m.input_specs = [P(("data", "expert")),
-                                 P(("data", "expert"))]
-            else:
-                m.set_optimizer(opt.SGD(lr=0.1))
-            tx = Tensor(data=x, device=dev, requires_grad=False)
-            ty = Tensor(data=y, device=dev, requires_grad=False)
-            m.compile([tx], is_train=True, use_graph=True)
-            losses = [float(np.asarray(m(tx, ty)[1].data))
-                      for _ in range(4)]
-            return (losses,
+            m = self._train(distributed, batch_axes)
+            return (m.losses,
                     np.asarray(jax.device_get(m.bn.running_mean.data)),
                     np.asarray(jax.device_get(m.bn.running_var.data)))
 
@@ -573,6 +549,32 @@ class TestSyncBatchNorm:
         np.testing.assert_allclose(dl, sl, rtol=1e-4)
         np.testing.assert_allclose(dmean, smean, rtol=1e-4, atol=1e-6)
         np.testing.assert_allclose(dvar, svar, rtol=1e-4, atol=1e-6)
+
+    def test_dp_bn_matches_single_device(self):
+        self._assert_stats_match(batch_axes=1)
+
+    def test_bn_batch_sharded_over_two_axes(self):
+        """VERDICT r2 weak #4: the batch sharded over ('data','expert')
+        must still produce GLOBAL statistics — the reduce axes come from
+        the step's input specs, not a hardcoded 'data'."""
+        self._assert_stats_match(batch_axes=2)
+
+    @pytest.mark.parametrize("batch_axes", [1, 2])
+    def test_sync_bn_backward_matches_single_device(self, batch_axes):
+        """The closed-form backward under sync-BN: dx needs the GLOBAL
+        Σdy and Σdy·x̂ (one stacked psum) while dγ, dβ stay the shard's
+        for the optimizer's all-reduce. The conv weight (fed by dx) and
+        the BN scale after 4 steps must be the single-device run's."""
+        sharded = self._train(True, batch_axes)
+        single = self._train(False, batch_axes)
+        for lyr, attr in (("conv", "W"), ("bn", "scale"), ("bn", "bias")):
+            got, ref = (np.asarray(jax.device_get(
+                getattr(getattr(m, lyr), attr).data))
+                for m in (sharded, single))
+            np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-6,
+                                       err_msg=f"{lyr}.{attr}")
+        # not vacuous: four steps moved the bias off its init of 0
+        assert np.abs(ref).max() > 1e-3
 
 
 class TestPipelineModel:
