@@ -57,6 +57,15 @@ Phases (each prints one JSON line with platform, device_kind, n_devices):
                   scan and plain masked attention over whole sequences);
                   its decode program holds ring_decode once a ring and
                   the read-only ring_attend once a cross layer.
+- latent_serve    at toy widths but the real latent row (512 + 64 numbers
+                  a token a block, kept once for all heads), in bf16: the
+                  shortcut-connected LM (models/longcat_flash.py: two
+                  latent-attention blocks, two dense FFNs and one expert
+                  layer with identity experts a layer) served with greedy
+                  requests, prefill in the attention's expanded form and
+                  decode in its absorbed form, against its own eval
+                  forward (expanded throughout); its decode program holds
+                  the latent_decode Mosaic call once a level.
 - multichip       with >= 4 devices: ResNet-50 through DistOpt (the
                   shard_map driver) and through the GSPMD step with FSDP,
                   the LM at dp2 x tp2; state on four devices, FSDP bytes
@@ -104,6 +113,15 @@ HYBRID_TOY = {"hidden": 256, "heads": 4, "kv_heads": 4, "ff": 512,
               "window": 128, "layers": 8, "vocab": 512, "slots": 4,
               "max_len": 256, "prefill_len": 160, "new_tokens": 24}
 
+# two double layers over the REAL latent row (kv_lora_rank 512 + 64 rotary
+# columns: the kernel's latent form as the benchmark's cell runs it), every
+# other width small; 4 of 16 routed experts held, 8 identity experts
+LATENT_TOY = {"hidden": 128, "heads": 4, "q_rank": 64, "kv_rank": 512,
+              "nope": 32, "rope": 64, "v": 32, "ff": 256, "expert_ff": 128,
+              "held": 4, "held_from": 4, "router": 24, "zero": 8,
+              "top_k": 4, "layers": 2, "vocab": 512, "slots": 4,
+              "max_len": 256, "prefill_len": 160, "new_tokens": 24}
+
 FULL = {
     "resnet": {"depth": 50, "batch": 32, "image": 224, "steps": 5,
                "timed_steps": 20},
@@ -119,6 +137,7 @@ FULL = {
               "new_tokens": 12, "ref_len": 128},
     "moe": MOE_TOY,
     "hybrid": HYBRID_TOY,
+    "latent": LATENT_TOY,
 }
 # --dry-run: the same control flow where a CPU can finish it. 224 px stays
 # because the ResNet's 7x7 average pool needs the 7x7 final feature map.
@@ -136,6 +155,7 @@ DRY = {
               "new_tokens": 4, "ref_len": 128},
     "moe": MOE_TOY,
     "hybrid": HYBRID_TOY,
+    "latent": LATENT_TOY,
 }
 
 
@@ -194,14 +214,15 @@ def _compiled_for_chip(ctx, jitted, args, what, n=1):
     return compiled
 
 
-def _ring_kernel_in_decode(ctx, eng, attends=0):
+def _ring_kernel_in_decode(ctx, eng, attends=0, kernel="ring_decode"):
     """The compiled decode program of a ring engine holds the
-    ``ring_decode`` Mosaic call once a ring level, the read-only
-    ``ring_attend`` once a layer that reads another's ring (``attends``)
-    and no ``dynamic-update-slice`` of a level's size: the level is
-    walked and written by the kernel, in place. Returns the count of
-    ``ring_decode`` calls. (The dry run interprets the kernel; nothing
-    is compiled for a chip.)"""
+    ``ring_decode`` Mosaic call (``kernel``: ``latent_decode`` for
+    latent levels) once a ring level, the read-only ``ring_attend`` once
+    a layer that reads another's ring (``attends``) and no
+    ``dynamic-update-slice`` of a level's size: the level is walked and
+    written by the kernel, in place. Returns the count of the kernel's
+    calls. (The dry run interprets the kernel; nothing is compiled for a
+    chip.)"""
     if ctx.dry:
         return None
     from singa_tpu.aot import export as aot_export
@@ -210,9 +231,9 @@ def _ring_kernel_in_decode(ctx, eng, attends=0):
     rings = [lv for lv in eng._cache if "k" in lv]
     count = lambda name: len(re.findall(                       # noqa: E731
         rf"^\s*%?{name}[.\d]* = .*custom-call\(", hlo, re.M))
-    calls = count("ring_decode")
+    calls = count(kernel)
     assert calls == len(rings), \
-        f"{calls} ring_decode calls in the decode program for " \
+        f"{calls} {kernel} calls in the decode program for " \
         f"{len(rings)} ring levels"
     assert count("ring_attend") == attends, \
         f"{count('ring_attend')} ring_attend calls for {attends} layers " \
@@ -939,6 +960,100 @@ def phase_hybrid_serve(ctx):
 
 
 # ---------------------------------------------------------------------------
+# latent_serve
+# ---------------------------------------------------------------------------
+
+def phase_latent_serve(ctx):
+    """What PR 33 added to the serving path, at toy widths around the
+    real latent row, in bf16: a shortcut-connected LM with latent
+    attention served greedily — prefill in the expanded form, decode in
+    the absorbed form over latent ring levels — each served token read
+    in the model's own eval forward (expanded attention, no cache)."""
+    import jax.numpy as jnp
+    from singa_tpu.models.longcat_flash import LongCatFlashLM
+    from singa_tpu.observability import metrics as obs_metrics
+    from singa_tpu.observability import spans
+    from singa_tpu.serving import kv_cache
+    c = ctx.sizes["latent"]
+    m = LongCatFlashLM(
+        c["vocab"], hidden_size=c["hidden"], num_layers=c["layers"],
+        num_heads=c["heads"], q_lora_rank=c["q_rank"],
+        kv_lora_rank=c["kv_rank"], qk_nope_head_dim=c["nope"],
+        qk_rope_head_dim=c["rope"], v_head_dim=c["v"],
+        ffn_hidden_size=c["ff"], expert_ffn_hidden_size=c["expert_ff"],
+        num_experts=c["held"], router_width=c["router"],
+        zero_expert_num=c["zero"], top_k=c["top_k"],
+        experts_held_from=c["held_from"], init_std=0.08,
+        router_bias_std=0.01)
+    ids = _put(ctx, jnp.zeros((1, c["max_len"]), jnp.float32))
+    m.compile([ids], is_train=False, use_graph=True, policy="bfloat16")
+    m.eval()
+    reg = obs_metrics.MetricsRegistry()
+    eng = m.compile_serving(slots=c["slots"], max_len=c["max_len"],
+                            prefill_len=c["prefill_len"], prefill_batch=1,
+                            policy="bfloat16", registry=reg)
+    levels = 2 * c["layers"]
+    assert eng._layout.adapter.cache_kinds() == ["latent"] * levels
+    assert all(isinstance(lv, kv_cache.LatentLevel)
+               and lv["k"].dtype == jnp.bfloat16 for lv in eng._cache)
+    # one row a token a block, for every head: 576 numbers of 2 bytes
+    # (stored in whole lane tiles: 640)
+    row_bytes = sum(lv.width * lv["k"].dtype.itemsize for lv in eng._cache)
+    assert row_bytes == 1152 * levels, row_bytes
+    stored = reg.get("serve_kv_bytes").value(kind="latent")
+    assert stored == c["slots"] * c["max_len"] * 1280 * levels, stored
+    rng = np.random.RandomState(SEED + 13)
+    prompts = [rng.randint(1, c["vocab"], (n,))
+               for n in (c["prefill_len"], 5, 131, 11, 40)]
+    futs = [eng.submit(p, max_new_tokens=c["new_tokens"]) for p in prompts]
+    eng.run_until_idle()
+    served = [f.result(timeout=5)["tokens"] for f in futs]
+    info = eng.compiled_step_info()
+    out = {"latent_decode_calls": _ring_kernel_in_decode(
+               ctx, eng, kernel="latent_decode"),
+           "logits_readbacks": _logits_readbacks(reg)}
+    eng.stop()
+    assert info["n_traces"] == 1 and info["kv_layout"] == "ring", info
+    pairs = {h: int(reg.get("moe_pairs_total").value(held=h))
+             for h in ("here", "absent", "zero")}
+    rows = sum(len(p) + c["new_tokens"] - 1 for p in prompts)
+    assert sum(pairs.values()) == rows * c["top_k"] * c["layers"], pairs
+    assert pairs["zero"] > 0, pairs
+    ticks = [r for r in spans.recorder().records()
+             if r.get("name") in ("serve.decode", "serve.prefill")
+             and "pairs_zero" in r]
+    assert sum(r["pairs_zero"] for r in ticks) >= pairs["zero"]
+    seqs = np.zeros((len(prompts), c["max_len"]), np.float32)
+    for r, (p, toks) in enumerate(zip(prompts, served)):
+        assert len(toks) == c["new_tokens"], toks
+        seqs[r, :len(p) + len(toks)] = np.concatenate([p, toks])
+    logits = np.asarray(m(_put(ctx, seqs)).data, np.float32)
+    assert np.isfinite(logits).all(), "non-finite logits"
+    gaps = np.asarray([logits[r, len(p) - 1 + j].max()
+                       - logits[r, len(p) - 1 + j, tok]
+                       for r, (p, toks) in enumerate(zip(prompts, served))
+                       for j, tok in enumerate(toks)])
+    # both sides in bf16 by different routes (absorbed attention over the
+    # cached rows, with the query's up-projection rounded to bf16, against
+    # expanded attention over whole sequences), and a rounding that swaps
+    # a token's last pick of experts moves that one token by about the
+    # spread of the logits, so the MEAN gap decides (as in moe_serve and
+    # in the benchmark's cell)
+    spread = float(logits.std())
+    worst, mean = float(gaps.max()), float(gaps.mean())
+    assert mean < 0.03 * spread, \
+        f"served tokens trail the eval forward by {mean} on average " \
+        f"(at most {worst}) of {spread}"
+    out.update(
+        max_logit_gap_vs_eval=round(worst, 4),
+        mean_logit_gap_vs_eval=round(mean, 5), logit_std=round(spread, 2),
+        longest_context=max(len(p) for p in prompts) + c["new_tokens"],
+        latent_bytes_a_token=row_bytes, pairs_here=pairs["here"],
+        pairs_absent=pairs["absent"], pairs_zero=pairs["zero"])
+    return out
+
+
+# ---------------------------------------------------------------------------
 # multichip
 # ---------------------------------------------------------------------------
 
@@ -1098,6 +1213,7 @@ PHASES = (("device", phase_device),
           ("serve", phase_serve),
           ("moe_serve", phase_moe_serve),
           ("hybrid_serve", phase_hybrid_serve),
+          ("latent_serve", phase_latent_serve),
           ("multichip", phase_multichip),
           ("cache", phase_cache))
 
@@ -1107,7 +1223,14 @@ def main(argv=None):
     ap.add_argument("--dry-run", action="store_true",
                     help="sandbox debugging: tiny sizes, Pallas "
                          "interpreted, no TPU needed; not a result")
+    ap.add_argument("--only", default="",
+                    help="comma-separated phases to run after `device` "
+                         "(debugging one phase; the check is the whole run)")
     args = ap.parse_args(argv)
+    only = set(filter(None, args.only.split(","))) | {"device"}
+    unknown = only - {name for name, _ in PHASES}
+    if unknown:
+        ap.error(f"unknown phases {sorted(unknown)}")
     t0 = time.perf_counter()
 
     import jax
@@ -1144,7 +1267,8 @@ def main(argv=None):
 
     ctx = Ctx(args.dry_run)
     for name, fn in PHASES:
-        run_phase(name, fn, ctx)
+        if not args.only or name in only:
+            run_phase(name, fn, ctx)
 
     wall = round(time.perf_counter() - t0, 1)
     if args.dry_run:

@@ -107,7 +107,8 @@ PREFILL_ROWS = 512
 def masked_attention(q, k, v, scale, window=None, n_blocks=None):
     """Causal attention of grouped heads over whole sequences, queries in
     blocks of ``PREFILL_ROWS`` so that the scores of S = 4096 never exist
-    for all rows at once. ``q``: (B, S, Hq, D); ``k``/``v``: (B, S, Hkv, D);
+    for all rows at once. ``q``/``k``: (B, S, Hq | Hkv, D); ``v``:
+    (B, S, Hkv, Dv), as wide as the keys or not; returns (B, S, Hq, Dv);
     ``window``: keep ``i - j < window`` besides ``j <= i``; ``n_blocks``
     (a device scalar): only the first ``n_blocks`` query blocks hold a
     token, the rest come out nought. Operands in their own dtype, sums
@@ -115,7 +116,7 @@ def masked_attention(q, k, v, scale, window=None, n_blocks=None):
     import jax
     import jax.numpy as jnp
     B, S, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     block_q = PREFILL_ROWS
     qg = q.reshape(B, S, Hkv, Hq // Hkv, D)
     cols = jnp.arange(S)
@@ -132,7 +133,7 @@ def masked_attention(q, k, v, scale, window=None, n_blocks=None):
                           preferred_element_type=jnp.float32).astype(q.dtype)
 
     if S <= block_q or S % block_q:
-        return rows_of(qg, 0).reshape(q.shape)
+        return rows_of(qg, 0).reshape(B, S, Hq, Dv)
     n = S // block_q
     blocks = qg.reshape(B, n, block_q, Hkv, Hq // Hkv, D).swapaxes(0, 1)
     if n_blocks is None:
@@ -142,8 +143,8 @@ def masked_attention(q, k, v, scale, window=None, n_blocks=None):
         out = jax.lax.fori_loop(
             0, n_blocks,
             lambda i, acc: acc.at[i].set(rows_of(blocks[i], i * block_q)),
-            jnp.zeros(blocks.shape, q.dtype))
-    return out.swapaxes(0, 1).reshape(q.shape)
+            jnp.zeros(blocks.shape[:-1] + (Dv,), q.dtype))
+    return out.swapaxes(0, 1).reshape(B, S, Hq, Dv)
 
 
 def block_apply(cfg, kind, p, x, positions, attend, rows=None, blocks=None):
@@ -439,6 +440,39 @@ class ByReferenceAdapter:
 
 
 
+def moe_stats_recorder(registry, names):
+    """The expert layer's counters in the engine's registry, and the
+    function the engine hands each program call's counts (the array
+    ``names`` beside the logits: ``pairs_here``, ``pairs_absent``,
+    ``experts_touched``, and ``pairs_zero`` where the layer has experts
+    without weights): it feeds the counters and returns the attrs of
+    the call's span."""
+    pairs = registry.counter(
+        "moe_pairs_total", "(token, expert) picks the router made for "
+        "real tokens, by where the expert is (here: computed on this "
+        "chip; absent: add nothing here; zero: an expert without "
+        "weights, its pick adds the token's own row)", labels=("held",))
+    touched = registry.counter(
+        "moe_experts_touched_total", "held experts that got any pair, "
+        "summed over layers and program calls (each one's matrices had "
+        "to be read)", labels=("program",))
+    calls = registry.counter(
+        "moe_calls_total", "program calls the expert counts were read "
+        "from", labels=("program",))
+
+    def record(program, stats):
+        st = dict(zip(names, (int(v) for v in stats)))
+        for held in ("here", "absent", "zero"):
+            if f"pairs_{held}" in st:
+                pairs.inc(st[f"pairs_{held}"], held=held)
+        touched.inc(st["experts_touched"], program=program)
+        calls.inc(program=program)
+        return {k: st[k] for k in ("pairs_here", "pairs_zero",
+                                   "experts_touched") if k in st}
+
+    return record
+
+
 class _ServeAdapter(ByReferenceAdapter):
     """What ``ServingEngine`` needs of the model (docs/serving.md, "The
     adapter contract"): the model's own arrays by reference, per-layer
@@ -449,31 +483,7 @@ class _ServeAdapter(ByReferenceAdapter):
     (:meth:`stats_recorder`)."""
 
     def stats_recorder(self, registry):
-        """The expert layer's counters in the engine's registry, and the
-        function the engine hands each program call's counts (the
-        ``STAT_NAMES`` array beside the logits): it feeds the counters
-        and returns the attrs of the call's span."""
-        pairs = registry.counter(
-            "moe_pairs_total", "(token, expert) picks the router made for "
-            "real tokens, by whether the expert lives on this chip (here: "
-            "computed; absent: add nothing here)", labels=("held",))
-        touched = registry.counter(
-            "moe_experts_touched_total", "held experts that got any pair, "
-            "summed over layers and program calls (each one's matrices had "
-            "to be read)", labels=("program",))
-        calls = registry.counter(
-            "moe_calls_total", "program calls the expert counts were read "
-            "from", labels=("program",))
-
-        def record(program, stats):
-            here, absent, hit = (int(v) for v in stats)
-            pairs.inc(here, held="here")
-            pairs.inc(absent, held="absent")
-            touched.inc(hit, program=program)
-            calls.inc(program=program)
-            return {"pairs_here": here, "experts_touched": hit}
-
-        return record
+        return moe_stats_recorder(registry, STAT_NAMES)
 
     def ring_lengths(self, max_len):
         c = self.cfg
@@ -561,4 +571,4 @@ class _ServeAdapter(ByReferenceAdapter):
 
 __all__ = ["CohereMoELM", "CohereMoEBlock", "Config", "block_apply",
            "forward_logits", "create_model", "DrawnBeforeCompile",
-           "ByReferenceAdapter", "EvalForward"]
+           "ByReferenceAdapter", "EvalForward", "moe_stats_recorder"]

@@ -32,6 +32,13 @@ walk read-only (:func:`ring_attend`, ``kv_cache.attend_token``): no new
 row, nothing written, the level no output of the call, so every such
 reader shares the owner's buffer.
 
+A LATENT level (``kv_cache.LatentLevel``: one array a token, ``(W, 1, L,
+P)``, scored over the row's whole width, the values its first columns)
+takes the same walk in a latent form (:func:`latent_decode`): one streamed
+operand instead of two, every query head a row of one matrix product
+against it, the value product on a slice of the block already in VMEM,
+one tile written back.
+
 The kernel's text does not depend on ``L`` (blocks are a grid axis) nor
 on ``G`` (one matrix product), and the call sits in a jitted wrapper with
 static arguments, so a model lowers it once a distinct
@@ -112,7 +119,7 @@ def kernel_block(n_kv, length, D):
 
 def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
                         q_ref, *refs, scale, block, length, tile, cols,
-                        write):
+                        write, vcols=None):
     """One (slot, block) pair of the walk, one group of KV heads.
     ``write`` false is the read-only pass (:func:`ring_attend`): no new
     row, no output but the attention's, the level is only read.
@@ -124,19 +131,26 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
     queries in that head's columns and zeros elsewhere, so that one
     product scores every head against its own keys; the value product
     then holds every head's values in every row, and the wrapper reads
-    each row's own head out of it."""
-    if write:
-        (kn_ref, vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
-         acc_ref, m_ref, l_ref, sem) = refs
-    else:
-        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    each row's own head out of it.
+
+    ``vcols`` (the latent form, :func:`latent_decode`): the level is ONE
+    row-major array a token; the scores take the row's whole width and
+    the values are its first ``vcols`` columns, so the block is fetched
+    once for both products and one tile is written back."""
+    n = 2 if vcols is None else 1       # arrays a level holds
+    new_refs, refs = (refs[:n], refs[n:]) if write else ((), refs)
+    lvl_refs, o_ref, refs = refs[:n], refs[n], refs[n + 1:]
+    out_refs, refs = (refs[:n], refs[n:]) if write else ((), refs)
+    acc_ref, m_ref, l_ref, *sem = refs
+    k_ref = lvl_refs[0]
     hg = pl.program_id(0)
     s = pl.program_id(1)
     w = slot_ref[s]
     j = blk_ref[s]
     p = pos_ref[w]
     r = p % length                      # ring index of the new row
-    M, K = acc_ref.shape
+    M, A = acc_ref.shape                # A: columns of the value product
+    K = k_ref.shape[-2] if cols else k_ref.shape[-1]
     writes = j == r // block            # this block takes the new row
     live = s < total_ref[0]
     # the tile of the block that takes the new row: `tile` rows of it,
@@ -151,11 +165,9 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
         lead = (0, 0)
         here = (pl.ds(base, tile), slice(None))
         there = (w, hg, pl.ds(j * block + base, tile), slice(None))
-    copies = () if not write else (
-        pltpu.make_async_copy(k_ref.at[lead + here], ko_ref.at[there],
-                              sem.at[0]),
-        pltpu.make_async_copy(v_ref.at[lead + here], vo_ref.at[there],
-                              sem.at[1]))
+    copies = [pltpu.make_async_copy(ref.at[lead + here], out.at[there],
+                                    sem[0].at[i])
+              for i, (ref, out) in enumerate(zip(lvl_refs, out_refs))]
 
     def _write():
         # merged into the fetched block, which the walk below then reads
@@ -175,7 +187,7 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
                 if k_ref.dtype == jnp.float32 else None
         else:
             mask = lax.broadcasted_iota(jnp.int32, (tile, K), 0) == at
-        for ref, new_ref in ((k_ref, kn_ref), (v_ref, vn_ref)):
+        for ref, new_ref in zip(lvl_refs, new_refs):
             if not cols:
                 ref[lead + here] = jnp.where(
                     mask, new_ref[lead], ref[lead + here].astype(
@@ -204,7 +216,8 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
     @pl.when(live)
     def _walk():
         col = j * block + lax.broadcasted_iota(jnp.int32, (M, block), 1)
-        v = v_ref[lead]
+        v = lvl_refs[1][lead] if vcols is None \
+            else k_ref[lead + (slice(None), slice(0, vcols))]
         sc = _mxu(q_ref[0, 0], k_ref[lead], _NN if cols else _NT)
         sc = jnp.where(col <= p, sc * scale, _NEG_INF)
         m = m_ref[...]
@@ -213,13 +226,13 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
         alpha = jnp.exp(m - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1,
                                                   keepdims=True)
-        acc_ref[...] = acc_ref[...] * _across(alpha, K) + _mxu(
+        acc_ref[...] = acc_ref[...] * _across(alpha, A) + _mxu(
             pr.astype(v.dtype), v, _NT if cols else _NN)
         m_ref[...] = m_new
 
     @pl.when(jnp.logical_and(live, j == walk_ref[w] - 1))
     def _out():
-        o_ref[0, 0] = (acc_ref[...] / _across(l_ref[...], K)).astype(
+        o_ref[0, 0] = (acc_ref[...] / _across(l_ref[...], A)).astype(
             o_ref.dtype)
 
     if write:
@@ -227,6 +240,28 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
         def _written():
             for c in copies:
                 c.wait()
+
+
+def _walk_list(pos, active, L, block):
+    """The (slot, block) pairs a tick walks, in order: ``(total (1,),
+    slot (steps,), blk (steps,), walk (W,))``, the grid ending with the
+    last of the ``total`` pairs."""
+    W, nb = pos.shape[0], L // block
+    walk = live_blocks(pos, active, L, block)
+    ends = jnp.cumsum(walk)
+    step = jnp.arange(W * nb, dtype=jnp.int32)
+    past = step[:, None] >= ends[None, :]       # (steps, W); no gather
+    real = step < ends[-1]
+    slot = jnp.where(real, jnp.sum(past, axis=1, dtype=jnp.int32), 0)
+    blk = jnp.where(real, step - jnp.sum(jnp.where(past, walk, 0), axis=1),
+                    0)
+    return ends[-1:], slot, blk, walk
+
+
+def _by_walk(shape, index):
+    """A block chosen by the walk's step: ``index(slot, group, block)``."""
+    return pl.BlockSpec(shape, lambda g, s, t, sl, bl, ps, wk:
+                        index(sl[s], g, bl[s]))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
@@ -245,22 +280,8 @@ def _ring_decode_call(q, k_new, v_new, k, v, pos, active, *, scale, block,
     M = -(-hb * G // rows) * rows
     K = hb * D
 
-    # the (slot, block) pairs the tick walks, in order; the grid ends
-    # with the last of them
     pos = pos.astype(jnp.int32)
-    walk = live_blocks(pos, active, L, block)
-    ends = jnp.cumsum(walk)
-    step = jnp.arange(W * nb, dtype=jnp.int32)
-    past = step[:, None] >= ends[None, :]       # (steps, W); no gather
-    real = step < ends[-1]
-    slot = jnp.where(real, jnp.sum(past, axis=1, dtype=jnp.int32), 0)
-    blk = jnp.where(real, step - jnp.sum(jnp.where(past, walk, 0), axis=1),
-                    0)
-    total = ends[-1:]
-
-    def spec(shape, index):
-        return pl.BlockSpec(shape, lambda g, s, t, sl, bl, ps, wk:
-                            index(sl[s], g, bl[s]))
+    total, slot, blk, walk = _walk_list(pos, active, L, block)
 
     # a group's queries, each head's in its own D columns of K
     qg = q.reshape(W, groups, hb, G, 1, D).astype(dtype)
@@ -272,14 +293,14 @@ def _ring_decode_call(q, k_new, v_new, k, v, pos, active, *, scale, block,
     if cols:
         k, v = (a.swapaxes(2, 3).reshape(W, n_kv * D, L) for a in (k, v))
         new_shape = (W, groups, K // _LANES, _LANES)
-        new_row = spec((1, 1, K // _LANES, _LANES),
+        new_row = _by_walk((1, 1, K // _LANES, _LANES),
                        lambda w, g, b: (w, g, 0, 0))
-        kv_block = spec((1, K, block), lambda w, g, b: (w, g, b))
+        kv_block = _by_walk((1, K, block), lambda w, g, b: (w, g, b))
     else:
         new_shape = (W, n_kv, 1, D)
-        new_row = spec((1, 1, 1, D), lambda w, g, b: (w, g, 0, 0))
-        kv_block = spec((1, 1, block, D), lambda w, g, b: (w, g, b, 0))
-    by_slot = spec((1, 1, M, K), lambda w, g, b: (w, g, 0, 0))
+        new_row = _by_walk((1, 1, 1, D), lambda w, g, b: (w, g, 0, 0))
+        kv_block = _by_walk((1, 1, block, D), lambda w, g, b: (w, g, b, 0))
+    by_slot = _by_walk((1, 1, M, K), lambda w, g, b: (w, g, 0, 0))
     kernel = functools.partial(
         _ring_decode_kernel, scale=scale, block=block, length=L,
         tile=_LANES if cols else rows, cols=cols, write=write)
@@ -346,3 +367,79 @@ def ring_attend(q, k, v, pos, active, scale, block):
     return _ring_decode_call(q, None, None, k, v, pos, active,
                              scale=float(scale), block=int(block),
                              interpret=_interpret())[0]
+
+
+# ---------------------------------------------------------------------------
+# the latent form: one array a token, values a prefix of the keys
+# ---------------------------------------------------------------------------
+
+def latent_block(length, padded):
+    """Rows of a block for a latent level ``length`` rows of ``padded``
+    columns, or None where the kernel has no form for it (no block
+    divides the ring, or the row is not whole lane tiles)."""
+    return None if padded % _LANES else block_rows(length)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block", "value_width", "interpret"))
+def _latent_decode_call(q, row_new, rows, pos, active, *, scale, block,
+                        value_width, interpret):
+    W, _, L, P = rows.shape
+    H, width = q.shape[1], q.shape[-1]
+    dtype = rows.dtype
+    tile = 32 // dtype.itemsize         # rows of one sublane tile
+    M = -(-H // tile) * tile
+    # the value product takes the row's first `value_width` columns
+    # where those are whole lane tiles, else the whole row (the wrapper
+    # then reads the columns out)
+    vcols = value_width if value_width % _LANES == 0 else P
+    pos = pos.astype(jnp.int32)
+    total, slot, blk, walk = _walk_list(pos, active, L, block)
+    qg = jnp.pad(q.reshape(W, 1, H, width).astype(dtype),
+                 ((0, 0), (0, 0), (0, M - H), (0, P - width)))
+    new = jnp.pad(row_new.astype(dtype).astype(jnp.float32),
+                  ((0, 0), (0, P - width))).reshape(W, 1, 1, P)
+    kernel = functools.partial(
+        _ring_decode_kernel, scale=scale, block=block, length=L, tile=tile,
+        cols=False, write=True, vcols=vcols)
+    out, rows = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(1, jnp.maximum(total[0], 1)),
+            in_specs=[
+                _by_walk((1, 1, M, P), lambda w, g, b: (w, g, 0, 0)),
+                _by_walk((1, 1, 1, P), lambda w, g, b: (w, g, 0, 0)),
+                _by_walk((1, 1, block, P), lambda w, g, b: (w, g, b, 0))],
+            out_specs=[
+                _by_walk((1, 1, M, vcols), lambda w, g, b: (w, g, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[
+                pltpu.VMEM((M, vcols), jnp.float32),
+                pltpu.VMEM((M, _LANES), jnp.float32),
+                pltpu.VMEM((M, _LANES), jnp.float32),
+                pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=[jax.ShapeDtypeStruct((W, 1, M, vcols), q.dtype),
+                   jax.ShapeDtypeStruct(rows.shape, dtype)],
+        # operands count the five prefetched arrays: the level is 7
+        input_output_aliases={7: 1},
+        interpret=interpret,
+        name="latent_decode",
+    )(total, slot, blk, pos, walk, qg, new, rows)
+    out = out[:, 0, :H, :value_width].reshape(W, H, 1, value_width)
+    return jnp.where(active[:, None, None, None], out, 0), rows
+
+
+def latent_decode(q, row_new, rows, pos, active, scale, block, value_width):
+    """The latent form of :func:`ring_decode`: write ``row_new``
+    ``(W, width)`` at ring index ``pos % L`` of ``rows`` ``(W, 1, L, P)``
+    (``width`` columns and zeros up to ``P``) and attend ``q``
+    ``(W, H, 1, width)`` over each live slot's ring — scores over the
+    row's whole width, values its first ``value_width`` columns, a block
+    fetched once for all ``H`` query rows and both products. Returns
+    ``(out (W, H, 1, value_width), rows)``; a dead slot's output is zero
+    and its ring is left as it was."""
+    return _latent_decode_call(q, row_new, rows, pos, active,
+                               scale=float(scale), block=int(block),
+                               value_width=int(value_width),
+                               interpret=_interpret())

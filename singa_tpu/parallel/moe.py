@@ -17,6 +17,7 @@ all-to-alls degrade to identity and the same code computes the dense
 
 from __future__ import annotations
 
+import collections
 import math
 
 import jax
@@ -183,18 +184,52 @@ class MoEFFN(Layer):
 # the share-aware expert layer: told which experts it holds
 # ---------------------------------------------------------------------------
 
+class Route(collections.namedtuple("Route", "score renormalise scale")):
+    """A top-k routing rule as data: ``score`` is the function that turns
+    the router's logits into scores (``"sigmoid"`` | ``"softmax"``, over
+    all of the router's columns), ``renormalise`` whether the picks'
+    weights are divided by their sum, ``scale`` a factor on the weights
+    (applied once, after the renormalisation if there is one)."""
+
+    __slots__ = ()
+
+    def __new__(cls, score="sigmoid", renormalise=True, scale=1.0):
+        if score not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown score function {score!r}")
+        return super().__new__(cls, score, bool(renormalise), float(scale))
+
+
+SIGMOID_TOPK = Route()
+
+
+def route_topk(h, router, top_k, route=SIGMOID_TOPK, bias=None):
+    """Scores over ALL of the router's columns in float32, the ``top_k``
+    best, their scores as weights by the rule ``route``: ``(idx (T, k)
+    int32, w (T, k) float32)``. ``bias`` (columns,) is added to the
+    scores for the CHOICE only: the weights are the picks' own scores.
+    The matrix product runs at the highest precision (a TPU's default
+    rounds float32 operands to bf16): the router is hundreds of columns
+    wide, and which expert comes last hangs on the fourth decimal."""
+    z = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(z) if route.score == "sigmoid" \
+        else jax.nn.softmax(z, axis=-1)
+    if bias is None:
+        w, idx = lax.top_k(s, top_k)
+    else:
+        _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+    if route.renormalise:
+        w = w / jnp.sum(w, -1, keepdims=True)
+    if route.scale != 1.0:
+        w = w * route.scale
+    return idx.astype(jnp.int32), w
+
+
 def route_sigmoid_topk(h, router, top_k):
-    """Sigmoid scores over ALL experts in float32, the ``top_k`` best,
-    their scores renormalised over the picks: ``(idx (T, k) int32,
-    w (T, k) float32)``. The matrix product runs at the highest
-    precision (a TPU's default rounds float32 operands to bf16): the
-    router is 128 columns wide, and which expert comes eighth hangs on
-    the fourth decimal."""
-    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
-                               router.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
-    w, idx = lax.top_k(s, top_k)
-    return idx.astype(jnp.int32), w / jnp.sum(w, -1, keepdims=True)
+    """:func:`route_topk` by its default rule: sigmoid scores, the
+    picks' weights renormalised over the picks, no bias, no factor."""
+    return route_topk(h, router, top_k)
 
 
 def _gated(x, w_gate, w_up, w_down):
@@ -283,50 +318,65 @@ SORTED_TILE = 256
 
 
 def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
-                     axis_name=None, blocks=None):
-    """The part of a sigmoid top-k expert layer that ONE share gives.
+                     axis_name=None, blocks=None, route=SIGMOID_TOPK,
+                     n_zero=0):
+    """The part of a top-k expert layer that ONE share gives.
 
-    ``p``: ``router`` (D, E) over all E experts; ``w_gate``/``w_up``
-    (G, D, F) and ``w_down`` (G, F, D), the G experts held here
-    (experts ``held_from .. held_from + G - 1``); ``s_gate``/``s_up``
-    (S, D, F), ``s_down`` (S, F, D), the shared experts, held whole.
+    ``p``: ``router`` (D, E + n_zero) over all E experts and the
+    ``n_zero`` experts without weights behind them; ``router_bias``
+    (E + n_zero,), where present, added to the scores for the choice
+    only; ``w_gate``/``w_up`` (G, D, F) and ``w_down`` (G, F, D), the G
+    experts held here (experts ``held_from .. held_from + G - 1``);
+    ``s_gate``/``s_up`` (S, D, F), ``s_down`` (S, F, D), the shared
+    experts, held whole (absent or S = 0: none).
     ``h``: (T, D) rows in the compute dtype; ``h_route``: the same rows
     in float32 for the router (default ``h``); ``rows``: (T,) bool,
     False for padding (such rows are routed nowhere and counted
     nowhere); ``blocks``: ``(n_blocks, block_rows)`` when only the
     first ``n_blocks`` (a device scalar) blocks of rows hold a token —
     the shared experts then run on those blocks alone
-    (:func:`rows_in_blocks`), as the routed ones do by their pairs.
+    (:func:`rows_in_blocks`), as the routed ones do by their pairs;
+    ``route``: the routing rule (:class:`Route`; default sigmoid
+    scores renormalised over the picks).
 
-    Routes over all E, renormalises the picks' weights over all
-    ``top_k`` of them, computes only the pairs whose expert lives here
-    and drops none; picks that go to absent experts add nothing. No
-    capacity factor. The shared experts' mean is added once. On an
-    active ``axis_name`` every peer holds its own G experts
-    (``held_from`` is then the peer's index times G), all peers see
-    the same rows, and the routed parts are summed over the axis; on
-    one chip the layer runs without the exchange.
+    Routes over all E + n_zero columns, weighs the picks by ``route``
+    over all ``top_k`` of them, computes only the pairs whose expert
+    lives here and drops none; picks that go to absent experts add
+    nothing. A pick ``>= E`` is an identity expert: it adds its weight
+    times the row itself (float32, from the rows the experts read), no
+    matrix and no FLOP, on every share alike. No capacity factor. The
+    shared experts' mean is added once. On an active ``axis_name``
+    every peer holds its own G experts (``held_from`` is then the
+    peer's index times G), all peers see the same rows, the routed
+    parts are summed over the axis, and the identity experts' term is
+    added once, outside that sum; on one chip the layer runs without
+    the exchange.
 
     Returns ``(y (T, D) float32, stats)`` with ``stats`` int32 scalars:
-    ``pairs_here``, ``pairs_absent``, ``experts_touched`` (held experts
-    that got any pair)."""
+    ``pairs_here``, ``pairs_absent``, ``pairs_zero`` (picks of identity
+    experts), ``experts_touched`` (held experts that got any pair);
+    the three kinds of pairs add up to real rows x ``top_k``."""
     T = h.shape[0]
     G = p["w_gate"].shape[0]
+    E = p["router"].shape[1] - n_zero
     if axis_name is not None and active_axis(axis_name):
         held_from = lax.axis_index(axis_name) * G
     with jax.named_scope("moe_route"):
-        idx, w = route_sigmoid_topk(h if h_route is None else h_route,
-                                    p["router"], top_k)
+        idx, w = route_topk(h if h_route is None else h_route,
+                            p["router"], top_k, route,
+                            p.get("router_bias"))
         local = idx - held_from
-        here = (local >= 0) & (local < G)
         real = jnp.ones((T,), bool) if rows is None else rows
-        here = here & real[:, None]
+        here = (local >= 0) & (local < G) & (idx < E) & real[:, None]
         group = jnp.where(here, local, G).reshape(-1)
         counts = jnp.zeros((G + 1,), jnp.int32).at[group].add(1)[:G]
         pairs_here = jnp.sum(counts)
+        zero = (idx >= E) & real[:, None]
+        pairs_zero = jnp.sum(zero.astype(jnp.int32))
         stats = {"pairs_here": pairs_here,
                  "pairs_absent": jnp.sum(real.astype(jnp.int32)) * top_k
-                 - pairs_here,
+                 - pairs_here - pairs_zero,
+                 "pairs_zero": pairs_zero,
                  "experts_touched": jnp.sum((counts > 0).astype(jnp.int32))}
     with jax.named_scope("moe_experts"):
         if T <= DENSE_ROWS:
@@ -337,8 +387,12 @@ def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
                                p["w_down"], min(SORTED_TILE, T))
         if axis_name is not None and active_axis(axis_name):
             y = lax.psum(y, axis_name)
+    if n_zero:
+        with jax.named_scope("moe_zero"):
+            y = y + jnp.sum(jnp.where(zero, w, 0.0), axis=-1,
+                            keepdims=True) * h.astype(jnp.float32)
     with jax.named_scope("moe_shared"):
-        S = p["s_gate"].shape[0]
+        S = p["s_gate"].shape[0] if "s_gate" in p else 0
 
         def shared(rows_):
             return sum(_gated(rows_, p["s_gate"][j], p["s_up"][j],
@@ -429,5 +483,5 @@ class ExpertShareFFN(Layer):
         return {n: getattr(self, n) for n in self.LEAVES}
 
 
-__all__ = ["MoEFFN", "ExpertShareFFN", "expert_share_ffn",
-           "route_sigmoid_topk", "rows_in_blocks"]
+__all__ = ["MoEFFN", "ExpertShareFFN", "expert_share_ffn", "Route",
+           "route_topk", "route_sigmoid_topk", "rows_in_blocks"]

@@ -25,7 +25,11 @@ length and every other backend keep the XLA twins :func:`write_token`
 + :func:`attend`, which write every slot and score the whole level. A
 layer that reads a ring it does not own (cross attention to another
 layer's keys and values) calls :func:`attend_token`, the read-only half
-by the same rule. A cache may also hold STATE levels beside its rings —
+by the same rule. A ring level may be LATENT (:class:`LatentLevel`,
+:func:`init_latent`): one array a token, scored over its whole width,
+whose first columns are the values — what latent attention keeps once
+for all its heads; the same functions take it by the same rule, the
+kernel in its latent form. A cache may also hold STATE levels beside its rings —
 what a recurrent layer keeps of a sequence, a fixed size a slot — which
 the ring layout sizes, counts, snapshots and hands off with the rings
 (:class:`RingLayout`).
@@ -159,6 +163,50 @@ def init_cache(n_slots, n_heads, length, head_dim, dtype=jnp.float32):
     return level
 
 
+@jax.tree_util.register_pytree_with_keys_class
+class LatentLevel(dict):
+    """A ring level that holds ONE array a token: ``{"k": (n_slots, 1,
+    length, padded)}``, rows of ``width`` numbers (zeros up to whole
+    lane tiles) that are scored over their whole width and whose first
+    ``value_width`` columns are the values — what latent attention in
+    its absorbed form keeps, one "KV head" for every query head. The two
+    widths are static (part of the tree's structure, not leaves)."""
+
+    def __init__(self, rows, width, value_width):
+        super().__init__(k=rows)
+        self.width, self.value_width = int(width), int(value_width)
+
+    def with_rows(self, rows):
+        """The same level holding ``rows``."""
+        return LatentLevel(rows, self.width, self.value_width)
+
+    def copy(self):
+        return self.with_rows(self["k"])
+
+    def tree_flatten_with_keys(self):
+        return ((jax.tree_util.DictKey("k"), self["k"]),), \
+            (self.width, self.value_width)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
+
+
+def init_latent(n_slots, length, width, value_width, dtype=jnp.float32):
+    """One latent ring level (:class:`LatentLevel`), zeroed: rows of
+    ``width`` numbers padded to whole lane tiles of 128."""
+    padded = -(-int(width) // 128) * 128
+    return LatentLevel(
+        jnp.zeros((int(n_slots), 1, int(length), padded), dtype),
+        width, value_width)
+
+
+def _latent_row(level, row):
+    """``row`` (..., width) as the level stores it: (..., padded)."""
+    pad = level["k"].shape[-1] - row.shape[-1]
+    return jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, pad)])
+
+
 def _quant_rows(x, axes):
     """Per-row cache quantization (one scale per written token row) —
     the ONE symmetric-int8 convention, shared with weight quantization
@@ -211,6 +259,10 @@ def write_token(level, k_new, v_new, pos):
         return lax.dynamic_update_slice(
             c, row[:, None, :].astype(c.dtype), (0, p % L, 0))
 
+    if isinstance(level, LatentLevel):
+        # the one row a token (``k_new`` (W, width); no ``v_new``)
+        return level.with_rows(jax.vmap(upd)(
+            level["k"], _latent_row(level, k_new)[:, None], pos))
     if "k_scale" not in level:
         return {"k": jax.vmap(upd)(level["k"], k_new, pos),
                 "v": jax.vmap(upd)(level["v"], v_new, pos)}
@@ -256,6 +308,8 @@ def ring_block(level):
     k = level["k"]
     if k.dtype not in (jnp.bfloat16, jnp.float32) or not _rd.kernels_run():
         return None
+    if isinstance(level, LatentLevel):
+        return _rd.latent_block(*k.shape[2:])
     return _rd.kernel_block(*k.shape[1:])
 
 
@@ -268,12 +322,24 @@ def decode_token(level, q, k_new, v_new, pos, active, scale):
     given) through ``ops/ring_decode.py`` — one pass over the blocks
     that hold a token, one tile of the cache written, nothing for a slot
     that is not ``active`` — and any other through :func:`write_token`
-    + :func:`attend`."""
+    + :func:`attend`.
+
+    A latent level (:class:`LatentLevel`) takes its one new row as
+    ``k_new`` ``(W, width)`` with ``v_new`` None, and ``q`` ``(W, H, 1,
+    width)``: every query head is scored against the one cached row over
+    its whole width, the values are the row's first ``value_width``
+    columns, ``out`` is ``(W, H, 1, value_width)`` — the same walk in
+    its latent form (``ops/ring_decode.latent_decode``), or the same
+    XLA twins."""
     block = ring_block(level)
     if block is None:
         level = write_token(level, k_new, v_new, pos)
         return attend(q, level, pos, scale), level
     from ..ops import ring_decode as _rd
+    if isinstance(level, LatentLevel):
+        out, rows = _rd.latent_decode(q, k_new, level["k"], pos, active,
+                                      scale, block, level.value_width)
+        return out, level.with_rows(rows)
     out, k, v = _rd.ring_decode(q, k_new, v_new, level["k"], level["v"],
                                 pos, active, scale, block)
     return out, {"k": k, "v": v}
@@ -307,25 +373,18 @@ def write_prompt(level, slot, k_rows, v_rows, valid):
     FIXED batch width over a variable number of admitted requests. A
     quantized level quantizes per token row (scale amax over heads ×
     head_dim) and writes the prompt's scale rows alongside."""
+    rows = {"k": k_rows} if v_rows is None else {"k": k_rows, "v": v_rows}
     if "k_scale" in level:
         # (H, S, D): one scale per prompt position -> (S,)
-        k_rows, ks = _quant_rows(k_rows, (0, 2))
-        v_rows, vs = _quant_rows(v_rows, (0, 2))
-    k_up = lax.dynamic_update_slice(
-        level["k"], k_rows[None].astype(level["k"].dtype),
-        (slot, 0, 0, 0))
-    v_up = lax.dynamic_update_slice(
-        level["v"], v_rows[None].astype(level["v"].dtype),
-        (slot, 0, 0, 0))
-    out = {"k": jnp.where(valid, k_up, level["k"]),
-           "v": jnp.where(valid, v_up, level["v"])}
-    if "k_scale" in level:
-        ks_up = lax.dynamic_update_slice(level["k_scale"], ks[None],
-                                         (slot, 0))
-        vs_up = lax.dynamic_update_slice(level["v_scale"], vs[None],
-                                         (slot, 0))
-        out["k_scale"] = jnp.where(valid, ks_up, level["k_scale"])
-        out["v_scale"] = jnp.where(valid, vs_up, level["v_scale"])
+        for name in ("k", "v"):
+            rows[name], rows[name + "_scale"] = _quant_rows(rows[name],
+                                                            (0, 2))
+    out = level.copy()
+    for name, new in rows.items():
+        old = level[name]
+        up = lax.dynamic_update_slice(
+            old, new[None].astype(old.dtype), (slot,) + (0,) * new.ndim)
+        out[name] = jnp.where(valid, up, old)
     return out
 
 
@@ -337,17 +396,23 @@ def write_prompts(level, slot_ids, k, v, lengths, valid):
     lies from index 0 (:func:`write_prompt`); of one that is longer
     (``S > L``: a window layer's ring under a longer prefill) ring index
     ``r`` gets the last prompt row ``t`` with ``t % L == r``, which is
-    what token-by-token writing would have left."""
+    what token-by-token writing would have left. A latent level
+    (:class:`LatentLevel`) takes its one row a token as ``k``
+    ``(B, S, width)`` with ``v`` None."""
     L = level["k"].shape[2]
     B, S = k.shape[:2]
-    kh, vh = k.swapaxes(1, 2), v.swapaxes(1, 2)          # B, H, S, D
+    if isinstance(level, LatentLevel):
+        # the one row a token, its one "KV head": (B, S, 1, padded)
+        k = _latent_row(level, k)[:, :, None]
+    kh = k.swapaxes(1, 2)                                # B, H, S, D
+    vh = None if v is None else v.swapaxes(1, 2)
     for b in range(B):
-        kb, vb = kh[b], vh[b]
+        kb, vb = kh[b], None if vh is None else vh[b]
         if S > L:
             r = jnp.arange(L, dtype=jnp.int32)
             last = lengths[b].astype(jnp.int32) - 1
             t = jnp.clip(last - ((last - r) % L), 0, S - 1)
-            kb, vb = kb[:, t], vb[:, t]
+            kb, vb = kb[:, t], None if vb is None else vb[:, t]
         level = write_prompt(level, slot_ids[b], kb, vb, valid[b])
     return level
 
@@ -366,6 +431,16 @@ def attend(q, level, pos, scale):
     before the f32 scores), result cast back to ``q.dtype``. Returns
     ``(W, H, 1, D)``."""
     L = level["k"].shape[2]
+    if isinstance(level, LatentLevel):
+        # every head against the one row a token, over its whole width;
+        # the values are the row's first columns
+        rows = level["k"][:, 0].astype(jnp.float32)          # W, L, P
+        qf = _latent_row(level, q[:, :, 0].astype(jnp.float32))
+        s = jnp.einsum("whd,wld->whl", qf, rows) * scale
+        s = jnp.where(ring_mask(pos, L)[:, None, :], s, -jnp.inf)
+        out = jnp.einsum("whl,wlv->whv", jax.nn.softmax(s, axis=-1),
+                         rows[..., :level.value_width])
+        return out.astype(q.dtype)[:, :, None]
     kf, vf = _dequant_level(level)
     shape = q.shape
     if shape[1] != kf.shape[1]:
@@ -834,7 +909,7 @@ def _rows_from_host(state, arrays, index, what, lead=None, skip=0):
     it = iter(arrays)
     new_state = []
     for level in state:
-        upd = dict(level)
+        upd = level.copy()
         for name in _level_names(level):
             arr = next(it)
             want = tuple(level[name].shape[1:])
@@ -931,13 +1006,15 @@ class RingLayout(KVLayout):
     """One slot's share of every level (``adapter.init_cache``): a free
     slot is a free row of each and generation past ``max_len`` slides a
     ring's window, so the defaults above hold; a decode tick carries one
-    token a slot. A cache is a list of levels, each a ring ``{"k","v"}``
-    or a state — any other dict of arrays with the slot first: what a
-    recurrent layer keeps of a sequence, of a fixed size whatever its
-    length. Everything here derives from that list, not from the model:
+    token a slot. A cache is a list of levels, each a ring ``{"k","v"}``,
+    a latent ring (:class:`LatentLevel`: one array a token under ``"k"``,
+    one KV head, counted, snapshot and handed off as a ring) or a state —
+    any other dict of arrays with the slot first: what a recurrent layer
+    keeps of a sequence, of a fixed size whatever its length. Everything here derives from that list, not from the model:
     levels may be fewer than layers (layers that read another layer's
     ring own none), and the adapter may say what each level is
-    (``cache_kinds()``: ``"window"`` | ``"full"`` | ``"state"``) and how
+    (``cache_kinds()``: ``"window"`` | ``"full"`` | ``"latent"`` |
+    ``"state"``) and how
     many layers read it each tick (``cache_readers()``). A recurrent
     adapter whose state is not such a list rides the layout opaquely
     (``lengths`` stays None, no gauges, no snapshots of it)."""
@@ -948,6 +1025,7 @@ class RingLayout(KVLayout):
     _programs = ("prefill_fn", "decode_fn")
     lengths = None
     n_state = 0
+    _latent = ()
     _prefill_rows = None
 
     def init_state(self):
@@ -968,12 +1046,14 @@ class RingLayout(KVLayout):
         # level's kind (``cache_kinds``); one geometry reads as "full"
         kinds = getattr(self.adapter, "cache_kinds", None)
         kinds = kinds() if kinds is not None else \
-            ["full" if "k" in lv else "state" for lv in state]
+            ["latent" if isinstance(lv, LatentLevel) else
+             "full" if "k" in lv else "state" for lv in state]
         kv_bytes = self._reg.gauge(
             "serve_kv_bytes", "bytes of per-slot serving state, by kind "
             "of level (window: rings of min(window, max_len) positions "
-            "a slot; full: of max_len; state: what recurrent layers "
-            "keep, of a fixed size a slot)", labels=("kind",))
+            "a slot; full: of max_len; latent: rings of one array a "
+            "token; state: what recurrent layers keep, of a fixed size "
+            "a slot)", labels=("kind",))
         for kind in sorted(set(kinds)):
             kv_bytes.set(sum(
                 int(a.size) * a.dtype.itemsize
@@ -995,6 +1075,10 @@ class RingLayout(KVLayout):
              str(lv[name].dtype)]
             for lv in state if "k" not in lv for name in _level_names(lv)]
         self.n_state = len(state) - len(rings)
+        # a latent level's two widths (its shape says only the padding)
+        self._latent = [[i, lv.width, lv.value_width]
+                        for i, lv in enumerate(state)
+                        if isinstance(lv, LatentLevel)]
         self._kv_rows = self._reg.counter(
             "serve_kv_rows_attended_total", "ring rows holding a token "
             "that decode ticks attended to, summed over the layers that "
@@ -1032,6 +1116,9 @@ class RingLayout(KVLayout):
             slot_ids[b] = free[b]
             valid[b] = True
             placed.append((req, free[b], None))
+        # what causal attention's work goes with: the sum of the
+        # prompts' squared lengths
+        attrs["tokens_sq"] = int(np.sum(lengths.astype(np.int64) ** 2))
         if self._prefill_rows is not None:
             for decoder, n in self.adapter.prefill_rows(
                     lengths[valid]).items():
@@ -1074,6 +1161,9 @@ class RingLayout(KVLayout):
         if self.n_state:
             # what each state level keeps of a slot: name, shape, dtype
             g["state"] = self._state_shapes
+        if self.lengths is not None and self._latent:
+            # which levels are latent: index, row width, value width
+            g["latent"] = self._latent
         return g
 
     def info(self, part):
@@ -1362,8 +1452,12 @@ def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
     # to page or share, and nothing a rejected draft could be rolled
     # back to
     kinds = getattr(adapter, "cache_kinds", None)
-    recurrent = kinds is not None and "state" in kinds()
-    if paged and (recurrent
+    kinds = kinds() if kinds is not None else ()
+    recurrent = "state" in kinds
+    # a latent level is one array a token: the pool's pages are
+    # ``{"k","v"}`` pairs of one head size, which it is not
+    latent = "latent" in kinds
+    if paged and (recurrent or latent
                   or not getattr(adapter, "supports_paged", False)):
         warnings.warn(
             f"kv_layout='paged' declined: {type(adapter).__name__} has "
@@ -1371,7 +1465,7 @@ def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
             "per-position KV rows); serving on the ring layout instead",
             stacklevel=4)
         declined["kv_layout_declined"] = "recurrent_state" if recurrent \
-            else "adapter_unsupported"
+            else "latent_level" if latent else "adapter_unsupported"
         paged = False
     # speculative_k = verify-program width: up to speculative_k tokens
     # emitted per tick (speculative_k - 1 of them drafted). It needs the
@@ -1389,7 +1483,7 @@ def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
                         "draft cannot be rolled back" if recurrent else "")
             + "); decoding one token per tick", stacklevel=4)
         declined["speculative_declined"] = "recurrent_state" if recurrent \
-            else "requires_paged_layout"
+            else "latent_level" if latent else "requires_paged_layout"
     spill = int(spill_bytes or 0)
     if spill > 0 and not paged:
         warnings.warn(
@@ -1418,7 +1512,7 @@ def pick_layout(kv_layout, adapter, registry, *, slots, max_len,
     return layout
 
 
-__all__ = ["init_cache", "ring_positions", "ring_mask", "write_token",
+__all__ = ["init_cache", "init_latent", "LatentLevel", "ring_positions", "ring_mask", "write_token",
            "write_prompt", "write_prompts", "attend", "decode_token",
            "attend_token",
            "ring_block",

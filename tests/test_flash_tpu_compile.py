@@ -193,3 +193,50 @@ def test_ring_attend_compiles_and_leaves_the_level_alone(
     assert mem.alias_size_in_bytes == 0
     assert mem.temp_size_in_bytes < level // 4
     assert mem.output_size_in_bytes < level // 4
+
+
+# (slots, query heads, ring, row width, value width), dtype: the latent
+# cell's level (64 heads on one cached row of 512 + 64 numbers, stored 640
+# wide), a float32 level, chip_smoke's toy level (4 heads on the same row),
+# and a row of 32 + 16 whose values are no whole lane tile
+LATENT_LEVELS = [
+    ((64, 64, 4096, 576, 512), jnp.bfloat16),
+    ((8, 16, 1024, 576, 512), jnp.float32),
+    ((4, 4, 256, 576, 512), jnp.bfloat16),
+    ((4, 4, 128, 48, 32), jnp.bfloat16),
+]
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize(
+    "shape,dtype", LATENT_LEVELS,
+    ids=[f"{'x'.join(map(str, s))}-{jnp.dtype(d).name}"
+         for s, d in LATENT_LEVELS])
+def test_latent_decode_compiles_and_writes_the_level_in_place(
+        one_chip, for_the_chip, monkeypatch, shape, dtype):
+    """The latent form of the walk compiles at the level's own block,
+    once; the one array of the level is aliased to its output and no
+    temporary of a level's size exists."""
+    from singa_tpu.ops import ring_decode
+    monkeypatch.setattr(ring_decode, "_interpret", lambda: False)
+    W, H, L, width, vw = shape
+    P = -(-width // 128) * 128
+    block = ring_decode.latent_block(L, P)
+    assert block is not None
+
+    def sds(*s, dt=dtype):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def f(q, row, rows, pos, active):
+        return ring_decode.latent_decode(q, row, rows, pos, active,
+                                         width ** -0.5, block, vw)
+
+    compiled = jax.jit(f, donate_argnums=(2,)).lower(
+        sds(W, H, 1, width), sds(W, width), sds(W, 1, L, P),
+        sds(W, dt=jnp.int32), sds(W, dt=jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "latent_decode" in hlo
+    level = W * L * P * jnp.dtype(dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == level
+    assert mem.temp_size_in_bytes < level // 4
