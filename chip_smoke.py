@@ -40,7 +40,9 @@ Phases (each prints one JSON line with platform, device_kind, n_devices):
                   future resolves, decode traced once, chosen tokens
                   agree with the eager forward's logits; the ring
                   engine's compiled decode program holds the ring_decode
-                  Mosaic call once a level and rewrites no level.
+                  Mosaic call once a level and rewrites no level; a
+                  call of either program passes at most 100 buffers
+                  (serve_program_arg_buffers, printed for both).
 - moe_serve       at a toy size, in bf16: the share-aware expert layer
                   (parallel/moe.py; few rows, every held expert on every
                   row; many rows, the pairs sorted and worked off in
@@ -255,6 +257,19 @@ def _logits_readbacks(reg):
             for p in ("prefill", "decode"))
     assert n == 0 and counter.total() > 0, \
         f"{n} of {counter.total()} greedy program calls read their logits"
+    return n
+
+
+def _arg_buffers(reg):
+    """Buffers a call of each serve program passes
+    (``serve_program_arg_buffers``). The host pays for each one every
+    tick: this LM's engine passes 6 matrices and 2 cache arrays a layer
+    and 19 leaves besides (its vector roles are one stacked leaf each),
+    67 at six layers, where a leaf a layer a role was 117."""
+    gauge = reg.get("serve_program_arg_buffers")
+    n = {p: int(gauge.value(program=p)) for p in ("prefill", "decode")}
+    assert all(0 < v <= 100 for v in n.values()), \
+        f"a TransformerLM engine passes {n} buffers a call: over 100"
     return n
 
 
@@ -724,7 +739,8 @@ def phase_serve(ctx):
         served[kv_layout] = [r["tokens"] for r in results]
         out[kv_layout] = {"requests": len(results),
                           "decode_n_traces": info["n_traces"],
-                          "prefill_n_traces": info["prefill_n_traces"]}
+                          "prefill_n_traces": info["prefill_n_traces"],
+                          "arg_buffers": _arg_buffers(reg)}
 
     # teacher-forced reference: the eager forward over prompt + generated
     # tokens, padded to a multiple of the flash block (causal attention:

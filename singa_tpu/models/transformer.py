@@ -271,40 +271,100 @@ def _lm_decode_tensors(m):
     return out
 
 
-def _lm_decode_params(m):
-    """Pull the trained weights into one host-gathered pytree of jnp
-    arrays for the pure decode functions (mesh-sharded state is gathered
-    once here — generation is a single-device inference convenience).
+# the block leaves the serve programs use through ``c()`` — the matrix
+# products' operands and their biases. LayerNorm's leaves are used in
+# float32 (``_ln``) and the MoE banks go to the MoE op as they are.
+_CAST_ROLES = ("wq", "bq", "wk", "bk", "wv", "bv", "wo", "bo",
+               "w_up", "b_up", "w_dn", "b_dn")
 
-    The gathered tree is CACHED against the identity of the live param
-    arrays (jax arrays are immutable, so a train step rebinds every
-    leaf): a serving loop pays the host round-trip once, not per call.
-    The cache holds references to the arrays it was built from, so after
-    a train step one stale weight copy lives until the next generate()
-    call refreshes it — an inference-convenience tradeoff, documented
-    here."""
+
+def _whole(t):
+    """A parameter's array where the decode tree can read it as it lies:
+    one device holds all of it. An array spread over a mesh is gathered
+    through the host, once."""
     import jax
+    import jax.numpy as jnp
+    a = t.data
+    if isinstance(a, jax.Array) and len(a.sharding.device_set) > 1:
+        return jnp.asarray(np.asarray(jax.device_get(a)))
+    return a
+
+
+def _lm_decode_params(m, block_dtype=None):
+    """The trained weights as the ONE pytree the pure decode functions
+    take (``generate()`` and the serve adapter both read it), formed on
+    the device from the live arrays: the six leaves outside the blocks,
+    and ``blocks`` — one dict that holds, role by role, the layers in
+    order (:func:`_layer` takes layer ``l``'s). The two forms a role
+    takes are what the chip showed (PERF.md §6, PR 34):
+
+    - a VECTOR role (biases, LayerNorm's scales and biases: 10 of a
+      block's 16 leaves) is ONE array with a leading layer axis,
+      ``bq (L, D)``. The host pays for every buffer of every call, and
+      these are most of the buffers and none of the bytes;
+    - a MATRIX role stays a list of ``L`` arrays. XLA prefetches a
+      parameter of its own into the fast memory while the ops before
+      its use run; of a leaf stacked over the layers it prefetches
+      nothing use by use — the products read their slice from HBM
+      inside the fusion, and the decode program took 0.6 ms longer
+      than with a buffer a matrix, more than the buffers cost.
+
+    ``block_dtype`` casts the matrix products' operands and biases
+    (``_CAST_ROLES``) as the tree is formed, so that a serve program
+    finds them in its compute dtype and casts nothing a tick;
+    LayerNorm's leaves, the tables and the head keep their dtype.
+    ``None`` (what ``generate()`` asks for) casts nothing.
+
+    The tree is CACHED against the identity of the live param arrays
+    (jax arrays are immutable, so a train step rebinds every leaf): a
+    serving loop builds it once, not per call — one tree a
+    ``block_dtype`` asked for. It owns its leaves (a train step donates
+    the model's arrays; an engine's tree outlives it), and the cache
+    holds references to the arrays it was built from, so after a train
+    step one stale weight copy lives until the next call refreshes it —
+    an inference-convenience tradeoff, documented here. The tree lies
+    where the model's arrays lie; mesh-sharded state is gathered through
+    the host first (generation is a single-device inference
+    convenience)."""
     import jax.numpy as jnp
 
     per_block = _lm_decode_tensors(m)
+    ends = dict(tok=m.tok_emb.W, pos=m.pos_emb.W, lnf_s=m.ln_f.scale,
+                lnf_b=m.ln_f.bias, head_w=m.head.W, head_b=m.head.b)
     live = [t.data for leaves in per_block for _, t in leaves] \
-        + [m.tok_emb.W.data, m.pos_emb.W.data, m.ln_f.scale.data,
-           m.ln_f.bias.data, m.head.W.data, m.head.b.data]
+        + [t.data for t in ends.values()]
     pin = getattr(m, "_decode_params_pin", None)
-    if pin is not None and len(pin[0]) == len(live) and \
-            all(a is b for a, b in zip(pin[0], live)):
-        return pin[1]
-
-    def a(t):
-        return jnp.asarray(np.asarray(jax.device_get(t.data)))
-
-    blocks = [{name: a(t) for name, t in leaves} for leaves in per_block]
-    P = dict(tok=a(m.tok_emb.W), pos=a(m.pos_emb.W),
-             lnf_s=a(m.ln_f.scale), lnf_b=a(m.ln_f.bias),
-             head_w=a(m.head.W), head_b=a(m.head.b),
-             blocks=blocks)
-    m._decode_params_pin = (live, P)
+    if pin is None or len(pin[0]) != len(live) or \
+            not all(a is b for a, b in zip(pin[0], live)):
+        pin = m._decode_params_pin = (live, {})
+    key = None if block_dtype is None else jnp.dtype(block_dtype)
+    if all(t.data.dtype == key for name, t in per_block[0]
+           if name in _CAST_ROLES):
+        key = None      # nothing to cast: the tree generate() reads
+    P = pin[1].get(key)
+    if P is None:
+        blocks = {}
+        for of_role in zip(*per_block):     # one role, layer by layer
+            role = of_role[0][0]
+            leaves = [_whole(t) for _, t in of_role]
+            dt = leaves[0].dtype
+            if key is not None and role in _CAST_ROLES and \
+                    jnp.issubdtype(dt, jnp.floating):
+                dt = key
+            blocks[role] = jnp.stack(leaves, dtype=dt) \
+                if leaves[0].ndim == 1 else \
+                [jnp.array(a, dtype=dt, copy=True) for a in leaves]
+        P = pin[1][key] = dict(
+            {name: jnp.array(_whole(t), copy=True)
+             for name, t in ends.items()},
+            blocks=blocks)
     return P
+
+
+def _layer(blocks, l):
+    """Layer ``l``'s leaves of the ``blocks`` tree: every role holds its
+    layers in order, as a stacked array's leading axis or as a list."""
+    return {role: leaves[l] for role, leaves in blocks.items()}
 
 
 def _ln(x, s, b, eps=1e-5):
@@ -363,6 +423,7 @@ def _generate(self, ids, max_new_tokens, temperature=1.0, top_k=None,
     B, S0 = prompt.shape
     P = _lm_decode_params(self)
     n_heads = self.blocks[0].attn.n_heads
+    n_layers = len(self.blocks)
     hd = self.d_model // n_heads
     L = S0 + max_new_tokens
     assert L <= P["pos"].shape[0], \
@@ -443,8 +504,8 @@ def _generate(self, ids, max_new_tokens, temperature=1.0, top_k=None,
         def run(Pq, prompt, key):
             x = embed(Pq, prompt, jnp.arange(S0)[None, :])
             caches = []
-            for p in Pq["blocks"]:
-                x, k, v = block_prefill(p, x)
+            for l in range(n_layers):
+                x, k, v = block_prefill(_layer(Pq["blocks"], l), x)
                 kc = jnp.zeros((B, n_heads, L, hd), k.dtype)
                 vc = jnp.zeros_like(kc)
                 kc = lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
@@ -459,8 +520,9 @@ def _generate(self, ids, max_new_tokens, temperature=1.0, top_k=None,
                 tok, pos, caches, key = carry
                 x = embed(Pq, tok[:, None], pos.reshape(1, 1))
                 new_caches = []
-                for p, (kc, vc) in zip(Pq["blocks"], caches):
-                    x, kc, vc = block_decode(p, x, kc, vc, pos[0])
+                for l, (kc, vc) in enumerate(caches):
+                    x, kc, vc = block_decode(_layer(Pq["blocks"], l), x,
+                                             kc, vc, pos[0])
                     new_caches.append((kc, vc))
                 hN = _ln(x, Pq["lnf_s"], Pq["lnf_b"])
                 logits = hN[:, -1] @ Pq["head_w"] + Pq["head_b"]
@@ -562,26 +624,39 @@ class _LMServeAdapter:
         return jnp.dtype(cd) if cd is not None else jnp.dtype(jnp.float32)
 
     def params(self):
+        """The tree both serve programs take as ``P``
+        (:func:`_lm_decode_params`: the vector roles one stacked leaf
+        each, the matrices a leaf a layer). Where ``c()`` would do
+        nothing to a block weight but cast it — no ``weight_quant``, no
+        ``compute_quant`` — the cast is made here, once, and the
+        programs read the compute dtype; under a quantized policy the
+        leaves stay as they are and ``c()`` does the work at the use
+        site."""
         from ..quant.core import dequant_params_scope
+        weight_quant = getattr(self.policy, "weight_quant", None)
+        plain = weight_quant is None and \
+            getattr(self.policy, "compute_quant", None) is None
         with dequant_params_scope(self.m):
             # a model already weight-quantized in place hands the
             # engine its DEQUANTIZED weights here (concrete arrays at
             # build time; re-quantized below under an int8 policy)
-            P = _lm_decode_params(self.m)
-        if getattr(self.policy, "weight_quant", None) == "int8":
+            P = _lm_decode_params(
+                self.m, self._compute_dtype() if plain else None)
+        if weight_quant == "int8":
             from ..quant import core as _qcore
             import jax.numpy as jnp
-            blocks = []
-            for p in P["blocks"]:
-                bp = dict(p)
-                for key in self._QUANT_KEYS:
-                    w = bp.get(key)
-                    if w is not None and w.ndim == 2 and \
-                            jnp.issubdtype(w.dtype, jnp.floating):
-                        q, s = _qcore.quantize_int8(
-                            w, _qcore.channel_axis(w.shape))
-                        bp[key] = {"q": q, "s": s}
-                blocks.append(bp)
+
+            def quantized(w):
+                q, s = _qcore.quantize_int8(
+                    w, _qcore.channel_axis(w.shape))
+                return {"q": q, "s": s}
+
+            blocks = dict(P["blocks"])
+            for key in self._QUANT_KEYS:
+                layers = blocks.get(key)
+                if layers is not None and layers[0].ndim == 2 and \
+                        jnp.issubdtype(layers[0].dtype, jnp.floating):
+                    blocks[key] = [quantized(w) for w in layers]
             P = dict(P, blocks=blocks)
         return P
 
@@ -715,8 +790,9 @@ class _LMServeAdapter:
                 return o, level
 
             new_cache = []
-            for p, level in zip(P["blocks"], cache):
-                x, level = block(p, x, level, attend)
+            for l, level in enumerate(cache):
+                x, level = block(_layer(P["blocks"], l), x, level,
+                                 attend)
                 new_cache.append(level)
             hN = _ln(x, P["lnf_s"], P["lnf_b"])
             h_last = jnp.take_along_axis(
@@ -764,8 +840,9 @@ class _LMServeAdapter:
                 return _merge_heads(o), level
 
             new_pool = []
-            for p, level in zip(P["blocks"], pool):
-                x, level = block(p, x, level, attend)
+            for l, level in enumerate(pool):
+                x, level = block(_layer(P["blocks"], l), x, level,
+                                 attend)
                 new_pool.append(level)
             return new_pool, _ln(x, P["lnf_s"], P["lnf_b"])
 
@@ -849,8 +926,9 @@ class _LMServeAdapter:
                 return _merge_heads(o), level
 
             new_cache = []
-            for p, level in zip(P["blocks"], cache):
-                x, level = block(p, x, level, attend)
+            for l, level in enumerate(cache):
+                x, level = block(_layer(P["blocks"], l), x, level,
+                                 attend)
                 new_cache.append(level)
             hN = _ln(x, P["lnf_s"], P["lnf_b"])[:, 0]
             logits = (hN.astype(jnp.float32) @ P["head_w"]
@@ -879,7 +957,8 @@ class _LMServeAdapter:
 def _decode_adapter(self, policy=None):
     """The serving engine's entry point (``Model.compile_serving``
     routes autoregressive models here): a :class:`_LMServeAdapter` over
-    this model's live (host-gathered) weights."""
+    a tree formed from this model's live weights
+    (:func:`_lm_decode_params`)."""
     return _LMServeAdapter(self, policy=policy)
 
 

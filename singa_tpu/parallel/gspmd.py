@@ -396,41 +396,50 @@ def lm_param_specs(part, params, n_heads):
     """PartitionSpec tree for the transformer serve-param dict
     (``models.transformer._lm_decode_params`` layout): attention heads
     and MLP hidden split over ``model``, vocab-sharded embedding rows
-    and head columns, everything small replicated. Typed declines for
-    every dimension the mesh cannot split honestly."""
+    and head columns, everything small replicated. ``blocks`` holds
+    its layers role by role: a vector role is one leaf stacked over the
+    layers and gets its spec behind a ``None`` for the layer axis, a
+    matrix role is a list with the role's spec a layer. Typed declines
+    for every dimension the mesh cannot split honestly."""
     ax = part.model_axis
     part.require_divisible("n_heads", n_heads, ax)
     vocab = int(params["tok"].shape[0])
     part.require_divisible("vocab_size", vocab, ax)
-    blocks = []
-    for i, p in enumerate(params["blocks"]):
-        if "wg" in p:
-            raise ShardingDecline(
-                "MoE decode blocks are not mesh-shardable yet: the "
-                "expert banks would silently replicate per device "
-                f"(block {i}); serve MoE models single-device, or "
-                "train with the 'expert' axis")
-        d_ff = int((p["w_up"]["q"] if isinstance(p["w_up"], dict)
-                    else p["w_up"]).shape[1])
-        part.require_divisible("d_ff (MLP hidden)", d_ff, ax)
-        spec = {
-            "ln1_s": P(), "ln1_b": P(), "ln2_s": P(), "ln2_b": P(),
-            # qkv columns = heads × head_dim: whole heads per shard
-            # (n_heads % m checked above keeps the reshape honest)
-            "wq": _weight_entry_spec(p["wq"], col_spec(ax)),
-            "bq": col_bias_spec(ax),
-            "wk": _weight_entry_spec(p["wk"], col_spec(ax)),
-            "bk": col_bias_spec(ax),
-            "wv": _weight_entry_spec(p["wv"], col_spec(ax)),
-            "bv": col_bias_spec(ax),
-            "wo": _weight_entry_spec(p["wo"], row_spec(ax)),
-            "bo": P(),
-            "w_up": _weight_entry_spec(p["w_up"], col_spec(ax)),
-            "b_up": col_bias_spec(ax),
-            "w_dn": _weight_entry_spec(p["w_dn"], row_spec(ax)),
-            "b_dn": P(),
-        }
-        blocks.append(spec)
+    p = params["blocks"]
+    if "wg" in p:
+        raise ShardingDecline(
+            "MoE decode blocks are not mesh-shardable yet: the "
+            "expert banks would silently replicate per device; serve "
+            "MoE models single-device, or train with the 'expert' "
+            "axis")
+    w_up = p["w_up"][0]
+    d_ff = int((w_up["q"] if isinstance(w_up, dict) else w_up).shape[1])
+    part.require_divisible("d_ff (MLP hidden)", d_ff, ax)
+
+    def vector(spec):
+        return P(None, *spec)
+
+    def matrix(role, spec):
+        return [_weight_entry_spec(w, spec) for w in p[role]]
+
+    blocks = {
+        "ln1_s": vector(P()), "ln1_b": vector(P()),
+        "ln2_s": vector(P()), "ln2_b": vector(P()),
+        # qkv columns = heads × head_dim: whole heads per shard
+        # (n_heads % m checked above keeps the reshape honest)
+        "wq": matrix("wq", col_spec(ax)),
+        "bq": vector(col_bias_spec(ax)),
+        "wk": matrix("wk", col_spec(ax)),
+        "bk": vector(col_bias_spec(ax)),
+        "wv": matrix("wv", col_spec(ax)),
+        "bv": vector(col_bias_spec(ax)),
+        "wo": matrix("wo", row_spec(ax)),
+        "bo": vector(P()),
+        "w_up": matrix("w_up", col_spec(ax)),
+        "b_up": vector(col_bias_spec(ax)),
+        "w_dn": matrix("w_dn", row_spec(ax)),
+        "b_dn": vector(P()),
+    }
     return dict(
         tok=vocab_spec(ax),          # vocab rows sharded
         pos=P(),                     # tiny, every rank reads every row

@@ -822,6 +822,18 @@ class ServingEngine(_EngineBase):
         self._reg.gauge("serve_slots",
                         "slot array width (max in-flight sequences)"
                         ).set(self.slots)
+        # the host pays for every buffer of every call (a hold, an
+        # event, a reference): an adapter that hands a leaf a layer a
+        # role shows here, once, at no cost a tick
+        arg_buffers = self._reg.gauge(
+            "serve_program_arg_buffers", "buffers one call of a serve "
+            "program passes: the leaves of the adapter's parameter "
+            "tree, of the donated KV state, and the tick's host arrays",
+            labels=("program",))
+        n_held = len(jax.tree_util.tree_leaves((self._P, self._cache)))
+        for program, names in (("prefill", layout.prefill_names),
+                               ("decode", layout.decode_names)):
+            arg_buffers.set(n_held + len(names), program=program)
         # an adapter whose programs return ``(logits, stats)`` (a small
         # array of per-call counts that rides the tokens' read-back, no
         # sync of its own) publishes them itself: ``stats_recorder(

@@ -664,6 +664,43 @@ class TestServingAot:
         e2.run_until_idle()
         assert f2.result()["tokens"] == f1.result()["tokens"]
 
+    def test_artifact_of_the_old_parameter_tree_is_refused(self, dev,
+                                                           tmp_path):
+        """The adapter's tree holds its layers role by role (PR 34: a
+        vector role one stacked leaf) and the manifest's avals are of
+        that tree. An artifact exported from the tree of a leaf a block
+        a role is refused typed and compiled fresh — the refused engine
+        serves what the exporting one served — and a fresh export
+        round-trips."""
+        from singa_tpu.serving.engine import ServingEngine
+        from test_serve_param_tree import _OldTreeAdapter, old_walk
+        geometry = dict(slots=2, max_len=48, prefill_len=8)
+        store = AotStore(str(tmp_path))
+        with old_walk():
+            e1 = ServingEngine(_OldTreeAdapter(self._model(dev)),
+                               **geometry)
+            e1.export_aot(store)
+            f1 = e1.submit([1, 2, 3], max_new_tokens=6)
+            e1.run_until_idle()
+        with pytest.warns(UserWarning, match="REFUSED"):
+            e2 = self._model(dev).compile_serving(aot_store=store,
+                                                  **geometry)
+        assert e2.compiled_step_info()["aot"] == {
+            "serve_prefill": "refused:avals",
+            "serve_decode": "refused:avals"}
+        f2 = e2.submit([1, 2, 3], max_new_tokens=6)
+        e2.run_until_idle()
+        assert f2.result()["tokens"] == f1.result()["tokens"]
+        e2.export_aot(store)
+        e3 = self._model(dev).compile_serving(aot_store=store,
+                                              **geometry)
+        assert e3.compiled_step_info()["aot"] == {
+            "serve_prefill": "loaded", "serve_decode": "loaded"}
+        f3 = e3.submit([1, 2, 3], max_new_tokens=6)
+        e3.run_until_idle()
+        assert f3.result()["tokens"] == f1.result()["tokens"]
+        assert e3.compiled_step_info()["n_traces"] == 1
+
 
 # ---------------------------------------------------------------------------
 # checkpoint scrub covers the aot sidecar

@@ -351,3 +351,54 @@ class TestFleetView:
         hb = obs_metrics.heartbeat_summary(reg)
         assert hb["serving_kv"]["per_device_bytes"] == \
             info["kv_per_device_bytes"]
+
+
+class TestServeTreeSpecs:
+    """The adapter's tree holds its layers role by role (PR 34): a
+    vector role is one leaf stacked over the layers and gets the spec
+    its role had a block behind a ``None`` for the layer axis; a matrix
+    role is a list with that spec a layer."""
+
+    @pytest.mark.parametrize("policy", [None, "int8_weight_only"])
+    def test_every_role_keeps_its_spec(self, policy):
+        from jax.sharding import PartitionSpec as P
+        from singa_tpu import mixed_precision as mp
+        m = tiny_lm(seed=20, layers=3)
+        pol = None if policy is None else mp.resolve(policy)
+        ad = m.decode_adapter(policy=pol)
+        tree = ad.params()
+        part = gspmd.serving_partitioner(model_shards=2, max_batch=4)
+        ax = part.model_axis
+        a_block = {
+            "ln1_s": P(), "ln1_b": P(), "ln2_s": P(), "ln2_b": P(),
+            "wq": P(None, ax), "bq": P(ax), "wk": P(None, ax),
+            "bk": P(ax), "wv": P(None, ax), "bv": P(ax),
+            "wo": P(ax, None), "bo": P(),
+            "w_up": P(None, ax), "b_up": P(ax),
+            "w_dn": P(ax, None), "b_dn": P()}
+        specs = gspmd.lm_param_specs(part, tree, ad.n_heads)
+        assert set(specs["blocks"]) == set(a_block)
+        for role, old in a_block.items():
+            got, leaf = specs["blocks"][role], tree["blocks"][role]
+            if not isinstance(leaf, list):
+                assert leaf.shape[0] == 3, role
+                assert got == P(None, *old), role
+            elif isinstance(leaf[0], dict):
+                # the scale (1, out) rides the payload's out axis
+                assert got == [{"q": old, "s": P(None, old[1])}] * 3, role
+            else:
+                assert got == [old] * 3, role
+        # and the mesh holds what the table says: half of wq's columns
+        # and half of bq's of every layer on a device
+        eng = m.compile_serving(slots=4, max_len=48, prefill_len=8,
+                                model_shards=2, policy=pol,
+                                registry=_reg())
+        wq = eng._P["blocks"]["wq"][2]
+        wq = wq["q"] if isinstance(wq, dict) else wq
+        assert wq.sharding.shard_shape(wq.shape) == (32, 16)
+        bq = eng._P["blocks"]["bq"]
+        assert bq.sharding.shard_shape(bq.shape) == (3, 16)
+        assert _run(eng, _prompts(4, shared_prefix=False)) == _run(
+            m.compile_serving(slots=4, max_len=48, prefill_len=8,
+                              policy=pol, registry=_reg()),
+            _prompts(4, shared_prefix=False))

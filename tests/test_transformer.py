@@ -380,6 +380,36 @@ class TestGeneration:
         greedy = m.generate(ids[:, :4], 5, temperature=0)
         np.testing.assert_array_equal(out_k1, greedy)
 
+    @pytest.mark.parametrize("moe", [0, 4], ids=["dense", "moe"])
+    def test_generate_gives_the_tokens_the_old_tree_gave(self, moe,
+                                                         monkeypatch):
+        """generate() reads the tree that holds its layers role by role
+        (PR 34): greedy and sampled, it emits what the same program
+        emits over the tree of a float32 leaf a block a role."""
+        from test_serve_param_tree import _OldTreeAdapter, old_walk
+        dev = device.create_cpu_device()
+        dev.SetRandSeed(11)
+        np.random.seed(11)
+        m = transformer.TransformerLM(VOCAB, d_model=32, n_heads=2,
+                                      n_layers=3, max_len=64, tp=False,
+                                      moe=moe, moe_capacity_factor=8.0)
+        m.eval()
+        ids, _ = lm_data(B=2, S=8)
+        m(tensor.Tensor(data=ids, device=dev, requires_grad=False))
+        asks = (dict(temperature=0), dict(temperature=0.8, top_k=3,
+                                          seed=1))
+        got = [m.generate(ids[:, :5], 6, **kw) for kw in asks]
+        blocks = transformer._lm_decode_params(m)["blocks"]
+        assert blocks["ln1_s"].shape == (3, 32) and len(blocks["wq"]) == 3
+        m._decode_cache = None      # the programs traced over that tree
+        with old_walk():
+            monkeypatch.setattr(
+                transformer, "_lm_decode_params",
+                lambda model: _OldTreeAdapter(model).params())
+            want = [m.generate(ids[:, :5], 6, **kw) for kw in asks]
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+
     def test_edge_cases(self):
         m, dev, ids = self._model(steps=1)
         # zero new tokens returns the prompt unchanged
