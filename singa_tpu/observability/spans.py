@@ -379,6 +379,27 @@ class _Phase:
         return False
 
 
+class annotation:
+    """The profiler annotation ``name`` and nothing else: no record and
+    no clock read. For a wait that has to show on the trace's clock
+    without filling the ring (the serve loop's ``serve.idle``: an idle
+    engine would otherwise evict every tick record)."""
+
+    __slots__ = ("_name", "_annotation")
+
+    def __init__(self, name):
+        self._name = name
+
+    def __enter__(self):
+        self._annotation = _annotate(self._name)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
 def event(name, **attrs):
     """Record a point-in-time event (no duration) — rollbacks, loss-
     scale backoffs, quarantines."""
@@ -413,5 +434,5 @@ def open_spans(now=None):
     return out
 
 
-__all__ = ["FlightRecorder", "context", "span", "event", "open_spans",
-           "recorder", "configure", "DEFAULT_CAPACITY"]
+__all__ = ["FlightRecorder", "context", "span", "annotation", "event",
+           "open_spans", "recorder", "configure", "DEFAULT_CAPACITY"]

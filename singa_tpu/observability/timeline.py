@@ -155,16 +155,52 @@ def _clip(intervals, t0, t1):
 
 _MAX_LANE_INTERVALS = 128
 
+# a TPU plane's rows: `XLA Ops` holds one event an executed op; `Steps`
+# and `XLA Modules` hold one event a step or a program, first op to
+# last, and so cover the gaps between its ops (they are not work)
+OP_LINE = "XLA Ops"
+NOT_OP_LINES = ("Steps", "XLA Modules")
 
-def analyze(events, window=None):
+
+def device_ops(events):
+    """The device-lane events that are ops: on a device process that
+    has an ``XLA Ops`` row, that row alone (as
+    ``benchmarks/lib/xplane.py`` counts); elsewhere every row but
+    ``Steps`` and ``XLA Modules``."""
+    device = [e for e in events if e.get("lane") == "device"]
+    with_op_line = {e.get("pid") for e in device
+                    if e.get("line") == OP_LINE}
+    return [e for e in device
+            if (e.get("line") == OP_LINE if e.get("pid") in with_op_line
+                else e.get("line") not in NOT_OP_LINES)]
+
+
+def host_extent(events, name):
+    """``(t0_us, t1_us)`` of the last host event called ``name`` (a
+    span's annotation, such as the profiled tick's ``serve.tick``), or
+    None where the trace holds none."""
+    found = [e for e in events if e.get("lane") == "host"
+             and e.get("name") == name and e.get("ts") is not None]
+    if not found:
+        return None
+    last = max(found, key=lambda e: e["ts"])
+    return last["ts"], last["ts"] + last["dur"]
+
+
+def analyze(events, window=None, own=()):
     """Bucket a step's trace events (``profiling.parse_trace_events``
     dicts) into the compute/collective/memcpy/host/idle decomposition.
 
-    Device lanes are the op timeline; on a backend without device lanes
-    (CPU CI) the host lane's XLA-op events stand in (and the ``host``
-    bucket is then empty — it cannot be told apart from compute there).
-    ``window`` is an optional ``(t0_us, t1_us)`` override; by default
-    the window spans the first op start to the last op end.
+    Device lanes are the op timeline (:func:`device_ops`: a TPU plane's
+    ``Steps`` and ``XLA Modules`` rows are no ops); on a backend without
+    device lanes (CPU CI) the host lane's XLA-op events stand in (and
+    the ``host`` bucket is then empty — it cannot be told apart from
+    compute there). ``window`` is an optional ``(t0_us, t1_us)``
+    override; by default the window spans the first op start to the
+    last op end. ``own``: name prefixes of the program's own host
+    annotations (its spans: what the host thread was inside, not the
+    runtime feeding or blocking the device); they are left out of the
+    host lane, so a gap under them alone is ``idle``.
 
     Returns None when nothing timestamped was captured, else a dict::
 
@@ -183,14 +219,17 @@ def analyze(events, window=None):
     bucketing successfully hid under compute)."""
     evs = [e for e in (events or [])
            if e.get("ts") is not None and e.get("dur")]
-    device = [e for e in evs if e.get("lane") == "device"]
+    own = tuple(own)
+    device = device_ops(evs)
     if device:
         ops = device
-        host = [e for e in evs if e.get("lane") == "host"]
+        host = [e for e in evs if e.get("lane") == "host"
+                and not str(e.get("name", "")).startswith(own)]
     else:
         # CPU fallback: host XLA-op events are the op timeline; there
         # is no separate runtime lane to attribute gaps to
-        ops = [e for e in evs if e.get("xla_op", True)]
+        ops = [e for e in evs if e.get("xla_op", True)
+               and not str(e.get("name", "")).startswith(own)]
         host = []
     if not ops:
         return None
@@ -447,6 +486,7 @@ def classify_cause(fractions, compile_share=None,
 
 __all__ = ["BUCKETS", "CAUSES", "CAUSE_THRESHOLD",
            "COMPILE_BOUND_SHARE", "classify_op", "merge_intervals",
-           "subtract_intervals", "intersect_intervals", "analyze",
+           "subtract_intervals", "intersect_intervals", "device_ops",
+           "host_extent", "analyze",
            "waterfall", "record_timeline", "compact",
            "timeline_summary", "classify_cause"]

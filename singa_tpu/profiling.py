@@ -29,10 +29,13 @@ def parse_trace_events(logdir):
     output directory, one dict per event::
 
         {"name": <enriched symbol>, "ts": <µs or None>, "dur": <µs>,
-         "lane": "device" | "host", "pid": ..., "xla_op": bool}
+         "lane": "device" | "host", "pid": ..., "line": <thread name>,
+         "xla_op": bool}
 
     ``lane`` is resolved per trace file (``/device:...`` process rows
-    are device lanes); ``xla_op`` records whether the RAW event name
+    are device lanes); ``line`` is the row's thread name (a TPU plane's
+    ``XLA Ops``, ``XLA Modules``, ``Steps`` …; ``""`` where the trace
+    names none); ``xla_op`` records whether the RAW event name
     looked like an XLA op/fusion symbol (the host-fallback filter —
     computed before :func:`_enrich` folds metadata into the name).
     Python source frames (``$...``) and zero-duration events are
@@ -49,10 +52,15 @@ def parse_trace_events(logdir):
         except (OSError, json.JSONDecodeError):
             continue
         events = trace.get("traceEvents", [])
-        lanes = {}
+        lanes, rows = {}, {}
         for e in events:
-            if e.get("ph") == "M" and e.get("name") == "process_name":
+            if e.get("ph") != "M":
+                continue
+            if e.get("name") == "process_name":
                 lanes[e["pid"]] = e.get("args", {}).get("name", "")
+            elif e.get("name") == "thread_name":
+                rows[(e.get("pid"), e.get("tid"))] = \
+                    e.get("args", {}).get("name", "")
         device_pids = {pid for pid, name in lanes.items()
                        if name.startswith("/device:")}
         for e in events:
@@ -69,6 +77,7 @@ def parse_trace_events(logdir):
                 "dur": float(e["dur"]),
                 "lane": "device" if pid in device_pids else "host",
                 "pid": pid,
+                "line": rows.get((pid, e.get("tid")), ""),
                 "xla_op": _is_xla_op_event(name)})
     return out
 

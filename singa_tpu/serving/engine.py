@@ -94,15 +94,18 @@ from .scheduler import (BlockPoolExhausted, EngineDraining,
 # donation regressions in the embedding application's unrelated jits).
 # The lock keeps concurrent engines from clobbering each other's
 # catch_warnings save/restore; dispatch returns before execution, so
-# the hold time is microseconds.
+# the hold time is microseconds. ``sp`` is the open serve span: the
+# call alone is its phase ``call``, so ``dispatch`` less ``call`` is
+# this lock, the filter and what ``_dispatch`` does around them.
 _WARN_LOCK = threading.Lock()
 
 
-def _quiet_donation(fn, *args):
+def _quiet_donation(sp, fn, *args):
     with _WARN_LOCK, warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
-        return fn(*args)
+        with sp.phase("call"):
+            return fn(*args)
 
 
 def _pack_arrays(arrays):
@@ -349,7 +352,12 @@ class _EngineBase:
                 capture_s)
         if table:
             _profiling.record_fusion_metrics(table, registry=self._reg)
-        tl = _timeline.analyze(events)
+        # the window is the tick, host work and waits included, and the
+        # engine's own annotations are what it was inside, not the
+        # runtime's work: a gap under them alone is idle
+        tl = _timeline.analyze(
+            events, window=_timeline.host_extent(events, "serve.tick"),
+            own=("serve.",))
         if tl is not None:
             _timeline.record_timeline(tl, registry=self._reg,
                                       site="serve")
@@ -383,7 +391,10 @@ class _EngineBase:
         while self._running:
             if not self._busy():
                 self._idle_evt.set()
-                self._wake.wait(0.02)
+                # on the trace's clock only: device idle time under
+                # `serve.idle` is load that was absent, not host cost
+                with _spans.annotation("serve.idle"):
+                    self._wake.wait(0.02)
                 self._wake.clear()
                 continue
             self._idle_evt.clear()
@@ -1591,20 +1602,26 @@ class ServingEngine(_EngineBase):
             self._tick_ewma = dt if not self._tick_ewma \
                 else 0.8 * self._tick_ewma + 0.2 * dt
 
-    def _dispatch(self, fn, rec, program, names, host):
+    def _dispatch(self, fn, rec, program, names, host, sp):
         """One call of a serve program on the DONATED state, which comes
         back threaded into ``self._cache``; returns the program's other
-        output. A call that traced (the ``n_traces`` delta) is
+        output. ``sp`` is the open span: the call alone is its phase
+        ``call``, and the engine thread's CPU time here goes to its
+        attr ``dispatch_cpu_s`` (against the phase's wall time: the
+        thread ran, or waited for the interpreter lock or inside the
+        call). A call that traced (the ``n_traces`` delta) is
         attributed: a first compile, or the retrace that breaks the
         one-trace contract, with what changed."""
+        cpu0 = time.thread_time()
         n0 = rec["n_traces"]
         t0 = time.perf_counter()
         cc0 = _cache_counts()
-        self._cache, out = _quiet_donation(fn, self._P, self._cache,
+        self._cache, out = _quiet_donation(sp, fn, self._P, self._cache,
                                            *host)
         if rec["n_traces"] > n0:
             _attribute_trace(rec, self._reg, program, list(host), names,
                              t0, cc0)
+        sp.attrs["dispatch_cpu_s"] = time.thread_time() - cpu0
         return out
 
     def _read_out(self, out, sp, program, requests):
@@ -1617,23 +1634,39 @@ class ServingEngine(_EngineBase):
         ``requests`` this call served samples — with ``temperature ==
         0`` a row's token is its argmax whatever ``top_k`` says — and
         otherwise they stay on the device and go with their
-        reference."""
+        reference.
+
+        Two phases of ``sp`` split it: ``ready``, the wait until the
+        program has made what is read (its copies to the host are
+        asked for first, so they follow the program as a plain read's
+        would), and ``fetch``, those arrays to numpy once they are."""
         import jax
         sampling = any(r.temperature != 0 for r in requests)
         what = sp.attrs["readback"] = "logits" if sampling else "tokens"
         self._readbacks.inc(program=program, what=what)
-        if self._record_stats is None:
-            tokens = np.asarray(out[0])
-        else:
-            tokens, stats = jax.device_get((out[0], out[-1]))
+        read = (out[0],
+                out[-1] if self._record_stats is not None else None,
+                out[1] if sampling else None)
+        with sp.phase("ready"):
+            for leaf in jax.tree.leaves(read):
+                leaf.copy_to_host_async()
+            jax.block_until_ready(read)
+        with sp.phase("fetch"):
+            if self._record_stats is None:
+                tokens = np.asarray(out[0])
+            else:
+                tokens, stats = jax.device_get(read[:2])
+            logits = np.asarray(out[1]) if sampling else None
+        if self._record_stats is not None:
             sp.attrs.update(self._record_stats(program, stats))
-        return tokens, (np.asarray(out[1]) if sampling else None)
+        return tokens, logits
 
     def _run_prefill(self, batch, free, sp):
         """``sp`` is the open ``serve.prefill`` span, split into
         ``pack`` (the layout's numpy inputs), ``dispatch`` (the program
-        call until it returns), ``readback`` (its output to the host)
-        and ``place`` (first tokens sampled, slots filled)."""
+        call until it returns; ``call`` inside it), ``readback`` (its
+        output to the host; ``ready`` and ``fetch`` inside it) and
+        ``place`` (first tokens sampled, slots filled)."""
         layout = self._layout
         with sp.phase("pack"):
             host, placed, n_tokens = layout.pack_prefill(
@@ -1642,7 +1675,7 @@ class ServingEngine(_EngineBase):
         with sp.phase("dispatch"):
             out = self._dispatch(self._prefill, self._prefill_rec,
                                  "serve_prefill", layout.prefill_names,
-                                 host)
+                                 host, sp)
         with sp.phase("readback"):
             tokens, logits = self._read_out(out, sp, "prefill", batch)
         with sp.phase("place"):
@@ -1671,9 +1704,10 @@ class ServingEngine(_EngineBase):
     def _run_decode(self, sp):
         """``sp`` is the open ``serve.decode`` span, split into ``pack``
         (the layout's numpy inputs, n-gram drafting), ``dispatch`` (the
-        program call until it returns), ``readback`` (wait for the
-        device, its output to the host) and ``sample`` (the per-slot
-        accept walk, events, finishing).
+        program call until it returns; ``call`` inside it), ``readback``
+        (``ready``: wait for the device; ``fetch``: its output to the
+        host) and ``sample`` (the per-slot accept walk, events,
+        finishing).
 
         Every live slot has a row of candidates the ONE program scored:
         its pending token and, under speculation, drafts behind it. The
@@ -1688,7 +1722,7 @@ class ServingEngine(_EngineBase):
         with sp.phase("dispatch"):
             out = self._dispatch(self._decode, self._decode_rec,
                                  "serve_decode", layout.decode_names,
-                                 host)
+                                 host, sp)
         with sp.phase("readback"):
             tokens, logits = self._read_out(
                 out, sp, "decode",

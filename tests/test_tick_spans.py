@@ -13,6 +13,7 @@ No number here is a measurement: the clocks are read, not judged.
 
 import glob
 import os
+import time
 
 import numpy as np
 import pytest
@@ -59,6 +60,20 @@ def _serve(eng, n=7, seed=0):
 def _named(records, name, kind="span"):
     return [r for r in records
             if r.get("kind") == kind and r.get("name") == name]
+
+
+# in the order they end: `call` inside `dispatch`, `ready` and `fetch`
+# inside `readback`
+DECODE_PHASES = ["pack", "call", "dispatch", "ready", "fetch", "readback",
+                 "sample"]
+PREFILL_PHASES = ["pack", "call", "dispatch", "ready", "fetch", "readback",
+                  "place"]
+NESTED = ("call", "ready", "fetch")
+
+
+def _top(record):
+    """A program span's phases that do not lie inside another phase."""
+    return {k: v for k, v in record["phases"].items() if k not in NESTED}
 
 
 @pytest.fixture
@@ -206,13 +221,11 @@ class TestServeTick:
         _serve(eng)
         records = ring.records()
         for r in _named(records, "serve.decode"):
-            assert list(r["phases"]) == ["pack", "dispatch", "readback",
-                                         "sample"]
-            assert sum(r["phases"].values()) <= r["dur_s"]
+            assert list(r["phases"]) == DECODE_PHASES
+            assert sum(_top(r).values()) <= r["dur_s"]
         for r in _named(records, "serve.prefill"):
-            assert list(r["phases"]) == ["pack", "dispatch", "readback",
-                                         "place"]
-            assert sum(r["phases"].values()) <= r["dur_s"]
+            assert list(r["phases"]) == PREFILL_PHASES
+            assert sum(_top(r).values()) <= r["dur_s"]
         # a tick's own phases and its two child spans fit inside it
         ticks = _named(records, "serve.tick")
         inner = sum(r["dur_s"] for r in records
@@ -296,9 +309,9 @@ class TestServeTick:
         decodes = _named(records, "serve.decode")
         assert len(prefills) >= 5 and len(decodes) >= 20
         assert {tuple(r["phases"]) for r in prefills} == \
-            {("pack", "dispatch", "readback", "place")}
+            {tuple(PREFILL_PHASES)}
         assert {tuple(r["phases"]) for r in decodes} == \
-            {("pack", "dispatch", "readback", "sample")}
+            {tuple(DECODE_PHASES)}
         info = eng.compiled_step_info()
         assert info["kv_layout"] == layout
         assert (info["prefill_n_traces"], info["n_traces"]) == (1, 1)
@@ -308,6 +321,125 @@ class TestServeTick:
         assert sorted(r["program"] for r in compiles) == \
             ["serve_decode", "serve_prefill"]
         assert not _named(records, "retrace", kind="event")
+
+
+class _Annotations:
+    """Stands in for `jax.profiler.TraceAnnotation`: every enter and exit,
+    in order, with the names open at it."""
+
+    def __init__(self):
+        self.log, self.open = [], []
+        book = self
+
+        class Fake:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                book.log.append(("enter", self.name, tuple(book.open)))
+                book.open.append(self.name)
+                return self
+
+            def __exit__(self, *exc):
+                book.log.append(("exit", self.name, tuple(book.open)))
+                book.open.remove(self.name)
+                return False
+
+        self.cls = Fake
+
+    def entered(self, name):
+        """The names open when `name` was entered, one tuple an entry."""
+        return [opened for kind, n, opened in self.log
+                if kind == "enter" and n == name]
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    book = _Annotations()
+    monkeypatch.setattr(spans, "_ANNOTATION", book.cls)
+    return book
+
+
+@pytest.mark.parametrize("layout", ["ring", "paged"])
+class TestDispatchAndReadbackSplit:
+    """`dispatch` holds the program call as a phase of its own, `readback`
+    the wait for the device and the fetch; the engine thread's CPU time in
+    `dispatch` rides the span. Host code only: the programs trace once."""
+
+    def test_the_split_phases_lie_inside_the_phase_they_split(self, ring,
+                                                             layout):
+        eng = _engine(layout, obs_metrics.MetricsRegistry())
+        futs, _ = _serve(eng)
+        # a sampling request brings the logits through `fetch` too
+        futs.append(eng.submit([1, 2, 3], max_new_tokens=4,
+                               temperature=0.8, seed=1))
+        eng.run_until_idle()
+        assert all(f.result(timeout=5)["tokens"] for f in futs)
+        records = ring.records()
+        spans_ = _named(records, "serve.decode") + \
+            _named(records, "serve.prefill")
+        assert {r["readback"] for r in spans_} == {"tokens", "logits"}
+        for r in spans_:
+            ph = r["phases"]
+            assert 0 <= ph["call"] <= ph["dispatch"]
+            assert ph["ready"] >= 0 and ph["fetch"] >= 0
+            assert ph["ready"] + ph["fetch"] <= ph["readback"]
+            assert isinstance(r["dispatch_cpu_s"], float)
+            assert r["dispatch_cpu_s"] >= 0
+        info = eng.compiled_step_info()
+        assert (info["prefill_n_traces"], info["n_traces"]) == (1, 1)
+
+    def test_the_annotations_nest_as_the_phases_do(self, ring, layout,
+                                                   annotations):
+        eng = _engine(layout, obs_metrics.MetricsRegistry())
+        _serve(eng, n=3)
+        for span in ("serve.decode", "serve.prefill"):
+            calls = annotations.entered(f"{span}.call")
+            readies = annotations.entered(f"{span}.ready")
+            fetches = annotations.entered(f"{span}.fetch")
+            assert calls and len(calls) == len(readies) == len(fetches)
+            assert all(o[-2:] == (span, f"{span}.dispatch") for o in calls)
+            assert all(o[-2:] == (span, f"{span}.readback")
+                       for o in readies + fetches)
+        # within a read-back the wait comes first, then the fetch
+        names = [n for kind, n, _ in annotations.log if kind == "enter"
+                 and n.endswith((".ready", ".fetch"))]
+        assert names[::2] == [n for n in names if n.endswith(".ready")]
+        assert not annotations.open
+
+
+def test_an_idle_serve_loop_waits_under_serve_idle_and_records_nothing(
+        ring, annotations):
+    """The loop's wait for work is the annotation `serve.idle` on the
+    trace's clock and no record: an idle engine does not evict the ring."""
+    eng = _engine("ring", obs_metrics.MetricsRegistry()).start()
+    try:
+        fut = eng.submit([1, 2, 3], max_new_tokens=3)
+        assert fut.result(timeout=60)["tokens"]
+        deadline = time.monotonic() + 30
+        while len(annotations.entered("serve.idle")) < 3 \
+                and time.monotonic() < deadline:
+            time.sleep(0.02)
+    finally:
+        eng.stop()
+    idle = annotations.entered("serve.idle")
+    assert len(idle) >= 3
+    assert all(not any(n.startswith("serve.") for n in o) for o in idle)
+    assert not [r for r in ring.records() if r.get("name") == "serve.idle"]
+    assert _named(ring.records(), "serve.tick")
+
+
+def test_an_annotation_alone_makes_no_record(ring, annotations,
+                                             monkeypatch):
+    with spans.annotation("probe.wait"):
+        pass
+    assert [(k, n) for k, n, _ in annotations.log] == \
+        [("enter", "probe.wait"), ("exit", "probe.wait")]
+    assert ring.records() == []
+    monkeypatch.setattr(spans, "_ANNOTATION", False)
+    with spans.annotation("probe.wait"):
+        pass
+    assert ring.records() == []
 
 
 def test_spans_of_a_running_engine_appear_in_a_profiler_trace(tmp_path):
@@ -334,9 +466,12 @@ def test_spans_of_a_running_engine_appear_in_a_profiler_trace(tmp_path):
                     "events; PERF.md shows the spans on the chip's trace")
     assert {"serve.tick", "serve.tick.reap", "serve.tick.admit",
             "serve.tick.post", "serve.prefill", "serve.prefill.pack",
-            "serve.prefill.dispatch", "serve.prefill.readback",
-            "serve.prefill.place", "serve.decode", "serve.decode.pack",
-            "serve.decode.dispatch", "serve.decode.readback",
+            "serve.prefill.dispatch", "serve.prefill.call",
+            "serve.prefill.readback", "serve.prefill.ready",
+            "serve.prefill.fetch", "serve.prefill.place", "serve.decode",
+            "serve.decode.pack", "serve.decode.dispatch",
+            "serve.decode.call", "serve.decode.readback",
+            "serve.decode.ready", "serve.decode.fetch",
             "serve.decode.sample"} <= names
 
 

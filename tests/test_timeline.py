@@ -181,6 +181,94 @@ class TestFixtureDecomposition:
         assert tl["fractions"]["host"] == 0.0
 
 
+def _write_chip_trace(tmp_path, device_rows, host_rows):
+    """A `.trace.json.gz` as the profiler writes it for a TPU: one process
+    a plane, one thread a row, named by metadata events; rows are
+    ``{row name: [(name, ts_us, dur_us), ...]}``."""
+    import gzip
+    events, tid = [], 0
+    for pid, (plane, rows) in enumerate(
+            (("/device:TPU:0", device_rows), ("/host:CPU", host_rows)), 1):
+        events.append({"ph": "M", "name": "process_name", "pid": pid,
+                       "args": {"name": plane}})
+        for row, evs in rows.items():
+            tid += 1
+            events.append({"ph": "M", "name": "thread_name", "pid": pid,
+                           "tid": tid, "args": {"name": row}})
+            events += [{"ph": "X", "name": n, "pid": pid, "tid": tid,
+                        "ts": ts, "dur": dur} for n, ts, dur in evs]
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    with gzip.open(d / "host.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": events}, f)
+    return prof.parse_trace_events(str(tmp_path))
+
+
+# a decode tick on a v5e, in µs: the `Steps` and `XLA Modules` rows cover
+# the gaps between the ops of the `XLA Ops` row
+CHIP_DEVICE = {
+    "Steps": [("0", 0, 1000)],
+    "XLA Modules": [("jit_decode_body(1)", 100, 300),
+                    ("jit_decode_body(2)", 600, 300)],
+    "XLA Ops": [("fusion.1", 100, 150), ("ring_decode.3", 260, 140),
+                ("fusion.1", 600, 300)]}
+
+
+class TestChipTraceRows:
+    """ROADMAP D12: the repair that lets `analyze` read a chip trace —
+    the device's `Steps` and `XLA Modules` rows are no ops."""
+
+    def test_rows_are_parsed_and_only_ops_are_work(self, tmp_path):
+        events = _write_chip_trace(tmp_path, CHIP_DEVICE, {
+            "python3": [("PjitFunction(decode_body)", 40, 50)]})
+        rows = {e["line"] for e in events if e["lane"] == "device"}
+        assert rows == {"Steps", "XLA Modules", "XLA Ops"}
+        assert {e["name"] for e in timeline.device_ops(events)} == \
+            {"fusion.1", "ring_decode.3"}
+        tl = timeline.analyze(events)
+        # first op to last: 590 of 800 µs ran an op; Steps alone would
+        # have read compute 1.0, idle 0.0
+        assert tl["window_s"] == pytest.approx(800e-6)
+        assert tl["compute_s"] == pytest.approx(590e-6)
+        assert tl["idle_s"] == pytest.approx(210e-6)
+        assert tl["fractions"]["idle"] > 0
+        assert sum(tl["fractions"].values()) == pytest.approx(1.0,
+                                                              abs=1e-6)
+
+    def test_a_serving_tick_reads_its_idle_time_over_the_tick(self,
+                                                              tmp_path):
+        """The serve site's window is the profiled tick; its own
+        annotations are what the host was inside, so a gap under them
+        alone is idle, and under the runtime's work it is host."""
+        events = _write_chip_trace(tmp_path, CHIP_DEVICE, {
+            "python3": [("serve.tick", 0, 1000),
+                        ("serve.decode.sample", 400, 120),
+                        ("PjitFunction(decode_body)", 560, 50)]})
+        window = timeline.host_extent(events, "serve.tick")
+        assert window == (0.0, 1000.0)
+        tl = timeline.analyze(events, window=window, own=("serve.",))
+        # gaps: 0-100, 250-260, 400-600, 900-1000 = 410 µs, of which
+        # 560-600 lie under the runtime's dispatch
+        assert tl["host_s"] == pytest.approx(40e-6)
+        assert tl["idle_s"] == pytest.approx(370e-6)
+        assert tl["fractions"]["idle"] == pytest.approx(0.37)
+        # without `own` the tick's annotation would hide every gap
+        every = timeline.analyze(events, window=window)
+        assert every["idle_s"] == 0.0
+        assert every["host_s"] == pytest.approx(410e-6)
+        assert timeline.host_extent(events, "serve.nothing") is None
+
+    def test_a_device_without_an_op_row_keeps_its_other_rows(self):
+        evs = [{"name": n, "ts": ts, "dur": 10.0, "lane": "device",
+                "pid": 7, "line": row}
+               for n, ts, row in (("step", 0.0, "Steps"),
+                                  ("module", 0.0, "XLA Modules"),
+                                  ("fusion.2", 0.0, "Stream #1"),
+                                  ("fusion.3", 20.0, ""))]
+        assert [e["name"] for e in timeline.device_ops(evs)] == \
+            ["fusion.2", "fusion.3"]
+
+
 class TestWaterfall:
     def test_attributes_the_gap(self):
         tl = timeline.analyze(prof.parse_trace_events(FIXTURE),
