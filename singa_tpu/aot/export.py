@@ -564,7 +564,10 @@ def serving_program_avals(engine):
                jax.ShapeDtypeStruct((B,), i32),
                jax.ShapeDtypeStruct((B,), i32),
                jax.ShapeDtypeStruct((B,), np.dtype(bool)))
+    # (prev_tokens, tokens, fresh, positions, active): RingLayout.programs
     decode = (Pa, Ca, jax.ShapeDtypeStruct((W,), i32),
+              jax.ShapeDtypeStruct((W,), i32),
+              jax.ShapeDtypeStruct((W,), np.dtype(bool)),
               jax.ShapeDtypeStruct((W,), i32),
               jax.ShapeDtypeStruct((W,), np.dtype(bool)))
     return prefill, decode

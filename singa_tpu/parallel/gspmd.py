@@ -502,8 +502,9 @@ def serving_arg_specs(part, kv_layout):
     return {
         # (tokens, lengths, slot_ids, valid)
         "prefill": (P(), P(), P(), P()),
-        # (tokens (W,), positions (W,), active (W,))
-        "decode": (P(b), P(b), P(b)),
+        # (prev_tokens (W,): the last call's tokens_out, tokens (W,),
+        #  fresh (W,), positions (W,), active (W,))
+        "decode": (P(), P(b), P(b), P(b), P(b)),
         "tokens_out": P(),
     }
 

@@ -21,7 +21,11 @@ blackbox):
     finished sequences free their slot mid-batch and new requests
     refill it, so the program NEVER retraces —
     ``compiled_step_info()["n_traces"]`` is pinned at 1 by CI exactly
-    like the train step's retrace guard.
+    like the train step's retrace guard. On a ring, where every live
+    slot is greedy and no pass is pending, tick n + 1 is dispatched on
+    tick n's tokens, still on the device, before tick n is read, so the
+    device runs through the host's work between two ticks
+    (``_run_decode``; docs/serving.md, "Decode ticks dispatched ahead").
 
   **The tick is written once; the KV format is not its business.** The
   engine owns the slot table, the queue, sampling (a greedy row's
@@ -236,10 +240,16 @@ class _EngineBase:
             "included — this is what the caller feels)")
         self._tok_lat = self._reg.histogram(
             "serve_token_seconds",
-            "the decode part of one continuous-batching tick: input "
-            "packing, the program, the read-back of its tokens (of its "
-            "logits too in a tick that serves a sampling request) and "
-            "the per-slot walk that places them (the serve.decode span)")
+            "the host's part of one delivered decode tick (the "
+            "serve.decode span): input packing and the program call, the "
+            "read-back of its tokens (of its logits too in a tick that "
+            "serves a sampling request) and the per-slot walk that "
+            "places them. Where the next tick is dispatched before this "
+            "one is read, the span holds that dispatch and this tick's "
+            "read; a span that only reads a tick in flight (before a "
+            "pass or a serial tick, or the last of a stream) is a tick "
+            "too, so the sum is the decode's host time and the count "
+            "the ticks delivered")
 
     # -- admission ---------------------------------------------------------
     def _admit(self, req):
@@ -299,6 +309,20 @@ class _EngineBase:
 
     def _fail_inflight(self, error):
         raise NotImplementedError
+
+    def _settle(self):
+        """Read what a tick left running on the device (nothing here)."""
+
+    def _fault_point(self):
+        """The tick's fault hook. It fires BEFORE any state mutates, so
+        a retry replays the tick cleanly: nothing delivered twice,
+        nothing dropped. A tick still in flight is read first, so the
+        retry starts from a settled engine."""
+        try:
+            self.faults.on_step(self._tick_count)
+        except FaultInjected:
+            self._settle()
+            raise
 
     def _run_tick(self):
         """One scheduler tick, every Nth one profiled: the profiled
@@ -399,10 +423,7 @@ class _EngineBase:
                 continue
             self._idle_evt.clear()
             try:
-                # the fault hook fires BEFORE any state mutates, so a
-                # retry replays the tick cleanly: nothing delivered
-                # twice, nothing dropped
-                self.faults.on_step(self._tick_count)
+                self._fault_point()
                 self._run_tick()
                 self._tick_count += 1
                 consecutive = 0
@@ -516,7 +537,7 @@ class _EngineBase:
                                "background loop is running")
         if not self._busy():
             return False
-        self.faults.on_step(self._tick_count)
+        self._fault_point()
         self._run_tick()
         self._tick_count += 1
         return True
@@ -769,6 +790,7 @@ class ServingEngine(_EngineBase):
                 return decode_raw(P, state, *host)
 
         jit_kw = {"prefill": {}, "decode": {}}
+        tok_sh = None
         if self._part is not None:
             # annotate the named state + KV layout once, jit the same
             # pure bodies: XLA's SPMD partitioner inserts the
@@ -791,6 +813,23 @@ class ServingEngine(_EngineBase):
                                                 for s in io[program])),
                     out_shardings=(c_sh, (tok_sh,)))
         self._hbm_dev = _perf.first_jax_device(self._cache)
+        # the record of the decode tick dispatched before the one it
+        # follows is read (``_run_decode``). A layout whose decode takes
+        # an input from the last call starts it from an array placed as
+        # the calls place their tokens, so every call passes one kind of
+        # array there: committed to a device only where the programs'
+        # other arguments are. A call with one committed argument
+        # returns committed arrays, and the state and the tokens it
+        # passes on would then give each program a second executable
+        self._inflight = None
+        held = jax.tree_util.tree_leaves((self._P, self._cache))
+        if tok_sh is not None:
+            layout.bind_device(lambda a: jax.device_put(a, tok_sh))
+        elif any(getattr(a, "committed", False) for a in held):
+            layout.bind_device(
+                lambda a: jax.device_put(a, self._hbm_dev))
+        else:
+            layout.bind_device(jax.device_put)
         # the KV state (ring cache or block pool) is DONATED: the one
         # large serving buffer is updated in place by XLA instead of
         # doubling per tick
@@ -863,6 +902,15 @@ class ServingEngine(_EngineBase):
         self._decode_steps = self._reg.counter(
             "serve_decode_steps_total", "continuous-batching decode "
             "ticks executed")
+        self._decode_ticks = self._reg.counter(
+            "serve_decode_ticks_total", "decode program calls by how "
+            "they were dispatched: ahead, before the call they follow "
+            "was read, on its tokens on the device (reason none); or "
+            "serial, because nothing was in flight to follow (first), a "
+            "live request samples on the host (sampling), the layout's "
+            "rows hold candidates (candidates), a hand-off, inject, "
+            "transfer or checkpoint pass reads the slots (pass) or the "
+            "engine drains (drain)", labels=("mode", "reason"))
         self._prefills = self._reg.counter(
             "serve_prefill_total", "prompts prefilled into a slot")
         self._prefill_tok = self._reg.counter(
@@ -1188,12 +1236,23 @@ class ServingEngine(_EngineBase):
         ``kill_mid_handoff`` fire on the sealed frame here, exactly
         like wire sends). Sharded engines refuse typed — each device
         holds only a KV slice, so recompute re-dispatch is their
-        failover path."""
+        failover path. It reads the tick in flight first, which places
+        tokens and may finish slots, so it runs on the serve loop's own
+        thread (its passes) or in :meth:`step` mode, never beside a
+        running loop."""
+        if self._thread is not None and \
+                threading.current_thread() is not self._thread:
+            raise RuntimeError("snapshot_slot runs on the serve loop or "
+                               "in step() mode; the background loop is "
+                               "running")
         if self.sharded:
             raise HandoffRefused(
                 "sharded engines cannot snapshot a slot: each device "
                 "holds only its slice of the KV state — re-dispatch "
                 "(recompute) is the sharded failover path")
+        # the rows and the request's stream must agree: a tick in
+        # flight has run the slot's pending token already
+        self._settle()
         if self._slots[i] is None:
             raise ValueError(f"slot {i} is empty")
         snap = self._snapshot_slot(i)
@@ -1433,7 +1492,8 @@ class ServingEngine(_EngineBase):
     # -- loop internals ----------------------------------------------------
     def _busy(self):
         return len(self.queue) > 0 or len(self._injects) > 0 or any(
-            s is not None for s in self._slots)
+            s is not None for s in self._slots) or \
+            self._inflight is not None
 
     def _release_blocks(self, slot):
         """Return what a finished/failed sequence had reserved to its
@@ -1447,6 +1507,7 @@ class ServingEngine(_EngineBase):
         return self.active_slots()
 
     def _fail_inflight(self, error):
+        self._inflight = None       # its rows' requests fail just below
         for i, slot in enumerate(self._slots):
             if slot is not None:
                 self._slots[i] = None
@@ -1532,6 +1593,14 @@ class ServingEngine(_EngineBase):
     def _tick_body(self, tick):
         now = time.monotonic()
         tick_t0 = now
+        # a pass reads or rewrites the slots' state and their rows, so a
+        # decode tick in flight is read before any runs
+        checkpoint = bool(self.snapshot_every) and \
+            self._tick_count % self.snapshot_every == 0
+        passes = self._draining or bool(self._injects) or \
+            self._transfer is not None or checkpoint
+        if passes:
+            self._settle()
         # 0) deadline drain: migrate what the budget cannot cover;
         #    then place validated snapshot injects into free slots
         if self._draining and self._handoff is not None:
@@ -1579,24 +1648,22 @@ class ServingEngine(_EngineBase):
             with tick.phase("transfer"):
                 self._transfer_pass()
 
-        # 3) decode: one token for EVERY active slot, one fixed program
+        # 3) decode: one token for EVERY active slot, one fixed program;
+        #    where nothing needs the host between two ticks, the next is
+        #    dispatched before this one is read
         active = tick.attrs["active"] = self.active_slots()
-        if active:
-            t0 = time.perf_counter()
-            with _spans.span("serve.decode") as sp:
-                self._run_decode(sp)
-            # a PROFILED tick's dispatch runs under an active trace:
-            # its inflated latency must not read as an SLO regression
-            # (the sampling cost is serve_profile_capture_seconds)
-            if not self._profiling_now:
-                self._tok_lat.observe(time.perf_counter() - t0)
-            self._decode_steps.inc()
+        if active or self._inflight is not None:
+            reason = self._serial_reason(passes)
+            if reason is not None:
+                self._settle()
+            if reason is None or self.active_slots():
+                with self._decode_span() as sp:
+                    self._run_decode(sp, reason)
         with tick.phase("post"):
             self._occupancy.set(self.active_slots())
             self._sample_hbm()
             # 4) cadence crash armor + the drain pass's tick-cost EWMA
-            if self.snapshot_every and \
-                    self._tick_count % self.snapshot_every == 0:
+            if checkpoint:
                 self._checkpoint_inflight()
             dt = time.monotonic() - tick_t0
             self._tick_ewma = dt if not self._tick_ewma \
@@ -1609,7 +1676,8 @@ class ServingEngine(_EngineBase):
         ``call``, and the engine thread's CPU time here goes to its
         attr ``dispatch_cpu_s`` (against the phase's wall time: the
         thread ran, or waited for the interpreter lock or inside the
-        call). A call that traced (the ``n_traces`` delta) is
+        call; summed where a span dispatches twice). A call that traced
+        (the ``n_traces`` delta) is
         attributed: a first compile, or the retrace that breaks the
         one-trace contract, with what changed."""
         cpu0 = time.thread_time()
@@ -1621,8 +1689,16 @@ class ServingEngine(_EngineBase):
         if rec["n_traces"] > n0:
             _attribute_trace(rec, self._reg, program, list(host), names,
                              t0, cc0)
-        sp.attrs["dispatch_cpu_s"] = time.thread_time() - cpu0
+        sp.attrs["dispatch_cpu_s"] = sp.attrs.get("dispatch_cpu_s", 0.0) \
+            + time.thread_time() - cpu0
         return out
+
+    def _reads(self, out, sampling):
+        """The parts of a program's ``out`` a tick brings to the host:
+        ``(tokens, stats or None, logits or None)``."""
+        return (out[0],
+                out[-1] if self._record_stats is not None else None,
+                out[1] if sampling else None)
 
     def _read_out(self, out, sp, program, requests):
         """What the tick needs of a program's ``out`` (``(tokens,
@@ -1644,9 +1720,7 @@ class ServingEngine(_EngineBase):
         sampling = any(r.temperature != 0 for r in requests)
         what = sp.attrs["readback"] = "logits" if sampling else "tokens"
         self._readbacks.inc(program=program, what=what)
-        read = (out[0],
-                out[-1] if self._record_stats is not None else None,
-                out[1] if sampling else None)
+        read = self._reads(out, sampling)
         with sp.phase("ready"):
             for leaf in jax.tree.leaves(read):
                 leaf.copy_to_host_async()
@@ -1701,32 +1775,119 @@ class ServingEngine(_EngineBase):
                 if done:
                     self._finish_slot(slot_idx)
 
-    def _run_decode(self, sp):
+    def _serial_reason(self, passes):
+        """Why the next decode tick cannot be dispatched before the one
+        in flight is read, or None: its rows hold candidates the host
+        has to walk, a live request samples from logits on the host, the
+        engine drains, or a pass (``passes``) reads the slots."""
+        if self._layout.candidate_axis:
+            return "candidates"
+        if any(s is not None and s["req"].temperature != 0
+               for s in self._slots):
+            return "sampling"
+        if self._draining:
+            return "drain"
+        return "pass" if passes else None
+
+    @contextlib.contextmanager
+    def _decode_span(self):
+        """One ``serve.decode`` span: the host's share of one delivered
+        decode tick, observed into ``serve_token_seconds``."""
+        t0 = time.perf_counter()
+        with _spans.span("serve.decode", ahead=0) as sp:
+            yield sp
+        # a PROFILED tick's dispatch runs under an active trace: its
+        # inflated latency must not read as an SLO regression (the
+        # sampling cost is serve_profile_capture_seconds)
+        if not self._profiling_now:
+            self._tok_lat.observe(time.perf_counter() - t0)
+
+    def _settle(self):
+        """Read the decode tick in flight, if one is, in a span of its
+        own (``readback`` and ``sample``): before a pass, a serial tick,
+        a snapshot or a retry."""
+        rec, self._inflight = self._inflight, None
+        if rec is not None:
+            with self._decode_span() as sp:
+                self._land(rec, sp)
+
+    def _run_decode(self, sp, reason):
         """``sp`` is the open ``serve.decode`` span, split into ``pack``
         (the layout's numpy inputs, n-gram drafting), ``dispatch`` (the
         program call until it returns; ``call`` inside it), ``readback``
         (``ready``: wait for the device; ``fetch``: its output to the
         host) and ``sample`` (the per-slot accept walk, events,
-        finishing).
+        finishing). It delivers one tick.
 
-        Every live slot has a row of candidates the ONE program scored:
-        its pending token and, under speculation, drafts behind it. The
-        walk emits the longest prefix of drafts matching what was
-        sampled — each emitted token EXACTLY what sequential greedy
-        decoding would have produced (the CI parity invariant) — and at
-        one candidate it is one token a slot."""
+        ``reason`` None: the tick in flight is read only after the next
+        is dispatched (span attr ``ahead`` 1), which takes each row's
+        token from it on the device, so the device runs through the
+        host's read, walk and packing. The first tick after an idle or
+        serial one is dispatched first. A request whose EOS the read
+        shows has run one row more, which nothing reads. Otherwise
+        (:meth:`_serial_reason`) the tick is dispatched and read."""
+        if reason is None:
+            rec = self._inflight or self._launch(sp, "serial", "first")
+            self._inflight = self._launch(sp, "ahead", "none", rec)
+            sp.attrs["ahead"] = int(self._inflight is not None)
+        else:
+            rec = self._launch(sp, "serial", reason)
+        self._land(rec, sp)
+
+    def _launch(self, sp, mode, reason, chained=None):
+        """Pack and dispatch one decode tick over the live slots; its
+        record for :meth:`_land`, or None where no row is due.
+        ``chained``: the record of the tick in flight, whose tokens the
+        rows of its requests take; a request that tick brings to
+        ``max_new_tokens`` runs no further row."""
+        import jax
+        last = chained["reqs"] if chained is not None else {}
+        slots = [None if s is None or (
+            last.get(i) is s["req"]
+            and len(s["req"].tokens) + 1 >= s["req"].max_new_tokens)
+            else s for i, s in enumerate(self._slots)]
+        if not any(slots):
+            return None
         layout = self._layout
+        attrs = {}
         with sp.phase("pack"):
             host, rows = layout.pack_decode(
-                self._slots, sp.attrs, not self._spec_throttled)
+                slots, attrs, not self._spec_throttled, last)
+        reqs = {i: s["req"] for i, s in enumerate(slots) if s is not None}
+        sampling = any(r.temperature != 0 for r in reqs.values())
         with sp.phase("dispatch"):
             out = self._dispatch(self._decode, self._decode_rec,
                                  "serve_decode", layout.decode_names,
                                  host, sp)
+            layout.decoded(out)
+            if not (sampling or self.sharded):
+                out = (out[0], None, *out[2:])  # logits nothing reads
+            # the tokens cross as soon as the device has them
+            for leaf in jax.tree.leaves(self._reads(out, sampling)):
+                leaf.copy_to_host_async()
+        self._decode_steps.inc()
+        self._decode_ticks.inc(mode=mode, reason=reason)
+        return {"out": out, "reqs": reqs, "rows": rows, "attrs": attrs}
+
+    def _land(self, rec, sp):
+        """Read one dispatched tick and place its tokens on the slots
+        that still hold the requests it ran; a request that finished
+        meanwhile (on EOS, a deadline, a hand-off) has its row dropped.
+        The span takes the tick's own attrs (what it read of the
+        rings, the adapter's counts).
+
+        Every row has candidates the ONE program scored: its pending
+        token and, under speculation, drafts behind it. The walk emits
+        the longest prefix of drafts matching what was sampled — each
+        emitted token EXACTLY what sequential greedy decoding would have
+        produced (the CI parity invariant) — and at one candidate it is
+        one token a slot."""
+        layout = self._layout
+        reqs, rows = rec["reqs"], rec["rows"]
+        sp.attrs.update(rec["attrs"])
         with sp.phase("readback"):
-            tokens, logits = self._read_out(
-                out, sp, "decode",
-                (s["req"] for s in self._slots if s is not None))
+            tokens, logits = self._read_out(rec["out"], sp, "decode",
+                                            reqs.values())
             if not layout.candidate_axis:
                 tokens = tokens[:, None]
                 if logits is not None:
@@ -1734,10 +1895,10 @@ class ServingEngine(_EngineBase):
         with sp.phase("sample"):
             at = time.monotonic()
             trace = self._trace_requests
-            for i, slot in enumerate(list(self._slots)):
-                if slot is None:
+            for i, req in reqs.items():
+                slot = self._slots[i]
+                if slot is None or slot["req"] is not req:
                     continue
-                req = slot["req"]
                 row = rows.get(i) if rows is not None else None
                 cnt = 1 if row is None else len(row)
                 emitted = 0

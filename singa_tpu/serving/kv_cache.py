@@ -957,7 +957,8 @@ class KVLayout:
     layout fills in ``name``, the programs' argument names (for the
     compile/retrace events), ``init_state``, ``pack_prefill``,
     ``pack_decode``, ``geometry``, ``info``, ``read_slot`` and
-    ``write_slot``."""
+    ``write_slot``; one whose decode takes an input from the last call
+    keeps it through ``bind_device`` and ``decoded``."""
 
     # the attributes ServingEngine republishes (None: no pool here)
     mgr = block_size = n_blocks = max_blocks = spill_tier = None
@@ -990,6 +991,14 @@ class KVLayout:
             parts.append("stats")
         return "+".join(parts)
 
+    def bind_device(self, put):
+        """``put(array)``: how the engine places an array on the device
+        beside its programs' arguments, once at build (nothing kept
+        here)."""
+
+    def decoded(self, out):
+        """A decode call's ``out`` as dispatched (nothing kept here)."""
+
     def never_fits(self, n_prompt, max_new):
         """The typed error for a request no state of the layout could
         take, or None."""
@@ -1021,12 +1030,38 @@ class RingLayout(KVLayout):
 
     name = "ring"
     prefill_names = ("tokens", "lengths", "slot_ids", "valid")
-    decode_names = ("tokens", "positions", "active")
+    decode_names = ("prev_tokens", "tokens", "fresh", "positions", "active")
     _programs = ("prefill_fn", "decode_fn")
     lengths = None
     n_state = 0
     _latent = ()
     _prefill_rows = None
+
+    def programs(self, sharded):
+        """The adapter's two programs (:meth:`KVLayout.programs`), decode
+        taking its input tokens from two places: ``tokens`` from the host
+        where ``fresh`` is set, else ``prev_tokens``, the tokens the last
+        decode call put first, which stay on the device. So a tick can be
+        dispatched before the one it follows has been read, through the
+        one executable."""
+        prefill, decode = super().programs(sharded)
+
+        def decode_chained(P, state, prev_tokens, tokens, fresh, *rest):
+            return decode(P, state, jnp.where(fresh, tokens, prev_tokens),
+                          *rest)
+
+        return prefill, decode_chained
+
+    def bind_device(self, put):
+        """``prev_tokens`` before the first decode call: zeros, placed
+        as the calls place their tokens, so every call passes one kind
+        of array there."""
+        self._prev_tokens = put(np.zeros((self.slots,), np.int32))
+
+    def decoded(self, out):
+        """Keep the call's tokens, on the device, for the next call's
+        ``prev_tokens``."""
+        self._prev_tokens = out[0]
 
     def init_state(self):
         state = self.adapter.init_cache(self.slots, self.max_len)
@@ -1127,19 +1162,28 @@ class RingLayout(KVLayout):
         return (tokens, lengths, slot_ids, valid), placed, \
             int(lengths.sum())
 
-    def pack_decode(self, slots, attrs, draft):
-        """``(program arrays, None)``: one pending token a live slot,
-        no candidate rows. What the tick has to read of the rings goes
-        on the span (``attrs``) and the two counters."""
+    def pack_decode(self, slots, attrs, draft, chained=None):
+        """``(program arrays, None)``: one pending token a live slot, no
+        candidate rows. A slot whose request ``chained``
+        (slot -> request) names had a row in the call still in flight
+        takes that call's token, one position on; every other row is
+        ``fresh``. What the tick has to read of the rings goes on the
+        span (``attrs``) and the two counters."""
         W = self.slots
         tokens = np.zeros((W,), np.int32)
+        fresh = np.ones((W,), bool)
         positions = np.zeros((W,), np.int32)
         active = np.zeros((W,), bool)
         for i, slot in enumerate(slots):
-            if slot is not None:
+            if slot is None:
+                continue
+            active[i] = True
+            if chained and chained.get(i) is slot["req"]:
+                fresh[i] = False
+                positions[i] = slot["pos"] + 1
+            else:
                 tokens[i] = slot["tok"]
                 positions[i] = slot["pos"]
-                active[i] = True
         if self.lengths is not None:
             rows = np.minimum(positions[active, None] + 1, self.lengths)
             attrs["kv_rows"] = int((rows * self._readers).sum())
@@ -1150,7 +1194,7 @@ class RingLayout(KVLayout):
             if self.n_state:
                 attrs["state_slots"] = int(active.sum()) * self.n_state
                 self._state_steps.inc(attrs["state_slots"])
-        return (tokens, positions, active), None
+        return (self._prev_tokens, tokens, fresh, positions, active), None
 
     def geometry(self):
         g = {"layout": self.name}
@@ -1351,11 +1395,12 @@ class PagedLayout(KVLayout):
         return (tables, tokens, starts, lengths, valid), placed, \
             int(lengths.sum())
 
-    def pack_decode(self, slots, attrs, draft):
+    def pack_decode(self, slots, attrs, draft, chained=None):
         """``(program arrays, rows)``: ``rows`` is None at width 1;
         under speculation it maps a slot to its candidate row, the
         pending token plus up to ``spec_width - 1`` n-gram drafts
-        (none while ``draft`` is off)."""
+        (none while ``draft`` is off). A verify tick is never dispatched
+        before the one it follows is read, so ``chained`` is empty."""
         W, K = self.slots, self.spec_width
         tokens = np.zeros((W, K), np.int32)
         positions = np.zeros((W,), np.int32)

@@ -583,6 +583,42 @@ class TestServingAot:
         assert info["n_traces"] == 1
         assert info["prefill_n_traces"] == 1
 
+    def test_decode_artifact_takes_the_chained_inputs(self, dev, tmp_path):
+        """The ring decode program's call, as the artifact records it:
+        the last call's tokens (on the device), the host's tokens, the
+        ``fresh`` mask, positions, active. Loaded, the one deserialized
+        executable serves ticks dispatched ahead and serial ones (a
+        drain), token for token what the exporting engine served."""
+        store = AotStore(str(tmp_path))
+        geometry = dict(slots=2, max_len=48, prefill_len=8)
+        e1 = self._model(dev).compile_serving(
+            registry=obs_metrics.MetricsRegistry(), **geometry)
+        e1.export_aot(store)
+        leaves = store.read_manifest("serve_decode")["avals"]["leaves"]
+        assert leaves[-5:] == [[[2], "int32"], [[2], "int32"],
+                               [[2], "bool"], [[2], "int32"],
+                               [[2], "bool"]]
+
+        def serve(eng):
+            futs = [eng.submit(p, max_new_tokens=7)
+                    for p in ([1, 2, 3], [4, 5], [6, 7, 8, 9])]
+            for _ in range(4):
+                eng.step()
+            assert eng.drain(timeout=30)
+            ticks = eng._reg.get("serve_decode_ticks_total")
+            assert ticks.value(mode="ahead", reason="none") > 0
+            assert ticks.value(mode="serial", reason="drain") > 0
+            return [f.result()["tokens"] for f in futs]
+
+        e2 = self._model(dev).compile_serving(
+            aot_store=store, registry=obs_metrics.MetricsRegistry(),
+            **geometry)
+        assert e2.compiled_step_info()["aot"] == {
+            "serve_prefill": "loaded", "serve_decode": "loaded"}
+        assert serve(e2) == serve(e1)
+        info = e2.compiled_step_info()
+        assert (info["prefill_n_traces"], info["n_traces"]) == (1, 1)
+
     def test_batch_engine_roundtrip(self, dev, tmp_path):
         """The stateless batch forward exports/loads too: same
         honored-or-refused contract, parity, n_traces reads 1."""

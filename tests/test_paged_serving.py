@@ -47,6 +47,9 @@ def _reg():
 
 def tiny_lm(vocab=19, d_model=16, heads=2, layers=2, max_len=64,
             seed=0):
+    """The same weights for one seed whatever ran before in the process:
+    they are drawn from the device's key, which is seeded too."""
+    DEV.set_rand_seed(seed)
     np.random.seed(seed)
     m = transformer.TransformerLM(vocab, d_model=d_model, n_heads=heads,
                                   n_layers=layers, max_len=max_len,
@@ -135,27 +138,49 @@ class TestPagedParity:
         """The engine's one decode walk: without speculation a paged
         slot's candidate row is its pending token alone, so the same
         requests take the same number of decode ticks (and of prefill
-        batches) on both layouts, token for token."""
+        batches) on both layouts, token for token, where the ring reads
+        each tick before it dispatches the next, as a paged verify tick
+        is. A ring that dispatches ticks ahead gives the same tokens and
+        prefill batches; a prompt it admits with a tick in flight joins
+        the tick after, so it takes a tick or more beyond."""
         m = tiny_lm(seed=3)
         rng = np.random.RandomState(11)
         work = [(rng.randint(0, 19, (int(rng.randint(1, 8)),)),
                  int(rng.randint(2, 9))) for _ in range(7)]
-        seen = {}
-        for layout, kw in (("ring", {}), ("paged", dict(
+        seen, modes = {}, {}
+        for name, kw in (("ring", {}), ("serial ring", {}), ("paged", dict(
                 kv_layout="paged", kv_block_size=4))):
             reg = _reg()
             eng = m.compile_serving(slots=3, max_len=32, prefill_len=8,
                                     prefill_batch=2, registry=reg, **kw)
+            if name == "serial ring":
+                # every tick read before the next is dispatched, as
+                # before a pass
+                eng._serial_reason = lambda passes: "pass"
             futs = [eng.submit(p, max_new_tokens=n, temperature=0.0)
                     for p, n in work]
             ticks = eng.run_until_idle()
-            seen[layout] = (
+            seen[name] = (
                 [f.result(timeout=5)["tokens"] for f in futs], ticks,
                 reg.get("serve_decode_steps_total").value(),
                 reg.get("serve_prefill_total").value(),
                 reg.get("serve_tokens_total").value())
-        assert seen["ring"] == seen["paged"]
-        assert seen["ring"][2] > 0
+            counted = reg.get("serve_decode_ticks_total")
+            modes[name] = {k: counted.value(mode=k[0], reason=k[1])
+                           for k in (("serial", "candidates"),
+                                     ("serial", "pass"),
+                                     ("serial", "first"),
+                                     ("ahead", "none")) if counted.value(
+                                         mode=k[0], reason=k[1])}
+            assert counted.total() == seen[name][2]
+        assert seen["serial ring"] == seen["paged"]
+        assert seen["paged"][2] > 0
+        ring, paged = seen["ring"], seen["paged"]
+        assert (ring[0], ring[3], ring[4]) == (paged[0], paged[3], paged[4])
+        assert ring[1] == ring[2] >= paged[1] == paged[2]
+        assert set(modes["paged"]) == {("serial", "candidates")}
+        assert set(modes["serial ring"]) == {("serial", "pass")}
+        assert set(modes["ring"]) == {("serial", "first"), ("ahead", "none")}
 
     def test_int8_kv_paged_matches_int8_ring(self):
         """int8 KV scales ride the block pool: per-(block, offset)
