@@ -1347,9 +1347,13 @@ def checkpoint(block, x):
     aux_layers = _aux_layers(block)
 
     def run(x_arr, *param_arrs):
-        backup = [t.data for t in tensors]
+        backup = [(t.data, t.requires_grad) for t in tensors]
         for t, a in zip(tensors, param_arrs):
-            t.data = a
+            # the block's own ops take no vjp: the checkpointed op's vjp
+            # differentiates the whole block, and a vjp nested in it would
+            # differentiate a custom_vjp's forward rule (a Pallas call
+            # has no JVP)
+            t.data, t.requires_grad = a, False
         try:
             xin = Tensor(data=x_arr, device=x.device, requires_grad=False)
             out = block(xin)
@@ -1364,8 +1368,8 @@ def checkpoint(block, x):
                 return (out.data,) + auxs
             return out.data
         finally:
-            for t, a in zip(tensors, backup):
-                t.data = a
+            for t, (a, grad) in zip(tensors, backup):
+                t.data, t.requires_grad = a, grad
 
     op = _Checkpointed(run)
     key = x.device.rand_key()

@@ -80,13 +80,16 @@ class Device:
     def _heal_key(self):
         """Self-heal if a traced consumer leaked its in-trace key into this
         host-side state (the stored key would be a dead tracer): hops to
-        a fresh per-device stream (device identity + leak counter)."""
+        a fresh per-device stream (device identity + leak counter), placed
+        where a seeded key is: a compiled step given a key of another
+        placement than the one it hands back compiles a second
+        executable."""
         if isinstance(self._key, jax.core.Tracer) and \
                 not isinstance(jnp.zeros(()), jax.core.Tracer):
             self._leaks = getattr(self, "_leaks", 0) + 1
-            self._key = jax.random.fold_in(
+            self._key = jax.device_put(jax.random.fold_in(
                 jax.random.PRNGKey(id(self) & 0x7fffffff),
-                0x5eed + self._leaks)
+                0x5eed + self._leaks), self.jax_device)
 
     def rand_key(self):
         """Split and return a fresh PRNG key (functional curand

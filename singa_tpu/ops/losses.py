@@ -9,6 +9,10 @@ rescans, rebuilding each chunk's probabilities from the saved (O(tokens))
 logsumexp, exactly the flash-attention residual trick applied to the
 classifier head. No reference counterpart (the reference computes full
 logits then CrossEntropyFwd, src/model/operation/../autograd).
+:func:`fused_ce_rows` is the same head returning each token's
+cross-entropy (the mean form is its mean), for losses that weight tokens
+unequally — a looped model's exits, weighted by each token's exit
+probability (models/ouro.py).
 
 Vocab-parallel: pass ``axis_name`` when the head weight's columns are
 sharded over a mesh axis (``ColumnParallelLinear``-style). Each rank
@@ -79,6 +83,14 @@ def _zero_ct(x):
 
 
 def _fwd(h, W, b, ids, chunk, axis_name=None):
+    lse, tgt = _scan_lse(h, W, b, ids, chunk, axis_name)
+    loss = jnp.mean(lse - tgt)
+    return loss, (h, W, b, ids, lse)
+
+
+def _scan_lse(h, W, b, ids, chunk, axis_name):
+    """Per row: (logsumexp of the logits, the target's logit), by the
+    chunked online logsumexp over the vocabulary."""
     sharded, offset = _shard_ctx(axis_name, W)
     hf = h.astype(jnp.float32)
     idi = ids.astype(jnp.int32) - offset        # local coords of targets
@@ -117,20 +129,26 @@ def _fwd(h, W, b, ids, chunk, axis_name=None):
         l = lax.psum(l * jnp.exp(m - m_all), axis_name)
         tgt = lax.psum(tgt, axis_name)          # exactly one rank hit
         m = m_all
-    lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    loss = jnp.mean(lse - tgt)
-    return loss, (h, W, b, ids, lse)
+    return m + jnp.log(jnp.maximum(l, 1e-30)), tgt
 
 
 def _bwd(chunk, axis_name, res, g):
     h, W, b, ids, lse = res
+    gN = (g / h.shape[0]).astype(jnp.float32)
+    return _scan_grads(h, W, b, ids, lse, gN, chunk, axis_name) \
+        + (_zero_ct(ids),)
+
+
+def _scan_grads(h, W, b, ids, lse, gN, chunk, axis_name):
+    """(dh, dW, db) by a second scan over the vocabulary chunks, each
+    chunk's softmax rebuilt from the saved ``lse``. ``gN`` is the
+    cotangent of each row's cross-entropy: a scalar shared by every row
+    (the mean form) or an (N, 1) column (the per-row form)."""
     sharded, offset = _shard_ctx(axis_name, W)
     idi = ids.astype(jnp.int32) - offset
     hf = h.astype(jnp.float32)
     Wc, bc, n, pad = _chunks(W.astype(jnp.float32),
                              b.astype(jnp.float32), chunk)
-    N = hf.shape[0]
-    gN = (g / N).astype(jnp.float32)
 
     owned = (idi >= 0) & (idi < W.shape[1])   # same bound as forward
 
@@ -158,11 +176,40 @@ def _bwd(chunk, axis_name, res, g):
     dW = dWks.transpose(1, 0, 2).reshape(W.shape[0],
                                          n * chunk)[:, :V]
     db = dbks.reshape(n * chunk)[:V]
-    return (dh.astype(h.dtype), dW.astype(W.dtype), db.astype(b.dtype),
-            _zero_ct(ids))
+    return dh.astype(h.dtype), dW.astype(W.dtype), db.astype(b.dtype)
 
 
 fused_ce_head.defvjp(_fwd, _bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def fused_ce_rows(h, W, b, ids, chunk=8192):
+    """Each row's cross-entropy of ``softmax(h @ W + b)`` against ``ids``:
+    (N,) float32, the per-token form of :func:`fused_ce_head` (whose mean
+    it is), for a loss that weights tokens unequally. Same scans, same
+    memory; the backward takes a cotangent a row. ``b`` may be None (a
+    head without a bias; no gradient is returned for it). The head is
+    whole on this device (no vocab-parallel form)."""
+    return _fwd_rows(h, W, b, ids, chunk)[0]
+
+
+def _bias(b, W):
+    return jnp.zeros((W.shape[1],), W.dtype) if b is None else b
+
+
+def _fwd_rows(h, W, b, ids, chunk):
+    lse, tgt = _scan_lse(h, W, _bias(b, W), ids, chunk, None)
+    return lse - tgt, (h, W, b, ids, lse)
+
+
+def _bwd_rows(chunk, res, g):
+    h, W, b, ids, lse = res
+    dh, dW, db = _scan_grads(h, W, _bias(b, W), ids, lse,
+                             g.astype(jnp.float32)[:, None], chunk, None)
+    return dh, dW, None if b is None else db, _zero_ct(ids)
+
+
+fused_ce_rows.defvjp(_fwd_rows, _bwd_rows)
 
 
 class _FusedCEHead(Operator):

@@ -191,3 +191,17 @@ class TestTraceOnce:
             m(x4, y4)
             m(x2, y2)
         assert len(log) == after_new, "a cached signature retraced"
+
+    def test_a_key_healed_after_a_traced_draw_keeps_its_device(self):
+        """A draw inside a trace (a rematerialised block in the dry run)
+        leaves a dead key behind; the key healed from it lies where a
+        seeded key lies, so the compiled step is handed a key of the
+        placement it hands back and compiles once."""
+        dev = device.create_cpu_device()
+        dev.SetRandSeed(3)
+        assert dev.current_key().committed
+        jax.eval_shape(lambda: dev.rand_key())      # leaks a tracer
+        assert isinstance(dev._get_rng_state(), jax.core.Tracer)
+        dev.rand_key()
+        assert not isinstance(dev._get_rng_state(), jax.core.Tracer)
+        assert dev.current_key().committed
