@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 import time
 import warnings
 
@@ -44,6 +45,9 @@ from .integrity import (IntegrityError, data_state_digest, digest_tree,
                         manifest_digest, read_digest_sidecar,
                         verify_tree, write_digest_sidecar)
 from .observability import metrics as _obs_metrics
+
+# see CheckpointManager._orbax_save
+_ORBAX_SAVE_LOCK = threading.Lock()
 
 
 def _obs_restore_done(t0, fallback_depth):
@@ -523,9 +527,7 @@ class CheckpointManager:
         self._join_digest_thread()
         arrays = {k: t.data for k, t in _state_tensor_dict(model).items()}
         try:
-            saved = self._mgr.save(
-                int(step), args=self._ocp.args.StandardSave(arrays),
-                force=force)
+            saved = self._orbax_save(step, arrays, force)
         except ValueError:
             # a crashed predecessor's zombie orbax machinery can still
             # mutate the directory after our init raced past it:
@@ -560,9 +562,7 @@ class CheckpointManager:
             self._reopen()
             self._known_steps &= {int(s)
                                   for s in self._mgr.all_steps()}
-            saved = self._mgr.save(
-                int(step), args=self._ocp.args.StandardSave(arrays),
-                force=force)
+            saved = self._orbax_save(step, arrays, force)
         if saved:
             self._known_steps.add(int(step))
             reg = _obs_metrics.default_registry()
@@ -615,6 +615,18 @@ class CheckpointManager:
                 target=digest_work, daemon=True, name="ckpt-digest")
             self._digest_thread.start()
         return saved
+
+    def _orbax_save(self, step, arrays, force):
+        # orbax keys each save's directory-creation signals by a
+        # process-global operation id, read back after it is bumped:
+        # two managers of one process (ranks run as threads) that start
+        # saves at once share an id, and each then creates and wipes
+        # the other's temporary step directory. Starts are serialized
+        # process-wide; the async write itself still overlaps.
+        with _ORBAX_SAVE_LOCK:
+            return self._mgr.save(
+                int(step), args=self._ocp.args.StandardSave(arrays),
+                force=force)
 
     def _join_digest_thread(self):
         t = getattr(self, "_digest_thread", None)

@@ -360,14 +360,15 @@ def _lanes_to_rows(x):
 
 
 def _across(stat, n):
-    """(rows, _LANES) lane-broadcast statistic -> (rows, n). Whole lane
+    """(..., rows, _LANES) lane-broadcast statistic -> (..., rows, n)
+    (leading axes for whole or narrower lane tiles only). Whole lane
     tiles repeat and a narrower tile is a slice, neither of which moves
     data between lanes (a ``stat[:, :1]`` broadcast does, once a row group
     a tile, and was most of the forward's time)."""
     if n % _LANES == 0:
-        return pltpu.repeat(stat, n // _LANES, axis=1)
+        return pltpu.repeat(stat, n // _LANES, axis=stat.ndim - 1)
     if n < _LANES:
-        return stat[:, :n]
+        return stat[..., :n]
     return jnp.broadcast_to(stat[:, :1], (stat.shape[0], n))
 
 

@@ -12,20 +12,26 @@ whose new token sits at position ``p`` holds tokens in its first
 wrapped; a dead slot holds none that matter. The wrapper lists those
 (slot, block) pairs in order and the grid has one step a pair — its
 length is read on the device — so the K/V blocks stream through VMEM by
-the ordinary pipeline and nothing else is fetched. Of the step:
+the ordinary pipeline and nothing else is fetched. A step takes a group
+of the level's KV heads (:func:`heads_per_step`: as many as their
+double-buffered blocks fit the VMEM): on a row-major level each head of
+the group is its own chain of two matrix products, batched over the
+heads, so that a pair of ten heads of 128 is one step and not ten. Of
+the step:
 
 - scores and the value product take the block in the cache's dtype on
   the MXU with float32 accumulation; running max, sum and accumulator
-  (online softmax across a slot's blocks) are float32 scratch; the ``G``
-  query heads that read one KV head ride the query axis, so a block is
-  read once for all of them;
+  (online softmax across a slot's blocks) are float32 scratch, each
+  head's in rows of its own; the ``G`` query heads that read one KV head
+  ride the query axis, so a block is read once for all of them;
 - the mask is ring arithmetic on ``p``: ring index ``j`` holds position
   ``p - ((p - j) % L)``, which is >= 0 exactly where ``j <= p`` (every
   ``j`` once ``p >= L``);
 - the new row is merged in VMEM into the fetched block that holds ring
-  index ``p % L``, is scored with the rest, and the one tile of that
-  block that took it is copied back by a DMA into the level, which is
-  aliased to the output — no other byte of the cache is written.
+  index ``p % L``, is scored with the rest, and the one tile of each
+  head's block that took it is copied back by a DMA into the level,
+  which is aliased to the output — no other byte of the cache is
+  written.
 
 A layer that attends to ANOTHER layer's keys and values takes the same
 walk read-only (:func:`ring_attend`, ``kv_cache.attend_token``): no new
@@ -42,7 +48,9 @@ one tile written back.
 The kernel's text does not depend on ``L`` (blocks are a grid axis) nor
 on ``G`` (one matrix product), and the call sits in a jitted wrapper with
 static arguments, so a model lowers it once a distinct
-``(W, H_kv, G, L, D, dtype)`` and not once a layer.
+``(W, H_kv, G, L, D, dtype)`` and not once a layer. A level of one KV
+head, a latent level and one with the ring on the lanes take one pair of
+two-dimensional products a step, with no axis of heads.
 """
 
 from __future__ import annotations
@@ -89,17 +97,21 @@ def live_blocks(pos, active, length, block):
     return jnp.where(active, -(-rows // block), 0).astype(jnp.int32)
 
 
-def _heads_per_step(n_kv, block, D, itemsize, cols):
-    """KV heads one grid step works on. A level that arrives with the
-    ring on the lanes (``cols``) gives all its heads to one pair of
-    matrix products — as many as fit the VMEM, in whole lane tiles of
-    ``heads x D`` — and a row-major level one head a step, whose query
-    group fills the product's rows."""
-    if not cols:
-        return 1
+def heads_per_step(n_kv, block, D, itemsize):
+    """KV heads one grid step works on, for a level of ``n_kv`` heads of
+    ``D`` cut into blocks of ``block`` rows: as many as the double-buffered
+    K and V blocks of a step fit in the VMEM. A level that arrives with
+    the ring on the lanes (head size under a lane tile) gives them to one
+    pair of matrix products, in whole lane tiles of ``heads x D``, halving
+    from all of them; a row-major level takes the largest divisor of
+    ``n_kv`` that fits, the heads the batch axis of its products, each
+    head's query group filling its own rows."""
+    fits = lambda hb: 4 * hb * D * block * itemsize <= _BLOCK_VMEM
+    if D % _LANES == 0:
+        return max(d for d in range(1, n_kv + 1)
+                   if n_kv % d == 0 and (d == 1 or fits(d)))
     hb = n_kv
-    while 4 * hb * D * block * itemsize > _BLOCK_VMEM and hb % 2 == 0 \
-            and (hb // 2 * D) % _LANES == 0:
+    while not fits(hb) and hb % 2 == 0 and (hb // 2 * D) % _LANES == 0:
         hb //= 2
     return hb
 
@@ -124,14 +136,18 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
     ``write`` false is the read-only pass (:func:`ring_attend`): no new
     row, no output but the attention's, the level is only read.
 
-    Row-major level (``cols`` false): the block is ``(B, D)`` of one KV
-    head and ``q`` its ``(G, D)`` query group. ``cols``: the level is
-    ``(W, H_kv * D, L)``, the block ``(heads * D, B)`` with every head
-    of the group, and ``q`` is ``(heads * G, heads * D)`` with a head's
-    queries in that head's columns and zeros elsewhere, so that one
-    product scores every head against its own keys; the value product
-    then holds every head's values in every row, and the wrapper reads
-    each row's own head out of it.
+    Row-major level (``cols`` false): the block is ``(heads, B, D)`` and
+    ``q`` ``(heads, M, D)``, each head's ``G`` queries in the first rows
+    of its own ``M``; the heads are the batch axis of both matrix
+    products and each keeps its own ``M`` rows of the scratch, so the
+    heads of a step are independent chains of work for the MXUs (one
+    head: plain two-dimensional products). ``cols``: the
+    level is ``(W, H_kv * D, L)``, the block ``(heads * D, B)`` with
+    every head of the group, and ``q`` is ``(heads * G, heads * D)``
+    with a head's queries in that head's columns and zeros elsewhere, so
+    that one product scores every head against its own keys; the value
+    product then holds every head's values in every row, and the wrapper
+    reads each row's own head out of it.
 
     ``vcols`` (the latent form, :func:`latent_decode`): the level is ONE
     row-major array a token; the scores take the row's whole width and
@@ -149,7 +165,9 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
     j = blk_ref[s]
     p = pos_ref[w]
     r = p % length                      # ring index of the new row
+    hb = 1 if cols else k_ref.shape[1]  # products a step: one a KV head
     M, A = acc_ref.shape                # A: columns of the value product
+    M //= hb                            # scratch rows of one product
     K = k_ref.shape[-2] if cols else k_ref.shape[-1]
     writes = j == r // block            # this block takes the new row
     live = s < total_ref[0]
@@ -157,14 +175,17 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
     # or the 128 ring indices of one lane tile
     base = pl.multiple_of((r % block) // tile * tile, tile)
     at = r % block - base
+
     if cols:
         lead = (0,)                     # the block's unit axes
         here = (slice(None), pl.ds(base, tile))
         there = (w, pl.ds(hg * K, K), pl.ds(j * block + base, tile))
     else:
-        lead = (0, 0)
+        # every head's tile in one copy
+        lead = (0, 0) if hb == 1 else (0, slice(None))
         here = (pl.ds(base, tile), slice(None))
-        there = (w, hg, pl.ds(j * block + base, tile), slice(None))
+        there = (w, hg if hb == 1 else pl.ds(hg * hb, hb),
+                 pl.ds(j * block + base, tile), slice(None))
     copies = [pltpu.make_async_copy(ref.at[lead + here], out.at[there],
                                     sem[0].at[i])
               for i, (ref, out) in enumerate(zip(lvl_refs, out_refs))]
@@ -189,9 +210,11 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
             mask = lax.broadcasted_iota(jnp.int32, (tile, K), 0) == at
         for ref, new_ref in zip(lvl_refs, new_refs):
             if not cols:
-                ref[lead + here] = jnp.where(
-                    mask, new_ref[lead], ref[lead + here].astype(
-                        jnp.float32)).astype(ref.dtype)
+                for h in range(hb):
+                    idx = (0, h) + here
+                    ref[idx] = jnp.where(
+                        mask, new_ref[0, h],
+                        ref[idx].astype(jnp.float32)).astype(ref.dtype)
                 continue
             for c in range(K // tile):
                 new = lax.dot_general(
@@ -216,24 +239,44 @@ def _ring_decode_kernel(total_ref, slot_ref, blk_ref, pos_ref, walk_ref,
     @pl.when(live)
     def _walk():
         col = j * block + lax.broadcasted_iota(jnp.int32, (M, block), 1)
-        v = lvl_refs[1][lead] if vcols is None \
-            else k_ref[lead + (slice(None), slice(0, vcols))]
-        sc = _mxu(q_ref[0, 0], k_ref[lead], _NN if cols else _NT)
+        if hb == 1:
+            v = lvl_refs[1][lead] if vcols is None \
+                else k_ref[lead + (slice(None), slice(0, vcols))]
+            q, k = q_ref[0, 0], k_ref[lead]
+            dims = (_NN, _NT) if cols else (_NT, _NN)
+        else:
+            # the step's heads are the batch axis of both products: as
+            # many independent chains of work as heads
+            v, q, k = lvl_refs[1][0], q_ref[0], k_ref[0]
+            dims = ((((2,), (2,)), ((0,), (0,))),
+                    (((2,), (1,)), ((0,), (0,))))
+
+        def stat(ref):
+            """The scratch, a head's rows on an axis of their own."""
+            return ref[...] if hb == 1 else \
+                ref[...].reshape(hb, M, ref.shape[-1])
+
+        def keep(ref, x):
+            ref[...] = x if hb == 1 else x.reshape(ref.shape)
+
+        sc = _mxu(q, k, dims[0])
         sc = jnp.where(col <= p, sc * scale, _NEG_INF)
-        m = m_ref[...]
+        m = stat(m_ref)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         pr = jnp.exp(sc - _across(m_new, block))
         alpha = jnp.exp(m - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * _across(alpha, A) + _mxu(
-            pr.astype(v.dtype), v, _NT if cols else _NN)
-        m_ref[...] = m_new
+        keep(l_ref, stat(l_ref) * alpha + jnp.sum(pr, axis=-1,
+                                                  keepdims=True))
+        keep(acc_ref, stat(acc_ref) * _across(alpha, A) + _mxu(
+            pr.astype(v.dtype), v, dims[1]))
+        keep(m_ref, m_new)
 
     @pl.when(jnp.logical_and(live, j == walk_ref[w] - 1))
     def _out():
-        o_ref[0, 0] = (acc_ref[...] / _across(l_ref[...], A)).astype(
-            o_ref.dtype)
+        for h in range(hb):
+            rows = slice(h * M, (h + 1) * M)
+            o_ref[0, h] = (acc_ref[rows] / _across(l_ref[rows], A)).astype(
+                o_ref.dtype)
 
     if write:
         @pl.when(jnp.logical_and(live, writes))
@@ -274,33 +317,39 @@ def _ring_decode_call(q, k_new, v_new, k, v, pos, active, *, scale, block,
     # ring on the lanes: (W, H_kv, D, L) is then a view of it
     cols = D % _LANES != 0
     rows = 32 // dtype.itemsize         # rows of one sublane tile
-    nb = L // block
-    hb = _heads_per_step(n_kv, block, D, dtype.itemsize, cols)
+    hb = heads_per_step(n_kv, block, D, dtype.itemsize)
     groups = n_kv // hb
-    M = -(-hb * G // rows) * rows
-    K = hb * D
 
     pos = pos.astype(jnp.int32)
     total, slot, blk, walk = _walk_list(pos, active, L, block)
-
-    # a group's queries, each head's in its own D columns of K
-    qg = q.reshape(W, groups, hb, G, 1, D).astype(dtype)
-    if hb > 1:
-        qg = qg * jnp.eye(hb, dtype=dtype)[:, None, :, None]
-    qg = jnp.pad(qg.reshape(W, groups, hb * G, K),
-                 ((0, 0), (0, 0), (0, M - hb * G), (0, 0)))
     write = k_new is not None
     if cols:
+        M = -(-hb * G // rows) * rows
+        K = hb * D
+        # a group's queries, each head's in its own D columns of K
+        qg = q.reshape(W, groups, hb, G, 1, D).astype(dtype)
+        if hb > 1:
+            qg = qg * jnp.eye(hb, dtype=dtype)[:, None, :, None]
+        qg = jnp.pad(qg.reshape(W, groups, hb * G, K),
+                     ((0, 0), (0, 0), (0, M - hb * G), (0, 0)))
         k, v = (a.swapaxes(2, 3).reshape(W, n_kv * D, L) for a in (k, v))
         new_shape = (W, groups, K // _LANES, _LANES)
         new_row = _by_walk((1, 1, K // _LANES, _LANES),
-                       lambda w, g, b: (w, g, 0, 0))
+                           lambda w, g, b: (w, g, 0, 0))
         kv_block = _by_walk((1, K, block), lambda w, g, b: (w, g, b))
+        by_slot = _by_walk((1, 1, M, K), lambda w, g, b: (w, g, 0, 0))
+        out_shape, R = (W, groups, M, K), M
     else:
+        # each head's queries in its own M rows
+        M, K = -(-G // rows) * rows, D
+        qg = jnp.pad(q.reshape(W, n_kv, G, D).astype(dtype),
+                     ((0, 0), (0, 0), (0, M - G), (0, 0)))
         new_shape = (W, n_kv, 1, D)
-        new_row = _by_walk((1, 1, 1, D), lambda w, g, b: (w, g, 0, 0))
-        kv_block = _by_walk((1, 1, block, D), lambda w, g, b: (w, g, b, 0))
-    by_slot = _by_walk((1, 1, M, K), lambda w, g, b: (w, g, 0, 0))
+        new_row = _by_walk((1, hb, 1, D), lambda w, g, b: (w, g, 0, 0))
+        kv_block = _by_walk((1, hb, block, D),
+                            lambda w, g, b: (w, g, b, 0))
+        by_slot = _by_walk((1, hb, M, D), lambda w, g, b: (w, g, 0, 0))
+        out_shape, R = (W, n_kv, M, D), hb * M
     kernel = functools.partial(
         _ring_decode_kernel, scale=scale, block=block, length=L,
         tile=_LANES if cols else rows, cols=cols, write=write)
@@ -320,11 +369,11 @@ def _ring_decode_call(q, k_new, v_new, k, v, pos, active, *, scale, block,
             + [kv_block, kv_block],
             out_specs=[by_slot] + level_out,
             scratch_shapes=[
-                pltpu.VMEM((M, K), jnp.float32),
-                pltpu.VMEM((M, _LANES), jnp.float32),
-                pltpu.VMEM((M, _LANES), jnp.float32),
+                pltpu.VMEM((R, K), jnp.float32),
+                pltpu.VMEM((R, _LANES), jnp.float32),
+                pltpu.VMEM((R, _LANES), jnp.float32),
             ] + ([pltpu.SemaphoreType.DMA((2,))] if write else [])),
-        out_shape=[jax.ShapeDtypeStruct((W, groups, M, K), q.dtype)]
+        out_shape=[jax.ShapeDtypeStruct(out_shape, q.dtype)]
         + ([jax.ShapeDtypeStruct(a.shape, dtype) for a in (k, v)]
            if write else []),
         # operands count the five prefetched arrays: k is 8, v is 9
@@ -332,15 +381,17 @@ def _ring_decode_call(q, k_new, v_new, k, v, pos, active, *, scale, block,
         interpret=interpret,
         name="ring_decode" if write else "ring_attend",
     )(total, slot, blk, pos, walk, qg, *new_rows, k, v)
+    # a dead slot's rows of the output were never written
     if cols:
         kv = [a.reshape(W, n_kv, D, L).swapaxes(2, 3) for a in kv]
-    # each row's own head of the value product; a dead slot's rows of
-    # the output were never written
-    out = out[:, :, :hb * G]
-    if hb > 1:
-        out = jnp.einsum("wnhgkd,hk->wnhgd",
-                         out.reshape(W, groups, hb, G, hb, D),
-                         jnp.eye(hb, dtype=out.dtype))
+        # each row's own head of the value product
+        out = out[:, :, :hb * G]
+        if hb > 1:
+            out = jnp.einsum("wnhgkd,hk->wnhgd",
+                             out.reshape(W, groups, hb, G, hb, D),
+                             jnp.eye(hb, dtype=out.dtype))
+    else:
+        out = out[:, :, :G]
     out = jnp.where(active[:, None, None, None], out.reshape(q.shape), 0)
     return (out, *kv)
 

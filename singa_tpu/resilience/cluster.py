@@ -601,9 +601,9 @@ class Coordinator(ClusterBase):
                 self._failed_barriers.pop(
                     next(iter(self._failed_barriers)))
             self._barriers.pop(name, None)
-        slot["event"].set()
         self._broadcast("barrier-fail", ranks=slot["arrived"],
                         name=name, missing=missing)
+        slot["event"].set()
 
     def _barrier_arrive(self, name, rank):
         with self._lock:
@@ -629,9 +629,9 @@ class Coordinator(ClusterBase):
             # slow still get the full timeout
             dead_missing = sorted(self._dead - slot["arrived"])
         if complete:
-            slot["event"].set()
             self._broadcast("barrier-ok", ranks=slot["arrived"],
                             name=name)
+            slot["event"].set()
         elif dead_missing:
             self._fail_barrier(name, slot, dead_missing)
 
@@ -730,8 +730,13 @@ class Coordinator(ClusterBase):
                 _prune_window(self._commit_ok, self._acks,
                               self._ack_digests, self._ack_data,
                               self._commit_done, self._commit_claimed)
-            ev.set()
+            # peers hear the decision BEFORE the coordinator's own
+            # waiter is released: released first, a coordinator at its
+            # last step can finish and close the cluster while this
+            # (receive) thread has yet to send, and its peers then wait
+            # out the commit timeout on a step that committed
             self._broadcast("commit", step=step, ok=ok)
+            ev.set()
 
     def ack_save(self, step, digest=None, data_digest=None):
         self.faults.on_ack(int(step))
@@ -754,8 +759,8 @@ class Coordinator(ClusterBase):
                     self._commit_claimed.add(step)
                     self._commit_ok[step] = False
             if aborted:
-                ev.set()
                 self._broadcast("commit", step=step, ok=False)
+                ev.set()
             else:
                 ev.wait(5.0)     # publish decision in flight; let it land
         with self._lock:
@@ -802,9 +807,9 @@ class Coordinator(ClusterBase):
                 f"{seq}): rank(s) {divergent} hold a minority state "
                 "(silent divergence — quarantine and roll back)",
                 stacklevel=2)
-        ev.set()
         self._broadcast("fp-result", seq=seq, ok=ok,
                         divergent=divergent)
+        ev.set()
 
     def fingerprint_agree(self, seq, fp, timeout=30.0):
         seq = int(seq)

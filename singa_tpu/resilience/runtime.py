@@ -1029,6 +1029,14 @@ class ResilientTrainer:
                 resumed = self._maybe_rollback(step, bad, summary)
                 step = resumed if resumed is not None else step + 1
             self.mgr.wait()
+            if self.cluster is not None and self.cluster.world > 1:
+                # the run ends together: a coordinator that returned
+                # (and closed the cluster) while a peer still had steps
+                # to run would look to that peer like a dead
+                # coordinator — membership lost on a healthy finish
+                with _spans.span("barrier", barrier=f"end-{step}"):
+                    self.cluster.barrier(
+                        f"end-{step}", timeout=self.start_barrier_timeout)
             self._finalize_summary(summary)
             return summary
         except _Preempted:

@@ -1096,9 +1096,16 @@ class RingLayout(KVLayout):
                 for a in level.values()), kind=kind)
         self.lengths = np.asarray([int(lv["k"].shape[2]) for lv in rings])
         # a ring that no block divides is walked whole
-        from ..ops.ring_decode import block_rows
+        from ..ops.ring_decode import block_rows, heads_per_step
         self._blocks = np.asarray(
             [block_rows(n) or n for n in self.lengths])
+        # grid steps a block of each ring takes: one a group of the KV
+        # heads the kernel walks together
+        self._groups = np.asarray([
+            lv["k"].shape[1] // heads_per_step(
+                lv["k"].shape[1], int(b), lv["k"].shape[3],
+                lv["k"].dtype.itemsize)
+            for lv, b in zip(rings, self._blocks)])
         # layers that read each ring a tick (a layer that attends to
         # another layer's keys and values reads that layer's ring again)
         readers = getattr(self.adapter, "cache_readers", None)
@@ -1125,6 +1132,11 @@ class RingLayout(KVLayout):
             "summed over the layers that read them and active slots "
             "(against slots x blocks a ring: the share of the whole "
             "walk)")
+        self._walk_steps = self._reg.counter(
+            "serve_kv_walk_steps_total", "grid steps of the ring decode "
+            "kernel's walks: blocks walked x the groups of KV heads a "
+            "step takes (against serve_kv_blocks_walked_total x KV "
+            "heads: the heads a step carries)")
         if self.n_state:
             self._state_steps = self._reg.counter(
                 "serve_state_steps_total", "recurrent states a decode "
@@ -1187,10 +1199,12 @@ class RingLayout(KVLayout):
         if self.lengths is not None:
             rows = np.minimum(positions[active, None] + 1, self.lengths)
             attrs["kv_rows"] = int((rows * self._readers).sum())
-            attrs["kv_blocks"] = int(
-                (-(-rows // self._blocks) * self._readers).sum())
+            blocks = -(-rows // self._blocks) * self._readers
+            attrs["kv_blocks"] = int(blocks.sum())
+            attrs["walk_steps"] = int((blocks * self._groups).sum())
             self._kv_rows.inc(attrs["kv_rows"])
             self._kv_blocks.inc(attrs["kv_blocks"])
+            self._walk_steps.inc(attrs["walk_steps"])
             if self.n_state:
                 attrs["state_slots"] = int(active.sum()) * self.n_state
                 self._state_steps.inc(attrs["state_slots"])

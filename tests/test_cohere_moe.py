@@ -237,6 +237,9 @@ def test_engine_counts_pairs_touched_experts_and_ring_rows(toy):
     # no block of the kernel divides these toy rings: each is one block
     assert [r["kv_blocks"] for r in decode[-5:]] == [4] * 5
     assert reg.get("serve_kv_blocks_walked_total").value() == 4 * 5
+    # one KV head a level: a grid step of the kernel a block
+    assert [r["walk_steps"] for r in decode[-5:]] == [4] * 5
+    assert reg.get("serve_kv_walk_steps_total").value() == 4 * 5
     # the counts came over with the tokens: no call read its logits
     assert {r["readback"] for r in decode[-5:]} == {"tokens"}
     readback = reg.get("serve_readback_total")

@@ -126,6 +126,16 @@ RING_LEVELS = [
 ]
 
 
+def _all_heads_a_step_where_they_fit(ring_decode, n_kv, block, D, dtype):
+    """Every level here takes all its KV heads in one grid step where
+    their double-buffered K and V blocks fit the rule's VMEM (phi4flash's
+    ten on both its rings)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    hb = ring_decode.heads_per_step(n_kv, block, D, itemsize)
+    assert hb == n_kv or \
+        4 * n_kv * block * D * itemsize > ring_decode._BLOCK_VMEM
+
+
 @pytest.mark.pallas
 @pytest.mark.parametrize(
     "shape,dtype", RING_LEVELS,
@@ -133,15 +143,16 @@ RING_LEVELS = [
          for s, d in RING_LEVELS])
 def test_ring_decode_compiles_and_writes_the_level_in_place(
         one_chip, for_the_chip, monkeypatch, shape, dtype):
-    """The kernel compiles at the level's own block, once, and the
-    compiled program aliases both halves of the level to its outputs and
-    holds no temporary of a level's size: the donated cache is written
-    where it lies, not copied."""
+    """The kernel compiles at the level's own block and the heads a step
+    its rule gives, once, and the compiled program aliases both halves
+    of the level to its outputs and holds no temporary of a level's
+    size: the donated cache is written where it lies, not copied."""
     from singa_tpu.ops import ring_decode
     monkeypatch.setattr(ring_decode, "_interpret", lambda: False)
     W, n_kv, G, L, D = shape
     block = ring_decode.kernel_block(n_kv, L, D)
     assert block is not None
+    _all_heads_a_step_where_they_fit(ring_decode, n_kv, block, D, dtype)
 
     def sds(*s, dt=dtype):
         return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
@@ -164,8 +175,10 @@ def test_ring_decode_compiles_and_writes_the_level_in_place(
 
 @pytest.mark.pallas
 @pytest.mark.parametrize(
-    "shape,dtype", [RING_LEVELS[4], RING_LEVELS[0], RING_LEVELS[-1]],
-    ids=["full-ring-10x4x128", "ring-on-the-lanes-16x64", "toy-4x2x32"])
+    "shape,dtype",
+    [RING_LEVELS[4], RING_LEVELS[3], RING_LEVELS[0], RING_LEVELS[-1]],
+    ids=["full-ring-10x4x128", "window-ring-10x4x128",
+         "ring-on-the-lanes-16x64", "toy-4x2x32"])
 def test_ring_attend_compiles_and_leaves_the_level_alone(
         one_chip, for_the_chip, monkeypatch, shape, dtype):
     """The read-only pass (a layer that reads another layer's ring): one
@@ -175,6 +188,7 @@ def test_ring_attend_compiles_and_leaves_the_level_alone(
     monkeypatch.setattr(ring_decode, "_interpret", lambda: False)
     W, n_kv, G, L, D = shape
     block = ring_decode.kernel_block(n_kv, L, D)
+    _all_heads_a_step_where_they_fit(ring_decode, n_kv, block, D, dtype)
 
     def sds(*s, dt=dtype):
         return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)

@@ -343,6 +343,9 @@ def test_span_attrs_sum_to_the_counters(toy):
     # no block of the kernel divides these toy rings: each is one block
     assert [r["kv_blocks"] for r in decode] == [2 * (2 + 2)] * 5
     assert reg.get("serve_kv_blocks_walked_total").value() == 40
+    # every level's KV heads fit one grid step of the kernel
+    assert [r["walk_steps"] for r in decode] == [2 * (2 + 2)] * 5
+    assert reg.get("serve_kv_walk_steps_total").value() == 40
     assert {r["readback"] for r in decode + prefill} == {"tokens"}
     assert reg.get("serve_readback_total").total() == 6
 

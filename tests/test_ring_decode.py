@@ -3,11 +3,17 @@ it replaces, on the CPU through the interpret-mode hook of
 ``ops/attention.py``: one parametrised test a property, so each case
 counts.
 
-- ``kv_cache.decode_token`` == ``write_token`` + ``attend`` for lengths
-  short of a block, on a block edge and mixed across slots; for a ring
-  that has wrapped; for one query head a KV head and sixteen, head sizes
-  64 and 128 (the level with the ring on the lanes, and the row-major
-  one); bf16 and float32 levels; dead slots beside live ones;
+- ``kv_cache.decode_token`` == ``write_token`` + ``attend``, and
+  ``kv_cache.attend_token`` (the read-only pass) == ``attend``, for
+  lengths short of a block, on a block edge and mixed across slots; for
+  a ring that has wrapped; for one query head a KV head and sixteen, head
+  sizes 64 and 128 (the level with the ring on the lanes, and the
+  row-major one); several row-major KV heads a grid step (ten of 128 read
+  by four queries each, rings of 512 and 3,072, and a level whose blocks
+  fit only half its heads in a step); bf16 and float32 levels; dead
+  slots beside live ones;
+- the heads a grid step takes, level by level, and the kernel timing
+  tool's sweep of them (``tools/ring_kernel_time.py``) at a toy size;
 - the written row is bitwise ``write_token``'s and no other row of a
   live slot, nor any row of a dead one, is touched;
 - a cache whose levels have two lengths;
@@ -15,7 +21,7 @@ counts.
   sharded engine, a ring that no block divides, a backend that is not
   the TPU outside the hook;
 - through the engine: the served tokens, ``n_traces == 1``, and the
-  ``kv_blocks`` span attr and counter.
+  ``kv_blocks`` and ``walk_steps`` span attrs and counters.
 """
 
 import functools
@@ -61,7 +67,8 @@ def _bits(a):
 
 
 def _check_against_twins(level, q, k_new, v_new, pos, active, tol):
-    """decode_token against write_token + attend on one level."""
+    """decode_token against write_token + attend on one level, and the
+    read-only pass over the written level against attend."""
     pos = jnp.asarray(pos, jnp.int32)
     active = np.asarray(active, bool)
     scale = q.shape[-1] ** -0.5
@@ -74,6 +81,10 @@ def _check_against_twins(level, q, k_new, v_new, pos, active, tol):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=0)
     assert not got[~active].any()
+    read = np.asarray(kv_cache.attend_token(
+        want_level, q, pos, jnp.asarray(active), scale), np.float32)
+    np.testing.assert_allclose(read[active], want[active], atol=tol, rtol=0)
+    assert not read[~active].any()
     for name in ("k", "v"):
         # a live slot: the new row as write_token wrote it, bit for bit,
         # and every other row as it was; a dead slot: untouched
@@ -94,25 +105,66 @@ LENGTHS = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(LENGTHS))
+# ten KV heads of 128 read by four queries each, bf16 (phi4flash's
+# levels): all ten heads a grid step, on a window ring of 512 (blocks of
+# 128) and a full ring of 3,072 (blocks of 256), slots partial and wrapped
+HEADS_LENGTHS = {
+    "ten_heads_ring_512": (512, [3, 127, 128, 511, 512, 700, 1023, 1536]),
+    "ten_heads_ring_3072": (3072, [0, 255, 256, 3071, 3072, 5000]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LENGTHS) + sorted(HEADS_LENGTHS))
 def test_lengths(interpreted, case):
-    pos = LENGTHS[case]
-    level, q, k_new, v_new = _level_and_token(len(pos), 2, 1, 384, 64,
-                                              jnp.float32)
+    if case in LENGTHS:
+        pos, tol = LENGTHS[case], 2e-6
+        level, q, k_new, v_new = _level_and_token(len(pos), 2, 1, 384, 64,
+                                                  jnp.float32)
+    else:
+        # the output is rounded to the level's dtype: one bf16 step
+        (L, pos), tol = HEADS_LENGTHS[case], 2 ** -6
+        level, q, k_new, v_new = _level_and_token(len(pos), 10, 4, L, 128,
+                                                  jnp.bfloat16)
+        assert _heads_per_step(level) == 10
     _check_against_twins(level, q, k_new, v_new, pos, [True] * len(pos),
-                         tol=2e-6)
+                         tol=tol)
 
 
-@pytest.mark.parametrize("G", [1, 16])
-@pytest.mark.parametrize("D", [64, 128])
-def test_grouped_heads_and_head_sizes(interpreted, G, D):
+def _heads_per_step(level):
+    W, n_kv, L, D = level["k"].shape
+    return ring_decode.heads_per_step(n_kv, ring_decode.block_rows(L), D,
+                                      level["k"].dtype.itemsize)
+
+
+# (head size, query heads a KV head, KV heads, ring, KV heads a step)
+GROUPS = {
+    "64-1": (64, 1, 2, 256, 2),
+    "64-16": (64, 16, 2, 256, 2),
+    "128-1": (128, 1, 1, 256, 1),
+    "128-16": (128, 16, 1, 256, 1),
+    "128-1-kv2": (128, 1, 2, 256, 2),
+    "128-4-kv2": (128, 4, 2, 256, 2),
+    "128-1-kv10": (128, 1, 10, 512, 10),
+    "128-4-kv10": (128, 4, 10, 512, 10),
+    # blocks of 512 float32 rows: K and V of five heads, double-buffered,
+    # fill the VMEM the rule allows, so the ten take two steps a block
+    "128-4-kv10-half-a-step": (128, 4, 10, 4096, 5),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPS))
+def test_grouped_heads_and_head_sizes(interpreted, case):
     """KV heads read by G query heads each; head size 64 is the level
     XLA keeps with the ring on the lanes (two heads fill its lane tile),
-    128 the row-major one (one KV head)."""
-    level, q, k_new, v_new = _level_and_token(3, 128 // D, G, 256, D,
+    128 the row-major one, one KV head or several, each step taking as
+    many KV heads as the rule gives."""
+    D, G, n_kv, L, hb = GROUPS[case]
+    level, q, k_new, v_new = _level_and_token(3, n_kv, G, L, D,
                                               jnp.float32)
-    _check_against_twins(level, q, k_new, v_new, [5, 255, 600], [True] * 3,
-                         tol=2e-6)
+    assert _heads_per_step(level) == hb
+    wrapped = 600 if L < 600 else L + 600
+    _check_against_twins(level, q, k_new, v_new, [5, 255, wrapped],
+                         [True] * 3, tol=2e-6)
 
 
 @pytest.mark.parametrize("D", [64, 128])
@@ -134,11 +186,13 @@ DEAD = {
 }
 
 
-@pytest.mark.parametrize("D", [64, 128])
+# head size, or head size x KV heads where there are more than two
+@pytest.mark.parametrize("heads", ["64", "128", "128x10"])
 @pytest.mark.parametrize("case", sorted(DEAD))
-def test_dead_slots(interpreted, case, D):
+def test_dead_slots(interpreted, case, heads):
     pos, active = DEAD[case]
-    level, q, k_new, v_new = _level_and_token(len(pos), 2, 1, 384, D,
+    D, n_kv = (int(n) for n in (heads + "x2").split("x")[:2])
+    level, q, k_new, v_new = _level_and_token(len(pos), n_kv, 1, 384, D,
                                               jnp.float32, seed=2)
     _check_against_twins(level, q, k_new, v_new, pos, active, tol=2e-6)
 
@@ -235,11 +289,11 @@ def test_what_the_kernel_cannot_take_keeps_the_xla_path(
 # through the engine
 # ---------------------------------------------------------------------------
 
-def _lm(seed=0, max_len=128):
+def _lm(seed=0, max_len=128, d_model=128, n_heads=4):
     np.random.seed(seed)
     DEV.SetRandSeed(seed)
-    m = transformer.TransformerLM(64, d_model=128, n_heads=4, n_layers=2,
-                                  max_len=max_len, tp=False)
+    m = transformer.TransformerLM(64, d_model=d_model, n_heads=n_heads,
+                                  n_layers=2, max_len=max_len, tp=False)
     m.eval()
     m(Tensor(data=np.zeros((1, 8), np.float32), device=DEV,
              requires_grad=False))
@@ -311,6 +365,68 @@ def test_kv_blocks_on_the_span_and_the_counter():
         sum(r["kv_rows"] for r in ticks)
 
 
+# (KV heads x head size, VMEM the rule may give a step's K and V blocks,
+# grid steps a block takes): four heads of 32 in one product on the
+# lanes; two row-major heads of 128 in one step, or, where the VMEM
+# holds one head's blocks only, in two
+WALKS = {
+    "lanes-4x32": (4, 32, None, 1),
+    "rows-2x128": (2, 128, None, 1),
+    "rows-2x128-one-head-a-step": (2, 128, 2 ** 17, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_walk_steps_on_the_span_and_the_counter(monkeypatch, case):
+    """``walk_steps`` is the kernel's grid steps a tick: ``kv_blocks`` x
+    the groups of KV heads a step takes (``ring_decode.heads_per_step``
+    of each level), so ``kv_blocks`` x KV heads ÷ ``walk_steps`` reads
+    the heads a step carries; summed over the spans it is
+    ``serve_kv_walk_steps_total``."""
+    n_kv, D, vmem, groups = WALKS[case]
+    if vmem is not None:
+        monkeypatch.setattr(ring_decode, "_BLOCK_VMEM", vmem)
+    m = _lm(seed=2, d_model=n_kv * D, n_heads=n_kv)
+    obs_spans.recorder().clear()
+    _, info, reg = _serve(m)
+    ticks = [r for r in obs_spans.recorder().records()
+             if r.get("name") == "serve.decode"]
+    assert ticks and info["n_traces"] == 1
+    for r in ticks:
+        assert r["kv_blocks"] > 0
+        assert r["walk_steps"] == r["kv_blocks"] * groups
+        assert r["kv_blocks"] * n_kv // r["walk_steps"] == n_kv // groups
+    assert reg.get("serve_kv_walk_steps_total").value() == \
+        sum(r["walk_steps"] for r in ticks)
+
+
+HEADS = {
+    # phi4flash's window and full rings: all ten heads a step
+    "phi4flash-window": ((10, 128, 128, 2), 10),
+    "phi4flash-full": ((10, 256, 128, 2), 10),
+    # one KV head (Command A+'s rings; a latent level's one row a token)
+    "one-kv-head": ((1, 512, 128, 2), 1),
+    "latent-row": ((1, 512, 640, 2), 1),
+    # the ring on the lanes: all of GPT-2's sixteen heads of 64 in one
+    # product, halved while a step's blocks outgrow the VMEM
+    "lanes-gpt2": ((16, 128, 64, 2), 16),
+    "lanes-halved": ((32, 512, 64, 4), 16),
+    # row-major blocks that hold half the heads: the largest divisor
+    "rows-halved": ((10, 512, 128, 4), 5),
+    "rows-odd": ((3, 2048, 128, 4), 1),
+}
+
+
+@pytest.mark.parametrize("case", list(HEADS))
+def test_heads_per_step(case):
+    """The KV heads a grid step takes, from ``(n_kv, block, D, itemsize)``
+    alone: one for a level of one KV head, all that fit for a row-major
+    level, and for a level with the ring on the lanes all of them halved
+    while their blocks outgrow the VMEM."""
+    args, want = HEADS[case]
+    assert ring_decode.heads_per_step(*args) == want
+
+
 @pytest.mark.parametrize("length,block,pos,want", [
     (1024, 128, [0, 127, 128, 1023, 5000], [1, 1, 2, 8, 8]),
     (5120, 512, [4095, 4096, 9999], [8, 9, 10]),
@@ -324,3 +440,28 @@ def test_live_blocks_from_positions(length, block, pos, want):
     dead = ring_decode.live_blocks(jnp.asarray(pos),
                                    jnp.zeros(len(pos), bool), length, block)
     assert not np.asarray(dead).any()
+
+
+def test_kernel_time_tool_forces_the_heads_a_step(interpreted, capsys):
+    """``tools/ring_kernel_time.py`` at a toy size: a line a (pass, heads
+    a step), each with the grid steps of that count, and the rule's own
+    count restored after the sweep."""
+    import json
+    import os
+    import runpy
+    tool = runpy.run_path(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools", "ring_kernel_time.py"))
+    rule = ring_decode.heads_per_step
+    tool["main"](["--slots", "4", "--kv-heads", "2", "--rings", "256",
+                  "--live", "3", "--tokens", "100", "--calls", "1",
+                  "--heads-a-step", "1,2"])
+    assert ring_decode.heads_per_step is rule
+    lines = [json.loads(line)
+             for line in capsys.readouterr().out.splitlines()]
+    assert [(d["op"], d["heads_a_step"]) for d in lines] == [
+        ("ring_decode", 1), ("ring_attend", 1),
+        ("ring_decode", 2), ("ring_attend", 2)]
+    for d in lines:
+        assert d["rule"] == 2 and d["block"] == 128
+        assert d["grid_steps"] == d["blocks_walked"] * 2 // d["heads_a_step"]
