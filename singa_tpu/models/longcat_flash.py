@@ -173,24 +173,37 @@ def _mm(a, w, blocks=None):
         r, w, preferred_element_type=jnp.float32), a, blocks)
 
 
+def _leaf(name, i):
+    """A layer's leaf ``name`` of sub-block ``i`` (``name_i``), or of the
+    layer's one attention where ``i`` is None."""
+    return name if i is None else f"{name}_{i}"
+
+
 def mla_project(cfg, p, i, h, positions, blocks=None):
     """What both forms of the attention start from. ``h``: (B, S, D) the
     normed stream in the compute dtype. Returns ``q_n`` (B, S, H, nope),
     ``q_r`` (B, S, H, rope) rotated, ``c`` (B, S, kv_lora_rank) normed
     and scaled, ``k_r`` (B, S, rope) rotated (one head, shared by all),
-    each in the compute dtype: ``[c; k_r]`` is the row a cache keeps."""
+    each in the compute dtype: ``[c; k_r]`` is the row a cache keeps.
+    The query is low-rank (``wq_a``, ``q_norm``, ``wq_b``, times
+    ``cfg.q_scale``) or, where ``cfg.q_lora_rank`` is 0, one full-rank
+    ``wq``."""
     import jax
     import jax.numpy as jnp
     B, S, _ = h.shape
     dt, H = h.dtype, cfg.num_heads
     with jax.named_scope("mla_project"):
-        c_q = rms_norm(_mm(h, p[f"wq_a_{i}"], blocks), p[f"q_norm_{i}"],
-                       cfg.rms_norm_eps).astype(dt)
-        q = (_mm(c_q, p[f"wq_b_{i}"], blocks) * cfg.q_scale).reshape(
-            B, S, H, cfg.qk_head_dim)
-        kv = _mm(h, p[f"wkv_a_{i}"], blocks)
+        if cfg.q_lora_rank:
+            c_q = rms_norm(_mm(h, p[_leaf("wq_a", i)], blocks),
+                           p[_leaf("q_norm", i)], cfg.rms_norm_eps
+                           ).astype(dt)
+            q = _mm(c_q, p[_leaf("wq_b", i)], blocks) * cfg.q_scale
+        else:
+            q = _mm(h, p[_leaf("wq", i)], blocks)
+        q = q.reshape(B, S, H, cfg.qk_head_dim)
+        kv = _mm(h, p[_leaf("wkv_a", i)], blocks)
         c = cfg.kv_scale * rms_norm(kv[..., :cfg.kv_lora_rank],
-                                    p[f"kv_norm_{i}"], cfg.rms_norm_eps)
+                                    p[_leaf("kv_norm", i)], cfg.rms_norm_eps)
         q_r = rope_interleaved(q[..., cfg.qk_nope_head_dim:], positions,
                                cfg.rope_theta)
         k_r = rope_interleaved(kv[..., None, cfg.kv_lora_rank:], positions,
@@ -202,25 +215,32 @@ def mla_project(cfg, p, i, h, positions, blocks=None):
 def _kv_b(cfg, p, i):
     """The one stored ``wkv_b`` leaf as (rank, H, nope + v): its first
     ``nope`` columns a head make keys, the rest values."""
-    return p[f"wkv_b_{i}"].reshape(
+    return p[_leaf("wkv_b", i)].reshape(
         cfg.kv_lora_rank, cfg.num_heads,
         cfg.qk_nope_head_dim + cfg.v_head_dim)
 
 
+def expanded_qkv(cfg, p, i, q_n, q_r, c, k_r):
+    """The keys and values written out a head: ``q_h = [q_n; q_r]``,
+    ``k_h = [W_K,h c; k_r]`` (B, S, H, nope + rope) and ``v_h = W_V,h c``
+    (B, S, H, v_head_dim), in ``c``'s dtype."""
+    import jax.numpy as jnp
+    kv = jnp.einsum("bsr,rhn->bshn", c, _kv_b(cfg, p, i),
+                    preferred_element_type=jnp.float32).astype(c.dtype)
+    k = jnp.concatenate(
+        [kv[..., :cfg.qk_nope_head_dim],
+         jnp.broadcast_to(k_r[:, :, None, :], q_r.shape)], axis=-1)
+    q = jnp.concatenate([q_n, q_r], axis=-1)
+    return q, k, kv[..., cfg.qk_nope_head_dim:]
+
+
 def mla_expanded(cfg, p, i, q_n, q_r, c, k_r, n_blocks=None):
     """Causal attention over whole sequences with the keys and values
-    written out a head: ``k_h = [W_K,h c; k_r]``, ``v_h = W_V,h c``.
-    Returns (B, S, H, v_head_dim)."""
+    written out a head (:func:`expanded_qkv`). Returns (B, S, H,
+    v_head_dim)."""
     import jax
-    import jax.numpy as jnp
     with jax.named_scope("latent_attention"):
-        kv = jnp.einsum("bsr,rhn->bshn", c, _kv_b(cfg, p, i),
-                        preferred_element_type=jnp.float32).astype(c.dtype)
-        k = jnp.concatenate(
-            [kv[..., :cfg.qk_nope_head_dim],
-             jnp.broadcast_to(k_r[:, :, None, :], q_r.shape)], axis=-1)
-        q = jnp.concatenate([q_n, q_r], axis=-1)
-        return masked_attention(q, k, kv[..., cfg.qk_nope_head_dim:],
+        return masked_attention(*expanded_qkv(cfg, p, i, q_n, q_r, c, k_r),
                                 cfg.scale, n_blocks=n_blocks)
 
 
@@ -256,9 +276,9 @@ def dense_ffn(p, i, u, blocks=None):
     import jax
 
     def ffn(r):
-        g = _mm(r, p[f"ffn_gate_{i}"])
-        return _mm((jax.nn.silu(g) * _mm(r, p[f"ffn_up_{i}"])).astype(
-            r.dtype), p[f"ffn_down_{i}"])
+        g = _mm(r, p[_leaf("ffn_gate", i)])
+        return _mm((jax.nn.silu(g) * _mm(r, p[_leaf("ffn_up", i)])).astype(
+            r.dtype), p[_leaf("ffn_down", i)])
 
     with jax.named_scope("dense_ffn"):
         return _on_rows(ffn, u, blocks)
@@ -441,9 +461,8 @@ class LongCatFlashLM(DrawnBeforeCompile, model.Model):
 
     def train_one_batch(self, *a, **kw):
         raise NotImplementedError(
-            "LongCatFlashLM is inference-only: the routed experts' loop "
-            "has no reverse, and at published widths four layers with 8 "
-            "experts a layer are 60 GB of training state")
+            "LongCatFlashLM is inference-only: at published widths four "
+            "layers with 8 experts a layer are 60 GB of training state")
 
     def decode_adapter(self, policy=None):
         return _ServeAdapter(self, policy)
@@ -550,5 +569,5 @@ class _ServeAdapter(ByReferenceAdapter):
 
 
 __all__ = ["LongCatFlashLM", "LongCatFlashLayer", "Config", "rms_norm",
-           "mla_project", "mla_expanded", "mla_absorbed", "dense_ffn",
-           "layer_apply", "forward_logits", "create_model"]
+           "mla_project", "expanded_qkv", "mla_expanded", "mla_absorbed",
+           "dense_ffn", "layer_apply", "forward_logits", "create_model"]

@@ -222,9 +222,15 @@ class Counter(_Metric):
 
 
 class Gauge(_Metric):
-    """A value that goes up and down (loss scale, straggler count)."""
+    """A value that goes up and down (loss scale, straggler count). A
+    series given a function (:meth:`set_function`) takes its value from
+    it whenever the gauge is read."""
 
     kind = "gauge"
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self._functions = {}
 
     def _new_slot(self):
         return [0.0]
@@ -235,7 +241,28 @@ class Gauge(_Metric):
     def set(self, value, **labels):
         slot = self._slot(labels)
         with self._lock:
+            self._functions.pop(_label_key(self.label_names, labels), None)
             slot[0] = float(value)
+
+    def set_function(self, fn, **labels):
+        """The series' value is ``fn()``, called each time the gauge is
+        read (outside the registry's lock): a value that lives elsewhere,
+        such as on a device, is fetched only when someone asks."""
+        self._slot(labels)
+        with self._lock:
+            self._functions[_label_key(self.label_names, labels)] = fn
+
+    def _refresh(self):
+        with self._lock:
+            functions = list(self._functions.items())
+        values = {key: float(fn()) for key, fn in functions}
+        with self._lock:
+            for key, value in values.items():
+                self._series[key][0] = value
+
+    def to_doc(self):
+        self._refresh()
+        return super().to_doc()
 
     def inc(self, amount=1, **labels):
         slot = self._slot(labels)
@@ -247,6 +274,9 @@ class Gauge(_Metric):
 
     def value(self, **labels):
         slot = self._slot(labels)
+        fn = self._functions.get(_label_key(self.label_names, labels))
+        if fn is not None:
+            return float(fn())
         with self._lock:
             return slot[0]
 

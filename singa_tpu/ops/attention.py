@@ -49,7 +49,7 @@ def _block_scan_attention(q, k, v, causal, scale, block_k,
     masking of sharded sequences.
     """
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[-1]
     block_k = min(block_k, Sk)
     nblocks = (Sk + block_k - 1) // block_k
     pad = nblocks * block_k - Sk
@@ -57,7 +57,7 @@ def _block_scan_attention(q, k, v, causal, scale, block_k,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kb = k.reshape(B, H, nblocks, block_k, D).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(B, H, nblocks, block_k, D).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(B, H, nblocks, block_k, Dv).transpose(2, 0, 1, 3, 4)
 
     q_pos = q_offset + jnp.arange(Sq)
 
@@ -83,7 +83,8 @@ def _block_scan_attention(q, k, v, causal, scale, block_k,
     # derive accumulators from q so they carry its shard_map varying-axes
     # type (fresh zeros would be 'unvarying' and fail the scan typecheck)
     zero = q.astype(jnp.float32) * 0.0
-    init = (zero,
+    init = (zero[..., :1] * jnp.zeros((Dv,), jnp.float32) if Dv != D
+            else zero,
             jnp.max(zero, axis=-1) + _NEG_INF,
             jnp.sum(zero, axis=-1))
     (out, m, l), _ = lax.scan(
@@ -125,7 +126,7 @@ def _scan_flash_bwd(q, k, v, out, lse, g, causal, scale, block_k):
         dQ    = dS K ;  dK = dSᵀ Q
     """
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[-1]
     block_k = min(block_k, Sk)
     nblocks = (Sk + block_k - 1) // block_k
     pad = nblocks * block_k - Sk
@@ -134,7 +135,7 @@ def _scan_flash_bwd(q, k, v, out, lse, g, causal, scale, block_k):
         kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kb = kp.reshape(B, H, nblocks, block_k, D).transpose(2, 0, 1, 3, 4)
-    vb = vp.reshape(B, H, nblocks, block_k, D).transpose(2, 0, 1, 3, 4)
+    vb = vp.reshape(B, H, nblocks, block_k, Dv).transpose(2, 0, 1, 3, 4)
     qf = q.astype(jnp.float32)
     gf = g.astype(jnp.float32)
     delta = jnp.sum(gf * out.astype(jnp.float32), axis=-1)   # (B, H, Sq)
@@ -170,7 +171,7 @@ def _scan_flash_bwd(q, k, v, out, lse, g, causal, scale, block_k):
     dk = dkbs.transpose(1, 2, 0, 3, 4).reshape(
         B, H, nblocks * block_k, D)[:, :, :Sk]
     dv = dvbs.transpose(1, 2, 0, 3, 4).reshape(
-        B, H, nblocks * block_k, D)[:, :, :Sk]
+        B, H, nblocks * block_k, Dv)[:, :, :Sk]
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -569,7 +570,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 _VMEM_SCOPED = 16 * 2 ** 20
 
 
-def _heads_per_step(n, block_q, block_k, D, itemsize):
+def _heads_per_step(n, block_q, block_k, D, itemsize, Dv=None):
     """How many of the ``n`` (batch, head) slices one grid step works on.
 
     The body is unrolled over them, so the scheduler overlaps one head's
@@ -581,17 +582,19 @@ def _heads_per_step(n, block_q, block_k, D, itemsize):
     is Mosaic's compile time, about 0.65 s a layer's three kernels at one
     head and 2.0 s at four, paid when a step compiles cold. As many as
     divide ``n`` and fit the scoped VMEM: the double-buffered blocks and
-    the scratch of the largest kernel (head size padded to whole lane
-    tiles, as VMEM holds it), with room for the float32 score tiles;
+    the scratch of the largest kernel (each head size padded to whole
+    lane tiles, as VMEM holds it: ``D`` of q, k, dq, dk and ``Dv``, by
+    default ``D``, of v, g, dv), with room for the float32 score tiles;
     8 at most, which bounds the unrolled code."""
-    row = -(-D // _LANES) * _LANES
+    rq = -(-D // _LANES) * _LANES
+    rv = rq if Dv is None else -(-Dv // _LANES) * _LANES
     per_head = max(
         # dQ: q, g, dq and k, v blocks twice; acc, lse, delta scratch
-        2 * (3 * block_q + 2 * block_k) * row * itemsize
-        + block_q * (row + 2 * _LANES) * 4,
+        2 * (block_q * (2 * rq + rv) + block_k * (rq + rv)) * itemsize
+        + block_q * (rq + 2 * _LANES) * 4,
         # dK/dV: q, g and k, v, dk, dv blocks twice; two accumulators
-        2 * (2 * block_q + 4 * block_k) * row * itemsize
-        + 2 * block_k * row * 4)
+        2 * (block_q + 2 * block_k) * (rq + rv) * itemsize
+        + block_k * (rq + rv) * 4)
     # six live score-sized float32 tiles and 2 MiB of slack
     room = _VMEM_SCOPED - 2 ** 21 - 6 * block_q * block_k * 4
     return next(h for h in (8, 4, 2, 1)
@@ -641,12 +644,12 @@ _KERNEL_STATICS = ("causal", "scale", "block_q", "block_k", "interpret")
 def _flash_fwd_call(q, k, v, pos_delta, *, causal, scale, block_q, block_k,
                     interpret):
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[-1]
     nkb = Sk // block_k
-    hb = _heads_per_step(B * H, block_q, block_k, D, q.dtype.itemsize)
+    hb = _heads_per_step(B * H, block_q, block_k, D, q.dtype.itemsize, Dv)
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * H, Sk, D)
-    vr = v.reshape(B * H, Sk, D)
+    vr = v.reshape(B * H, Sk, Dv)
     with_off = pos_delta is not None
 
     def kernel(*refs):
@@ -664,7 +667,7 @@ def _flash_fwd_call(q, k, v, pos_delta, *, causal, scale, block_q, block_k,
     in_specs = [
         pl.BlockSpec((hb, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((hb, block_k, D), kv_map),
-        pl.BlockSpec((hb, block_k, D), kv_map),
+        pl.BlockSpec((hb, block_k, Dv), kv_map),
     ]
     operands = [qr, kr, vr]
     if with_off:
@@ -676,22 +679,22 @@ def _flash_fwd_call(q, k, v, pos_delta, *, causal, scale, block_q, block_k,
         grid=(B * H // hb, Sq // block_q, nkb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((hb, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((hb, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((hb, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((hb, block_q, D), jnp.float32),
+            pltpu.VMEM((hb, block_q, Dv), jnp.float32),
             pltpu.VMEM((hb, block_q, _LANES), jnp.float32),
             pltpu.VMEM((hb, block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
     )(*operands)
-    return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
+    return out.reshape(B, H, Sq, Dv), lse.reshape(B, H, Sq)
 
 
 def _pallas_flash_bwd(q, k, v, out, lse, g, causal, scale,
@@ -706,13 +709,13 @@ def _pallas_flash_bwd(q, k, v, out, lse, g, causal, scale,
 def _flash_bwd_call(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
                     interpret):
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[-1]
     nqb, nkb = Sq // block_q, Sk // block_k
-    hb = _heads_per_step(B * H, block_q, block_k, D, q.dtype.itemsize)
+    hb = _heads_per_step(B * H, block_q, block_k, D, q.dtype.itemsize, Dv)
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * H, Sk, D)
-    vr = v.reshape(B * H, Sk, D)
-    gr = g.reshape(B * H, Sq, D)
+    vr = v.reshape(B * H, Sk, Dv)
+    gr = g.reshape(B * H, Sq, Dv)
     # row statistics enter the kernels with the sequence on lanes
     lser = lse.reshape(B * H, 1, Sq)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
@@ -726,6 +729,7 @@ def _flash_bwd_call(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
         return jnp.maximum(i, (j * block_k) // block_q) if causal else i
 
     qspec = pl.BlockSpec((hb, block_q, D), lambda b, i, j: (b, i, 0))
+    gspec = pl.BlockSpec((hb, block_q, Dv), lambda b, i, j: (b, i, 0))
     rowspec = pl.BlockSpec((hb, 1, block_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
@@ -734,8 +738,8 @@ def _flash_bwd_call(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
         in_specs=[
             qspec,
             pl.BlockSpec((hb, block_k, D), kv_map),
-            pl.BlockSpec((hb, block_k, D), kv_map),
-            qspec, rowspec, rowspec,
+            pl.BlockSpec((hb, block_k, Dv), kv_map),
+            gspec, rowspec, rowspec,
         ],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
@@ -746,8 +750,11 @@ def _flash_bwd_call(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
         name="flash_bwd_dq",
     )(qr, kr, vr, gr, lser, delta)
 
-    kvspec = pl.BlockSpec((hb, block_k, D), lambda b, j, i: (b, j, 0))
+    kspec = pl.BlockSpec((hb, block_k, D), lambda b, j, i: (b, j, 0))
+    vspec = pl.BlockSpec((hb, block_k, Dv), lambda b, j, i: (b, j, 0))
     qside = pl.BlockSpec((hb, block_q, D),
+                         lambda b, j, i: (b, first_q(i, j), 0))
+    gside = pl.BlockSpec((hb, block_q, Dv),
                          lambda b, j, i: (b, first_q(i, j), 0))
     rowside = pl.BlockSpec((hb, 1, block_q),
                            lambda b, j, i: (b, 0, first_q(i, j)))
@@ -755,19 +762,19 @@ def _flash_bwd_call(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k, nqb=nqb),
         grid=(B * H // hb, nkb, nqb),
-        in_specs=[qside, kvspec, kvspec, qside, rowside, rowside],
-        out_specs=[kvspec, kvspec],
+        in_specs=[qside, kspec, vspec, gside, rowside, rowside],
+        out_specs=[kspec, vspec],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H, Sk, Dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((hb, block_k, D), jnp.float32),
-                        pltpu.VMEM((hb, block_k, D), jnp.float32)],
+                        pltpu.VMEM((hb, block_k, Dv), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lser, delta)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
-            dv.reshape(B, H, Sk, D))
+            dv.reshape(B, H, Sk, Dv))
 
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_k):
@@ -782,8 +789,11 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_k):
 def flash_attention(q, k, v, causal=False, scale=None, block_k=512):
     """Fused multi-head attention: softmax(q·kᵀ·scale [+ causal mask])·v.
 
-    q/k/v: (batch, heads, seq, head_dim). The S×S score matrix is never
-    materialised in either direction — forward keeps online-softmax
+    q/k: (batch, heads, seq, head_dim); v: (batch, heads, seq, v_dim),
+    where ``v_dim`` may be narrower than ``head_dim`` (latent attention's
+    192-wide keys and 128-wide values); the output is as wide as ``v``
+    and ``scale`` defaults to ``head_dim ** -0.5``. The S×S score matrix
+    is never materialised in either direction — forward keeps online-softmax
     accumulators, backward recomputes per-block probabilities from the
     saved lse — so train-mode memory is O(S·D). On TPU both directions run
     as Pallas kernels (primal path included, so inference uses the fused

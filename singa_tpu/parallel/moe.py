@@ -18,11 +18,14 @@ all-to-alls degrade to identity and the same code computes the dense
 from __future__ import annotations
 
 import collections
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
 from ..autograd_base import Operator
 from ..layer import Layer, _param
@@ -317,9 +320,129 @@ DENSE_ROWS = 128
 SORTED_TILE = 256
 
 
+# ---------------------------------------------------------------------------
+# the grouped product: rows sorted by expert times each expert's matrix,
+# JAX's megablox kernels (gmm, tgmm), whose grid visits the row tiles of
+# the groups held here and no others (its length is read on the device)
+# ---------------------------------------------------------------------------
+
+# rows a grid step of the grouped product: a group's rows are worked off in
+# tiles of GROUPED_TILE, a tile that two groups share is visited by each
+GROUPED_TILE = 128
+# largest matrix block a grid step holds, bytes (twice, double-buffered,
+# within the v5e's 16 MiB of scoped VMEM beside the row tiles)
+_BLOCK_BYTES = 4 * 2 ** 20
+
+
+def _tiling(m, k, n):
+    """megablox's tile sizes for an (m, k) x (k, n) product: GROUPED_TILE
+    rows, all of k, and all n columns where a (k, n) bf16 block fits
+    _BLOCK_BYTES, else the largest multiple of 128 lanes that divides n
+    and fits."""
+    if k * n * 2 <= _BLOCK_BYTES or n % 128:
+        return GROUPED_TILE, k, n
+    fit = max(128, _BLOCK_BYTES // (k * 2) // 128 * 128)
+    return GROUPED_TILE, k, next(t for t in range(fit, 0, -128)
+                                 if n % t == 0)
+
+
+def _interpret():
+    return jax.default_backend() != "tpu"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(lhs, rhs, sizes, out_dtype=jnp.float32):
+    """The grouped product: ``lhs`` (M, K) rows sorted by group, M a
+    multiple of GROUPED_TILE, ``rhs`` (G, K, N) one matrix a group,
+    ``sizes`` (G + 1,) int32 rows a group, the last group's rows (pairs
+    routed to experts held elsewhere) left nought. (M, N) in
+    ``out_dtype``; operands in their own dtype, sums in float32. The grid
+    visits the row tiles the first G groups hold and no others, in the
+    forward (megablox ``gmm``) and in both halves of the reverse: the
+    rows' gradient (``gmm`` against each matrix turned over) and the
+    matrices' (``tgmm``, each group's rows turned over against its rows of
+    the cotangent), the cotangent taken in the operands' dtype."""
+    return gmm(lhs, rhs, sizes, out_dtype, _tiling,
+               interpret=_interpret())
+
+
+def _grouped_fwd(lhs, rhs, sizes, out_dtype):
+    return grouped_matmul(lhs, rhs, sizes, out_dtype), (lhs, rhs, sizes)
+
+
+def _grouped_bwd(out_dtype, res, g):
+    lhs, rhs, sizes = res
+    g = g.astype(lhs.dtype)
+    dlhs = gmm(g, rhs, sizes, lhs.dtype, _tiling, transpose_rhs=True,
+               interpret=_interpret())
+    drhs = tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype, _tiling,
+                num_actual_groups=rhs.shape[0], interpret=_interpret())
+    return dlhs, drhs, np.zeros(sizes.shape, jax.dtypes.float0)
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _routed_grouped(h, group, counts, w, w_gate, w_up, w_down):
+    """The pairs routed here through :func:`grouped_matmul`: sorted by
+    held expert (pairs routed elsewhere last), gathered, each expert's
+    SwiGLU on its own rows, weighted, added back to their tokens. None is
+    dropped; differentiable in ``h``, ``w`` (so the router) and the three
+    banks. The products' grid follows the pairs held here; their operands
+    and outputs, the gather, the SwiGLU between them, the weighting and the
+    scatter-add (and their reverses) are static shapes over all T * k
+    pairs, the rows of pairs held elsewhere nought.
+    Returns (y (T, D) float32, rows the grouped product computes)."""
+    T, k = w.shape
+    G = w_gate.shape[0]
+    if G == 0:
+        return jnp.zeros(h.shape, jnp.float32), jnp.int32(0)
+    M = -(-T * k // GROUPED_TILE) * GROUPED_TILE
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    if M > T * k:
+        order = jnp.concatenate(
+            [order, jnp.full((M - T * k,), T * k, jnp.int32)])
+    here = jnp.sum(counts)
+    sizes = jnp.concatenate([counts, (M - here)[None]]).astype(jnp.int32)
+    tok = jnp.minimum(order, T * k - 1) // k
+    xs = h[tok]
+    f32 = jnp.float32
+    gate = grouped_matmul(xs, w_gate, sizes, f32)
+    up = grouped_matmul(xs, w_up, sizes, f32)
+    y = grouped_matmul((jax.nn.silu(gate) * up).astype(h.dtype), w_down,
+                       sizes, f32)
+    live = jnp.arange(M) < here
+    weight = jnp.where(live, jnp.concatenate(
+        [w.reshape(-1), jnp.zeros((M - T * k,), f32)])[order], 0.0)
+    out = jnp.zeros((T, h.shape[1]), f32).at[tok].add(y * weight[:, None])
+    # the tiles the grid visits: a group's rows rounded out to whole
+    # tiles at both ends
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    tiles = jnp.where(counts > 0, -(-ends // GROUPED_TILE)
+                      - starts // GROUPED_TILE, 0)
+    return out, jnp.sum(tiles) * GROUPED_TILE
+
+
+def _weights_of_a_share_alone(w, present):
+    """``w`` (T, k) as it is in the forward; its gradient that of
+    ``w * sum_present(w) / sum_present(w)`` with the numerator held
+    constant: over the picks ``present`` (T, k) the weights renormalised
+    among themselves and scaled back to their share of the whole, the
+    picks not present left without a gradient. Under a rule that
+    renormalises over all the picks this is the gradient the whole layer
+    would give if each absent pick's output had the present picks' mean
+    gradient, weighted by their weights: nothing flows to an absent
+    pick's score, and the present picks' scores, weighted by themselves,
+    get gradients that sum to nought."""
+    held = jnp.sum(jnp.where(present, w, 0.0), -1, keepdims=True)
+    r = w * lax.stop_gradient(held) / jnp.where(held > 0, held, 1.0)
+    return lax.stop_gradient(w) + (r - lax.stop_gradient(r))
+
+
 def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
                      axis_name=None, blocks=None, route=SIGMOID_TOPK,
-                     n_zero=0):
+                     n_zero=0, grouped=False, alone=False):
     """The part of a top-k expert layer that ONE share gives.
 
     ``p``: ``router`` (D, E + n_zero) over all E experts and the
@@ -337,7 +460,21 @@ def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
     the shared experts then run on those blocks alone
     (:func:`rows_in_blocks`), as the routed ones do by their pairs;
     ``route``: the routing rule (:class:`Route`; default sigmoid
-    scores renormalised over the picks).
+    scores renormalised over the picks); ``grouped``: above DENSE_ROWS
+    rows, work the pairs off by :func:`grouped_matmul` (Pallas kernels
+    with a reverse: the path to train on) rather than by the tile loop
+    (a loop of a length read on the device, which has no reverse). Both
+    paths below DENSE_ROWS and the grouped path above are differentiable
+    in ``h``, ``h_route``, the router and every bank. ``alone``: this
+    share is trained without the exchange, so the outputs of the picks
+    routed elsewhere never come back and their gradient is missing; the
+    picks' scores then take their gradient as if each missing output
+    were the picks here on average: the weights of the picks whose
+    outputs are here are renormalised over those picks, their share of
+    the whole held constant. Picks routed elsewhere get no gradient, and
+    the scores of the picks here none toward more or less of that
+    share, so the router does not learn to prefer the experts held here.
+    The forward is unchanged, bit for bit.
 
     Routes over all E + n_zero columns, weighs the picks by ``route``
     over all ``top_k`` of them, computes only the pairs whose expert
@@ -355,7 +492,10 @@ def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
     Returns ``(y (T, D) float32, stats)`` with ``stats`` int32 scalars:
     ``pairs_here``, ``pairs_absent``, ``pairs_zero`` (picks of identity
     experts), ``experts_touched`` (held experts that got any pair);
-    the three kinds of pairs add up to real rows x ``top_k``."""
+    the three kinds of pairs add up to real rows x ``top_k``. The
+    grouped path adds ``load_max`` (most pairs a held expert got) and
+    ``rows_computed`` (rows the grouped product worked: the pairs here
+    and the rest of the tiles they begin and end in)."""
     T = h.shape[0]
     G = p["w_gate"].shape[0]
     E = p["router"].shape[1] - n_zero
@@ -378,10 +518,19 @@ def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
                  - pairs_here - pairs_zero,
                  "pairs_zero": pairs_zero,
                  "experts_touched": jnp.sum((counts > 0).astype(jnp.int32))}
+        if alone:
+            if axis_name is not None and active_axis(axis_name):
+                raise ValueError("alone: a share on an active axis gets "
+                                 "the other shares' outputs back")
+            w = _weights_of_a_share_alone(w, here | zero)
     with jax.named_scope("moe_experts"):
         if T <= DENSE_ROWS:
             y = _routed_dense(h, local, w, here, p["w_gate"], p["w_up"],
                               p["w_down"])
+        elif grouped:
+            y, stats["rows_computed"] = _routed_grouped(
+                h, group, counts, w, p["w_gate"], p["w_up"], p["w_down"])
+            stats["load_max"] = jnp.max(counts, initial=0)
         else:
             y = _routed_sorted(h, group, counts, w, p["w_gate"], p["w_up"],
                                p["w_down"], min(SORTED_TILE, T))
@@ -405,15 +554,13 @@ def expert_share_ffn(p, h, *, top_k, held_from=0, h_route=None, rows=None,
 
 
 class _ExpertShareFFN(Operator):
-    """Tape node of :func:`expert_share_ffn` (forward only: the sorted
-    path's loop has no reverse)."""
-
-    differentiable = False
+    """Tape node of :func:`expert_share_ffn` on its grouped path, so
+    differentiable in the rows, the router and every bank."""
 
     def __init__(self, top_k, held_from, axis_name):
         super().__init__()
         self.kw = dict(top_k=top_k, held_from=held_from,
-                       axis_name=axis_name)
+                       axis_name=axis_name, grouped=True)
         self.stats = None
 
     def forward(self, h, *leaves):
@@ -425,8 +572,9 @@ class _ExpertShareFFN(Operator):
 class ExpertShareFFN(Layer):
     """Sigmoid top-k experts of which this layer holds ``held_count``
     (from ``held_from``) out of ``n_experts``, with ``n_shared`` shared
-    experts averaged beside them — :func:`expert_share_ffn` as a layer
-    (inference only). ``self.stats`` holds the last call's counts."""
+    experts averaged beside them — :func:`expert_share_ffn` as a layer,
+    trainable (its grouped path). ``self.stats`` holds the last call's
+    counts."""
 
     LEAVES = ("router", "w_gate", "w_up", "w_down", "s_gate", "s_up",
               "s_down")
@@ -484,4 +632,5 @@ class ExpertShareFFN(Layer):
 
 
 __all__ = ["MoEFFN", "ExpertShareFFN", "expert_share_ffn", "Route",
-           "route_topk", "route_sigmoid_topk", "rows_in_blocks"]
+           "route_topk", "route_sigmoid_topk", "rows_in_blocks",
+           "grouped_matmul"]

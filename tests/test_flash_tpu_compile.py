@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from singa_tpu.ops import attention_mod as ATTN
+from singa_tpu.parallel import moe
 
 
 @pytest.fixture(scope="module")
@@ -39,6 +40,7 @@ def for_the_chip(monkeypatch):
     chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(ATTN, "_interpret", lambda: False)
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -83,6 +85,63 @@ def test_fwd_and_bwd_compile_at_the_picked_blocks(one_chip, for_the_chip,
     assert hlo.count("tpu_custom_call") == 3
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert name in hlo
+
+
+# (B, H, S, key head, value head): latent attention's 192-wide keys and
+# 128-wide values (the Kanana-2 train cell), and a float32 pair
+NARROW_V = [((2, 32, 4096, 192, 128), jnp.bfloat16),
+            ((1, 4, 512, 192, 128), jnp.float32)]
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize(
+    "shape,dtype", NARROW_V,
+    ids=[f"{'x'.join(map(str, s))}-{jnp.dtype(d).name}" for s, d in NARROW_V])
+def test_values_narrower_than_keys_compile(one_chip, for_the_chip, shape,
+                                           dtype):
+    """v, the output, g and dv as wide as the values; q, k, dq, dk as the
+    keys: no padding of V to the key width."""
+    B, H, S, D, Dv = shape
+    qk = jax.ShapeDtypeStruct((B, H, S, D), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, H, S, Dv), dtype, sharding=one_chip)
+    blocks = ATTN._pick_blocks(S, S)
+    hlo = jax.jit(_fwd_bwd(True, blocks)).lower(qk, qk, v, v).compile() \
+        .as_text()
+    assert hlo.count("tpu_custom_call") == 3
+    out = jax.eval_shape(_fwd_bwd(True, blocks), qk, qk, v, v)
+    assert [o.shape[-1] for o in out] == [Dv, D, D, Dv]
+
+
+# (pairs' rows, hidden, expert width, held experts): the Kanana-2 train
+# cell's expert layer (8,192 tokens x 6 picks, 16 of its 128 experts held
+# here), both of its products; then a toy
+GROUPED = [(49152, 2048, 768, 16), (49152, 768, 2048, 16),
+           (256, 64, 96, 4)]
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("M,K,N,G", GROUPED,
+                         ids=["x".join(map(str, g)) for g in GROUPED])
+def test_grouped_product_and_its_reverse_compile(one_chip, for_the_chip, M,
+                                                 K, N, G):
+    """megablox's `gmm` forward, and `gmm` (the rows' gradient) and `tgmm`
+    (the banks') backward at the tiles `moe._tiling` gives: their blocks
+    fit the scoped VMEM and Mosaic lays them out."""
+    lhs = jax.ShapeDtypeStruct((M, K), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((G, K, N), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((G + 1,), jnp.int32, sharding=one_chip)
+    dy = jax.ShapeDtypeStruct((M, N), jnp.float32, sharding=one_chip)
+
+    def f(lhs, rhs, sizes, dy):
+        out, vjp = jax.vjp(lambda a, b: moe.grouped_matmul(a, b, sizes),
+                           lhs, rhs)
+        return (out,) + vjp(dy)
+    hlo = jax.jit(f).lower(lhs, rhs, sizes, dy).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 3
+    calls = [line.split("=")[0] for line in hlo.splitlines()
+             if "tpu_custom_call" in line]
+    assert sum("tgmm" in c for c in calls) == 1
+    assert sum("gmm" in c for c in calls) == 3
 
 
 @pytest.mark.pallas
